@@ -13,11 +13,11 @@ Methods (mirroring pytest-timeout's two strategies, own implementation):
 
 - ``signal`` (default): SIGALRM in the main thread; dumps all thread
   stacks via faulthandler and fails JUST the hung test. Cannot interrupt
-  a test stuck inside a C call (e.g. a wedged XLA compile RPC) until it
+  a test stuck inside a C call (e.g. a long XLA compile) until it
   returns to Python.
 - ``thread``: a daemon ``threading.Timer`` that dumps all stacks and
   ``os._exit(7)``s the whole process — fires even inside C calls. This is
-  the backstop for truly wedged backends; the process dies, which is the
+  the backstop for a truly hung backend; the process dies, which is the
   honest outcome (state is unrecoverable).
 
 A test stuck in a C call under the default method keeps the alarm

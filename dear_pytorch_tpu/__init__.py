@@ -25,15 +25,7 @@ Public API (Horovod-style, mirroring reference dear/__init__.py:3-9):
     dear.allreduce(x)                              # metric averaging
 """
 
-# Must run before any submodule import: aliases new-jax names (jax.P,
-# jax.shard_map) on older jax releases so the rest of the package can be
-# written against one API surface. Lives at the package top level (not
-# utils/) so this import cannot drag in any jax-API-using module first.
-from dear_pytorch_tpu import _jax_compat
-
-_jax_compat.ensure()
-
-from dear_pytorch_tpu.comm.backend import (  # noqa: E402,F401
+from dear_pytorch_tpu.comm.backend import (  # noqa: F401
     init,
     is_initialized,
     shutdown,

@@ -220,8 +220,7 @@ def main(argv=None) -> runner.BenchResult:
     timed_kwargs["batch_size"] = timed_kwargs["batch_size"] / sp
 
     def sync():
-        # One device->host scalar fetch drains the in-order pipeline (see
-        # bench.py's tunnel note).
+        # One device->host scalar fetch drains the in-order pipeline.
         if holder["metrics"] is not None:  # warmup may be zero steps
             float(holder["metrics"]["loss"])
 

@@ -171,13 +171,7 @@ def hlo_collective_stats(compiled_text: str) -> dict:
 
 
 def _flops_of(compiled) -> Optional[float]:
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0]
-        return float(ca.get("flops", 0.0)) or None
-    except Exception:
-        return None
+    return float(compiled.cost_analysis().get("flops", 0.0)) or None
 
 
 def audit_train_step(

@@ -232,12 +232,15 @@ def main(argv=None) -> int:
 
     # Force the emulated multi-device CPU world BEFORE backend init — the
     # audit is meaningless at world=1 (no collectives in the program).
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["DEAR_NUM_CPU_DEVICES"] = str(args.world)
-    os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
-    os.environ.setdefault("DEAR_COMPILATION_CACHE_DIR", "off")
-
+    # Through jax.config: the package import already loaded jax, so the
+    # environment is read no more.
+    import jax
     import jax.numpy as jnp
+
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", args.world)
+    jax.config.update("jax_enable_compilation_cache", False)
+    os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
 
     from dear_pytorch_tpu.comm import backend
     from dear_pytorch_tpu.observability import configure, snapshot

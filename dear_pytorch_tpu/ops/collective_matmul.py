@@ -63,23 +63,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from dear_pytorch_tpu.observability import tracer as _telemetry
 
-# `CompilerParams` is the current pallas name; older jax spells it
-# `TPUCompilerParams` — same dataclass (ops/flash_attention.py precedent).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
-#: distinct collective ids so concurrently-compiled ring kernels never
-#: share a barrier semaphore on chip (all-gather / fused-RS / collective-
-#: matmul fwd / dx / dw)
-_CID_AG, _CID_RS, _CID_CM_FWD, _CID_CM_DX, _CID_CM_DW = 2, 3, 4, 5, 6
-
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _params(cid: int):
-    return _CompilerParams(collective_id=cid)
 
 
 # Trace-time kernel-construction telemetry below counts one per pallas
@@ -112,7 +98,7 @@ def _ring_neighbors(axis_name):
 # exactly (prime 1 + slot-0 release + rounds 1..W-3 = W-1 signals against
 # W-1 waits), so the semaphores drain to zero by kernel end.
 #
-# Interpret mode cannot execute remote semaphore signals (jax 0.4.37:
+# Interpret mode cannot execute remote semaphore signals (NotImplementedError:
 # "Remote signal not implemented"), so the capacity protocol is the one
 # piece of the ring that only the CHIP path runs — the interpreter
 # delivers each emulated copy atomically at its wait point, so there is
@@ -254,10 +240,9 @@ def ring_all_gather(shard: jax.Array, axis_name) -> jax.Array:
     out = pl.pallas_call(
         functools.partial(_ag_kernel, world=world, axis_name=axis_name),
         out_shape=jax.ShapeDtypeStruct((world, n), shard.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=_ring_scratch((n,), shard.dtype),
-        compiler_params=_params(_CID_AG),
         interpret=_interpret(),
     )(shard)
     return out.reshape(world * n)
@@ -438,7 +423,7 @@ def fused_reduce_scatter_update(
         has_step=has_step, axis_name=axis_name,
     )
     in_specs = (
-        [pl.BlockSpec(memory_space=pltpu.ANY),      # gbuf (chunk rows)
+        [pl.BlockSpec(memory_space=pl.ANY),      # gbuf (chunk rows)
          pl.BlockSpec(memory_space=pltpu.VMEM)]     # param
         + [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(vecs)
         + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(scalars)
@@ -468,7 +453,6 @@ def fused_reduce_scatter_update(
         out_specs=out_specs,
         scratch_shapes=_ring_scratch((ss,), jnp.float32)
         + [pltpu.VMEM((2, ss), gbuf.dtype)],
-        compiler_params=_params(_CID_RS),
         interpret=_interpret(),
     )(*args)
     new_param = outs[0].reshape(ss)
@@ -615,14 +599,13 @@ def _cm_fwd_call(x, w_shard, axis_name):
         functools.partial(_cm_fwd_kernel, world=world, kc=kc,
                           axis_name=axis_name),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=_ring_scratch((kc, n), w_shard.dtype) + [
             pltpu.VMEM((m, kc), x.dtype),
             pltpu.VMEM((m, n), jnp.float32),
         ],
-        compiler_params=_params(_CID_CM_FWD),
         interpret=_interpret(),
     )(x, w_shard)
 
@@ -671,10 +654,9 @@ def _allgather_matmul_bwd(axis_name, res, dy):
         out_shape=jax.ShapeDtypeStruct((m, k), x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=_ring_scratch((kc, n), w_shard.dtype)
         + [pltpu.VMEM((m, kc), x.dtype)],
-        compiler_params=_params(_CID_CM_DX),
         interpret=_interpret(),
     )(dy, w_shard)
     # dw ring fuses the xᵀ·dy tile matmuls into the reduce-scatter — the
@@ -683,14 +665,13 @@ def _allgather_matmul_bwd(axis_name, res, dy):
         functools.partial(_cm_dw_kernel, world=world, kc=kc,
                           axis_name=axis_name),
         out_shape=jax.ShapeDtypeStruct((kc, n), w_shard.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=_ring_scratch((kc, n), jnp.float32) + [
             pltpu.VMEM((m, kc), x.dtype),
             pltpu.VMEM((kc, n), jnp.float32),
         ],
-        compiler_params=_params(_CID_CM_DW),
         interpret=_interpret(),
     )(x, dy)
     return dx, dw

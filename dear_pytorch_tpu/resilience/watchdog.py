@@ -1,7 +1,7 @@
 """Step watchdog: detect a hung training step and say where it hung.
 
 `utils.guard.GuardedTrainer` can only *log* a slow interval after the step
-returns — a truly hung collective (tunnel drop, wedged device RPC, a
+returns — a truly hung collective (a dropped link, a stuck device call, a
 deadlocked host thread) never returns, and the reference's answer was an
 operator watching mpirun output (SURVEY.md §5). `StepWatchdog` is a
 daemon thread fed per-step heartbeats; when no beat arrives within the

@@ -1,5 +1,5 @@
 """Native host runtime: C++ data pipeline + timers (csrc/dear_runtime.cpp),
-with a pure-numpy fallback when no C++ toolchain is available."""
+beside a pure-numpy pipeline for hosts without a C++ toolchain."""
 
 from dear_pytorch_tpu.runtime.pipeline import (  # noqa: F401
     NumpyPipeline,

@@ -2,8 +2,9 @@
 
 Compiles ``csrc/dear_runtime.cpp`` with the system C++ toolchain on first
 use (no pybind11 in this environment — plain C ABI + ctypes) and caches the
-.so next to the package. Thread-safe; failures degrade to the numpy
-fallback in `runtime.pipeline`.
+.so next to the package. Thread-safe; a failure is recorded in
+`load_error` and `load()` returns None — `runtime.pipeline.Pipeline` then
+raises (callers wanting numpy construct `NumpyPipeline` themselves).
 """
 
 from __future__ import annotations
@@ -94,8 +95,8 @@ def load_error() -> Optional[str]:
 def _dlopen(so: str) -> Optional[ctypes.CDLL]:
     """CDLL with stale-binary recovery: a loader mismatch on the cached
     .so triggers one forced recompile with the local toolchain; any
-    remaining failure degrades to the numpy fallback (recorded in
-    `load_error`) instead of crashing the import path."""
+    remaining failure is recorded in `load_error` instead of crashing
+    the import path."""
     global _load_error
     try:
         return ctypes.CDLL(so)
@@ -118,7 +119,7 @@ def _dlopen(so: str) -> Optional[ctypes.CDLL]:
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """The native library, or None if unbuildable (numpy fallback kicks in)."""
+    """The native library, or None if unbuildable (see `load_error`)."""
     global _lib, _tried
     with _lock:
         if _tried:

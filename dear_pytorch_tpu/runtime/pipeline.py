@@ -1,4 +1,4 @@
-"""Batch pipeline over the native ring buffer (numpy fallback included).
+"""Batch pipelines: the native ring buffer and a pure-numpy twin.
 
 A `SyntheticSpec` declares the batch as named (shape, dtype, distribution)
 fields; `Pipeline` produces dicts of numpy arrays, generated by the C++
@@ -239,16 +239,10 @@ class Pipeline(_ShardState):
 
     ``next()`` returns ``{name: np.ndarray}`` — fresh copies safe to hold
     (the underlying slot returns to the producers immediately).
-    Falls back to `NumpyPipeline` when the native library is unavailable.
+    Raises when the native library cannot be built or loaded: a caller who
+    asked for the native producers must not time `NumpyPipeline` under
+    their name (construct that one explicitly where numpy is wanted).
     """
-
-    def __new__(cls, spec: SyntheticSpec, *, nslots: int = 4,
-                nthreads: int = 2, seed: int = 0, shard: int = 0,
-                num_shards: int = 1):
-        if cls is Pipeline and B.load() is None:
-            return NumpyPipeline(spec, seed=seed, shard=shard,
-                                 num_shards=num_shards)
-        return super().__new__(cls)
 
     def __init__(self, spec: SyntheticSpec, *, nslots: int = 4,
                  nthreads: int = 2, seed: int = 0, shard: int = 0,
@@ -257,7 +251,10 @@ class Pipeline(_ShardState):
         self._init_shard(seed, shard, num_shards)
         self._nslots, self._nthreads = int(nslots), int(nthreads)
         lib = B.load()
-        assert lib is not None
+        if lib is None:
+            raise RuntimeError(
+                "native runtime library unavailable "
+                f"({B.load_error()}); use NumpyPipeline for the numpy path")
         self._lib = lib
         self._slot_bytes = spec.slot_bytes
         self._h = None

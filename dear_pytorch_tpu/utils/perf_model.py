@@ -67,21 +67,16 @@ def mfu(flops_per_step: float, secs_per_step: float, device) -> float:
 
 def peak_hbm_bytes(compiled) -> float:
     """Peak device memory of a compiled executable (argument + output +
-    temp + generated code), from XLA's memory analysis. 0.0 when the
-    backend doesn't expose it. The reference has no analog — GPU peak
-    memory there is whatever nvidia-smi happens to show; on TPU the
-    compiler knows the exact static allocation."""
-    try:
-        m = compiled.memory_analysis()
-        return float(
-            getattr(m, "argument_size_in_bytes", 0)
-            + getattr(m, "output_size_in_bytes", 0)
-            + getattr(m, "temp_size_in_bytes", 0)
-            + getattr(m, "generated_code_size_in_bytes", 0)
-            - getattr(m, "alias_size_in_bytes", 0)
-        )
-    except Exception:
-        return 0.0
+    temp + generated code, less aliased bytes), from XLA's memory
+    analysis. The reference has no analog — GPU peak memory there is
+    whatever nvidia-smi happens to show; on TPU the compiler knows the
+    exact static allocation."""
+    m = compiled.memory_analysis()
+    return float(
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes + m.generated_code_size_in_bytes
+        - m.alias_size_in_bytes
+    )
 
 
 def topk_perf_model(n: int, s: float = 2.18e-9) -> float:

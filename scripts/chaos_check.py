@@ -433,11 +433,9 @@ def run_worker_elastic(checkpoint_every: int, workdir: str) -> dict:
 
     os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
     os.environ["DEAR_CKPT_SHARED"] = "0"
-    from dear_pytorch_tpu import _jax_compat
-
-    _jax_compat.set_cpu_device_count(4, scrub_env=True)
-
     import jax
+
+    jax.config.update("jax_num_cpu_devices", 4)
 
     from dear_pytorch_tpu.observability import flight as FL
     from dear_pytorch_tpu.observability import tracer as T
@@ -682,11 +680,10 @@ def run_worker_sdc(checkpoint_every: int, workdir: str) -> dict:
 
     os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
     os.environ["DEAR_CKPT_SHARED"] = "0"
-    from dear_pytorch_tpu import _jax_compat
-
-    _jax_compat.set_cpu_device_count(4, scrub_env=True)
-
     import jax
+
+    jax.config.update("jax_num_cpu_devices", 4)
+
     import numpy as np
 
     from dear_pytorch_tpu.observability import tracer as T
@@ -1253,11 +1250,10 @@ def run_worker_autoscale(checkpoint_every: int, workdir: str) -> dict:
 
     os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
     os.environ["DEAR_CKPT_SHARED"] = "0"
-    from dear_pytorch_tpu import _jax_compat
-
-    _jax_compat.set_cpu_device_count(4, scrub_env=True)
-
     import jax
+
+    jax.config.update("jax_num_cpu_devices", 4)
+
     import numpy as np
 
     from dear_pytorch_tpu.observability import tracer as T
@@ -1375,11 +1371,10 @@ def run_cold_start(workdir: str) -> dict:
     import json
 
     os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
-    from dear_pytorch_tpu import _jax_compat
-
-    _jax_compat.set_cpu_device_count(4, scrub_env=True)
-
     import jax
+
+    jax.config.update("jax_num_cpu_devices", 4)
+
     import numpy as np
 
     from dear_pytorch_tpu.observability import tracer as T
@@ -1707,11 +1702,10 @@ def run_worker_multislice(checkpoint_every: int, workdir: str) -> dict:
 
     os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
     os.environ["DEAR_CKPT_SHARED"] = "0"
-    from dear_pytorch_tpu import _jax_compat
-
-    _jax_compat.set_cpu_device_count(2, scrub_env=True)
-
     import jax
+
+    jax.config.update("jax_num_cpu_devices", 2)
+
     import numpy as np
 
     from dear_pytorch_tpu.comm.dcn import DcnExchanger, DcnSelfEvict
@@ -2420,11 +2414,10 @@ def run_serve_publish(version: int, workdir: str) -> dict:
     Different versions use different init seeds, so a swapped fleet is
     observably serving different logits."""
     os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
-    from dear_pytorch_tpu import _jax_compat
-
-    _jax_compat.set_cpu_device_count(1, scrub_env=True)
-
     import jax
+
+    jax.config.update("jax_num_cpu_devices", 1)
+
     import jax.numpy as jnp
 
     from dear_pytorch_tpu.serving import weights as W
@@ -2448,9 +2441,9 @@ def run_worker_serve_replica(workdir: str) -> dict:
     through a continuous-batching `serving.engine`, and exits 0 only via
     the SIGTERM drain path (`resilience.preempt`)."""
     os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
-    from dear_pytorch_tpu import _jax_compat
+    import jax
 
-    _jax_compat.set_cpu_device_count(1, scrub_env=True)
+    jax.config.update("jax_num_cpu_devices", 1)
 
     from dear_pytorch_tpu.resilience import PreemptionHandler
     from dear_pytorch_tpu.resilience import inject as INJ
@@ -2870,11 +2863,10 @@ def run_worker_online_trainer(checkpoint_every: int, workdir: str) -> dict:
 
     os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
     os.environ["DEAR_CKPT_SHARED"] = "0"
-    from dear_pytorch_tpu import _jax_compat
-
-    _jax_compat.set_cpu_device_count(2, scrub_env=True)
-
     import jax
+
+    jax.config.update("jax_num_cpu_devices", 2)
+
     import numpy as np
 
     from dear_pytorch_tpu.observability import tracer as T
@@ -4019,7 +4011,7 @@ def main(argv=None) -> int:
 
 if __name__ == "__main__":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("DEAR_COMPILATION_CACHE_DIR", "off")
+    os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "0")
     if "--worker" in sys.argv:
         # storm rank: the launcher env contract (coordinator address,
         # process id) drives backend.init(); each rank keeps its single
@@ -4044,9 +4036,7 @@ if __name__ == "__main__":
     # suite uses
     import jax
 
-    from dear_pytorch_tpu import _jax_compat
-
     jax.config.update("jax_platforms", "cpu")
-    _jax_compat.set_cpu_device_count(
-        int(os.environ.get("DEAR_NUM_CPU_DEVICES", "8")), scrub_env=True)
+    jax.config.update("jax_num_cpu_devices",
+                      int(os.environ.get("DEAR_NUM_CPU_DEVICES", "8")))
     sys.exit(main())

@@ -159,9 +159,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     os.environ.setdefault("DEAR_DISABLE_DISTRIBUTED", "1")
-    from dear_pytorch_tpu import _jax_compat
+    import jax
 
-    _jax_compat.set_cpu_device_count(args.emulate, scrub_env=True)
+    jax.config.update("jax_num_cpu_devices", args.emulate)
 
     from dear_pytorch_tpu.comm import backend
     from dear_pytorch_tpu.tuning.planspace import (

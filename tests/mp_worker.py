@@ -370,9 +370,9 @@ def _elastic_main() -> None:
     # (world shrinks 3 -> 2 and grows back; the mesh is rebuilt per epoch)
     os.environ["DEAR_DISABLE_DISTRIBUTED"] = "1"
     os.environ["DEAR_CKPT_SHARED"] = "0"  # every rank owns its ckpt dir
-    from dear_pytorch_tpu import _jax_compat
+    import jax
 
-    _jax_compat.set_cpu_device_count(4, scrub_env=True)
+    jax.config.update("jax_num_cpu_devices", 4)
 
     from dear_pytorch_tpu.observability import flight as FL
     from dear_pytorch_tpu.observability import tracer as T

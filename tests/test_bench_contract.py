@@ -77,6 +77,23 @@ def test_bert_baseline_pin_on_first_capture(bench, monkeypatch, tmp_path):
     assert bench._bert_baseline() == (1111.0, "per-iter-fetch-r03")
 
 
+def test_failed_phase_fails_the_run(bench, mesh, monkeypatch, capsys):
+    """A phase that raises must end the run (traceback, non-zero exit from
+    the interpreter) with NO contract line — the pre-PR-24 loop caught
+    every phase after the first and still exited 0."""
+    monkeypatch.setenv("DEAR_TELEMETRY", "0")  # leave the global tracer be
+    monkeypatch.setattr(bench, "bench_resnet", lambda mesh: {
+        "metric": bench.PRIMARY_METRIC, "value": 1.0, "unit": "img/s"})
+
+    def boom(mesh, variant="bert_base"):
+        raise RuntimeError("compile refused")
+
+    monkeypatch.setattr(bench, "bench_bert", boom)
+    with pytest.raises(RuntimeError, match="compile refused"):
+        bench.main()
+    assert capsys.readouterr().out.strip() == ""
+
+
 def test_smoke_contract_one_json_line():
     """End-to-end: the smoke bench must emit EXACTLY one stdout line and it
     must parse as the contract object, primary metric first."""
@@ -86,8 +103,8 @@ def test_smoke_contract_one_json_line():
         JAX_PLATFORMS="cpu", DEAR_BENCH_SMOKE="1",
         DEAR_BENCH_BERT_LARGE="0", DEAR_BENCH_VIT="0",
         DEAR_DISABLE_DISTRIBUTED="1",
-        # cross-host CPU AOT cache entries can SIGILL (see tests/conftest)
-        DEAR_COMPILATION_CACHE_DIR="off",
+        # no cache entries into the checkout (see tests/conftest)
+        JAX_ENABLE_COMPILATION_CACHE="0",
         PYTHONPATH=REPO + os.pathsep + env.get("PYTHONPATH", ""),
     )
     proc = subprocess.run(

@@ -1,0 +1,204 @@
+"""Compile the main path's kernels and one step program for a TPU v5e that
+is described, not attached (on-chip-measurement guide, section 2).
+
+The TPU compiler is installed with JAX; a `v5e:2x2` topology description
+gives it four devices to compile for, so what Mosaic or XLA:TPU refuses
+costs no chip time to find. A compile that passes is a compile, never a
+run — `chip_smoke.py` is the run.
+
+All of it lives in this one file, and the topology is described inside a
+module-scoped fixture (never at import): only one process may load the
+TPU's library, so only the xdist worker that is handed this file does.
+"""
+
+import functools
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+import chip_smoke
+from dear_pytorch_tpu.ops.collective_matmul import (
+    allgather_matmul,
+    fused_reduce_scatter_update,
+    ring_all_gather,
+)
+from dear_pytorch_tpu.ops.flash_attention import flash_attention
+from dear_pytorch_tpu.ops.fused_sgd import fused_sgd
+from dear_pytorch_tpu.parallel import DearState, build_train_step
+
+P = jax.P
+#: a 25 MB f32 fusion bucket (THRESHOLD_MB of the smoke), in elements
+BUCKET = 25 * 2**20 // 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices), ("dp",))
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The kernels pick interpret mode from `jax.default_backend()`, which
+    is the CPU here; steer them to the Mosaic path for these compiles.
+    (By module name through sys.modules: `dear_pytorch_tpu.ops` re-exports
+    a `flash_attention` FUNCTION that shadows the module attribute.)"""
+    for name in ("flash_attention", "collective_matmul"):
+        monkeypatch.setattr(sys.modules[f"dear_pytorch_tpu.ops.{name}"],
+                            "_interpret", lambda: False)
+
+
+def _on(mesh, shape, dtype, spec):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, spec))
+
+
+def test_perf_model_knows_the_described_device(topo):
+    from dear_pytorch_tpu.utils import perf_model
+
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    assert perf_model.device_peak_flops(topo.devices[0]) == 197e12
+
+
+# GPT-2 124M attention at S=1024 (causal) and BERT-Base at S=128
+@pytest.mark.parametrize("shape,causal", [((8, 1024, 12, 64), True),
+                                          ((32, 128, 12, 64), False)])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_kernel_compiles_for_v5e(compiled_kernels, one_chip, shape,
+                                       causal, direction):
+    attend = functools.partial(flash_attention, causal=causal)
+    fn = attend if direction == "fwd" else jax.grad(
+        lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_dear_step_compiles_for_four_v5e_chips(mesh4):
+    """A 2-layer full-width GPT-2 `dear` step, lowered from shapes alone on
+    the described mesh: the program as written asks for reduce-scatter and
+    all-gather. What XLA:TPU keeps is pinned as observed on this topology
+    (PERF.md, PR 24): the parameter all-gathers survive, every gradient
+    reduce-scatter is rewritten into a combined all-reduce + slice."""
+    model, loss_fn = chip_smoke.make_loss(
+        chip_smoke.gpt2_config(jnp.bfloat16, num_layers=2))
+    params = jax.eval_shape(
+        lambda key, ids: model.init({"params": key}, ids,
+                                    train=False)["params"],
+        jax.random.PRNGKey(0), jax.ShapeDtypeStruct((1, 1024), jnp.int32))
+    ts = build_train_step(
+        loss_fn, params, mesh=mesh4, mode="dear",
+        threshold_mb=chip_smoke.THRESHOLD_MB,
+        optimizer=fused_sgd(lr=chip_smoke.LR, momentum=chip_smoke.MOMENTUM),
+        comm_dtype=jnp.bfloat16)
+    sizes = [b.padded_size for b in ts.plan.buckets]
+    state = DearState(
+        buffers=tuple(_on(mesh4, (n,), jnp.float32, P("dp")) for n in sizes),
+        opt_state=tuple((_on(mesh4, (n,), jnp.float32, P("dp")),
+                         _on(mesh4, (), jnp.bool_, P())) for n in sizes),
+        step=_on(mesh4, (), jnp.int32, P()),
+    )
+    batch = {"input_ids": _on(mesh4, (32, 1024), jnp.int32, P("dp"))}
+    lowered = ts.lower(state, batch)
+    asked = lowered.as_text()
+    assert "stablehlo.reduce_scatter" in asked
+    assert "stablehlo.all_gather" in asked
+    compiled = lowered.compile()
+    kept = chip_smoke.count_collectives(compiled.as_text())
+    assert kept.get("all-gather", 0) >= 1, kept
+    assert kept.get("reduce-scatter", 0) + kept.get("all-reduce", 0) >= 1, kept
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def _expect_refusal(compile_fn, words: str):
+    """Run a compile that is expected to be refused; let the refusal
+    propagate (the strict xfail records it) only when it is the recorded
+    one — a new reason is a plain failure."""
+    try:
+        compile_fn()
+    except Exception as e:
+        if words not in str(e):
+            pytest.fail(f"refused for a new reason: {e}")
+        raise
+
+
+def _ring(mesh, fn, in_specs, out_specs, *args):
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
+    return lambda: jax.jit(mapped).lower(*args).compile()
+
+
+# The ring kernels (mode="dear-fused", --ring-projections, ring-TP decode)
+# have only ever run in interpret mode. With the collective_id API repair
+# made (PR 24), Mosaic refuses each for v5e with the words below, so they
+# cannot run on the chip today. strict: the kernel PR that repairs one
+# (ROADMAP A6) has to flip its mark.
+
+
+@pytest.mark.xfail(strict=True, raises=Exception, reason=(
+    "Mosaic: Slice shape along dimension 0 must be aligned to tiling (2), "
+    "but is 1 — the (2, n) double-buffered comm scratch is sliced one row "
+    "at a time"))
+def test_ring_all_gather_compiles_for_v5e(compiled_kernels, mesh4):
+    _expect_refusal(
+        _ring(mesh4, lambda s: ring_all_gather(s, "dp"), P("dp"), P(),
+              _on(mesh4, (BUCKET,), jnp.float32, P("dp"))),
+        "must be aligned to tiling (2), but is 1")
+
+
+@pytest.mark.xfail(strict=True, raises=Exception, reason=(
+    "Mosaic: Slice shape along dimension 0 must be aligned to tiling (4), "
+    "but is 1 — the (world, shard) bf16 gradient view is sliced one row "
+    "at a time"))
+def test_fused_reduce_scatter_update_compiles_for_v5e(compiled_kernels,
+                                                      mesh4):
+    opt = fused_sgd(lr=0.01, momentum=0.9)
+
+    def fused(g, p, m, seeded):
+        return fused_reduce_scatter_update(g, p, (m, seeded), opt, "dp",
+                                           mean_world=4)
+
+    _expect_refusal(
+        _ring(mesh4, fused, (P(), P("dp"), P("dp"), P()),
+              (P("dp"), (P("dp"), P())),
+              _on(mesh4, (BUCKET,), jnp.bfloat16, P()),
+              _on(mesh4, (BUCKET,), jnp.float32, P("dp")),
+              _on(mesh4, (BUCKET,), jnp.float32, P("dp")),
+              _on(mesh4, (), jnp.bool_, P())),
+        "must be aligned to tiling (4), but is 1")
+
+
+@pytest.mark.xfail(strict=True, raises=Exception, reason=(
+    "Mosaic: Slice shape along dimension 1 must be aligned to tiling (128), "
+    "but is 192 — GPT-2's K=768 over four chips gives 192-column slices"))
+def test_allgather_matmul_compiles_for_v5e_at_gpt2_width(compiled_kernels,
+                                                         mesh4):
+    # QKV projection of GPT-2 124M: [tokens, 768] @ [768, 2304], the
+    # weight row-sharded over the four chips
+    _expect_refusal(
+        _ring(mesh4, lambda x, w: allgather_matmul(x, w, "dp"),
+              (P("dp"), P("dp")), P("dp"),
+              _on(mesh4, (4 * 8192, 768), jnp.bfloat16, P("dp")),
+              _on(mesh4, (768, 2304), jnp.bfloat16, P("dp"))),
+        "must be aligned to tiling (128), but is 192")

@@ -96,10 +96,10 @@ def test_bf16_inputs():
     )
 
 
-def test_bert_impl_contract_and_dropout_fallback():
+def test_bert_impl_contract_and_dropout_refusal():
     """The attention_impl adapter matches the dense model path exactly at
-    dropout 0 and falls back to the dense implementation (same rng stream)
-    when dropout is active."""
+    dropout 0 and REFUSES a live dropout rate (the kernel has no dropout
+    path; a silent dense fall-through would be timed under its name)."""
     from dear_pytorch_tpu.models.bert import dot_product_attention
 
     impl = make_flash_attention_impl()
@@ -111,12 +111,9 @@ def test_bert_impl_contract_and_dropout_fallback():
     want = dot_product_attention(q, k, v, additive)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-    rng = jax.random.PRNGKey(9)
-    got_dp = impl(q, k, v, additive, dropout_rng=rng, dropout_rate=0.5)
-    want_dp = dot_product_attention(q, k, v, additive, dropout_rng=rng,
-                                    dropout_rate=0.5)
-    np.testing.assert_allclose(np.asarray(got_dp), np.asarray(want_dp),
-                               rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="no attention-dropout path"):
+        impl(q, k, v, additive, dropout_rng=jax.random.PRNGKey(9),
+             dropout_rate=0.5)
 
 
 def test_bert_end_to_end_with_flash_impl():
