@@ -131,6 +131,13 @@ def _apply_platform_env() -> None:
     that variable itself — nothing is touched here), else in
     `DEFAULT_COMPILATION_CACHE_DIR`. ``JAX_ENABLE_COMPILATION_CACHE=0``
     (JAX's own switch) turns it off; the test suite runs that way.
+
+    The cache is keyed on the instructions' metadata too. By default JAX
+    strips it from the key, so a program that differs only in its named
+    scopes (or in the lines they were traced from) is served the executable
+    compiled first, whose ``as_text()`` carries the old ``op_name``s: the
+    join from a device trace to the step's scopes (docs/OBSERVABILITY.md)
+    would then silently read another build's names.
     """
     ndev = _env_int("DEAR_NUM_CPU_DEVICES")
     if ndev:
@@ -139,6 +146,7 @@ def _apply_platform_env() -> None:
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           DEFAULT_COMPILATION_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def init(

@@ -116,15 +116,21 @@ def dot_product_attention(q, k, v, mask, *, dropout_rng=None,
     """Default attention core: one softmax(QK^T)V per layer, batched over
     (batch, heads). Shapes: q/k/v [B, S, H, D]; mask [B, 1, 1, S] additive."""
     depth = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(depth).astype(dtype)
-    if mask is not None:
-        scores = scores + mask
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
+    with jax.named_scope("attention/scores"):
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q, k)
+                  / jnp.sqrt(depth).astype(dtype))
+    with jax.named_scope("attention/softmax"):  # the mask included
+        if mask is not None:
+            scores = scores + mask
+        probs = jax.nn.softmax(scores.astype(jnp.float32),
+                               axis=-1).astype(dtype)
     if dropout_rng is not None and dropout_rate > 0.0:
-        keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
-                                    probs.shape)
-        probs = probs * keep / (1.0 - dropout_rate)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        with jax.named_scope("attention/dropout"):
+            keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
+                                        probs.shape)
+            probs = probs * keep / (1.0 - dropout_rate)
+    with jax.named_scope("attention/context"):
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 class BertSelfAttention(nn.Module):
@@ -233,17 +239,17 @@ class BertLayer(nn.Module):
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="attention_ln")(x + attn)
         kinit = nn.initializers.normal(cfg.initializer_range)
-        if self.projection_impl is not None:
-            y = ProjDense(cfg.intermediate_size, impl=self.projection_impl,
-                          dtype=cfg.dtype, kernel_init=kinit,
-                          name="intermediate")(x)
-        else:
-            y = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype,
-                         kernel_init=kinit, name="intermediate")(x)
-        y = nn.gelu(y, approximate=True)
-        y = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
-                     kernel_init=nn.initializers.normal(cfg.initializer_range),
-                     name="output")(y)
+        with jax.named_scope("mlp"):
+            if self.projection_impl is not None:
+                y = ProjDense(cfg.intermediate_size,
+                              impl=self.projection_impl, dtype=cfg.dtype,
+                              kernel_init=kinit, name="intermediate")(x)
+            else:
+                y = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype,
+                             kernel_init=kinit, name="intermediate")(x)
+            y = nn.gelu(y, approximate=True)
+            y = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, kernel_init=kinit,
+                         name="output")(y)
         y = nn.Dropout(cfg.hidden_dropout_prob, deterministic=not train)(y)
         return nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                             name="output_ln")(x + y)
@@ -371,16 +377,18 @@ def bert_pretraining_loss(logits, nsp_logits, masked_lm_labels,
     ``BertPretrainingCriterion``, dear/bert_benchmark.py:101-112:
     CrossEntropyLoss(ignore_index=-1) on flattened logits, summed).
     """
-    V = logits.shape[-1]
-    flat_logits = logits.reshape(-1, V)
-    flat_labels = masked_lm_labels.reshape(-1)
-    valid = flat_labels != ignore_index
-    safe = jnp.where(valid, flat_labels, 0)
-    logp = jax.nn.log_softmax(flat_logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, safe[:, None], axis=-1)[:, 0]
-    mlm_loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
-    nsp_logp = jax.nn.log_softmax(nsp_logits, axis=-1)
-    nsp_loss = -jnp.mean(
-        jnp.take_along_axis(nsp_logp,
-                            next_sentence_labels.reshape(-1, 1), axis=-1))
-    return mlm_loss + nsp_loss
+    with jax.named_scope("loss"):
+        V = logits.shape[-1]
+        flat_logits = logits.reshape(-1, V)
+        flat_labels = masked_lm_labels.reshape(-1)
+        valid = flat_labels != ignore_index
+        safe = jnp.where(valid, flat_labels, 0)
+        logp = jax.nn.log_softmax(flat_logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, safe[:, None], axis=-1)[:, 0]
+        mlm_loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
+        nsp_logp = jax.nn.log_softmax(nsp_logits, axis=-1)
+        nsp_loss = -jnp.mean(
+            jnp.take_along_axis(nsp_logp,
+                                next_sentence_labels.reshape(-1, 1),
+                                axis=-1))
+        return mlm_loss + nsp_loss

@@ -101,18 +101,24 @@ def causal_dot_product_attention(q, k, v, mask, *, dropout_rng=None,
     mask [B,1,1,S] or None — the causal triangle is applied here)."""
     depth = q.shape[-1]
     S = q.shape[1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(depth).astype(dtype)
-    tri = jnp.tril(jnp.ones((S, S), jnp.bool_))
-    scores = jnp.where(tri[None, None], scores,
-                       jnp.asarray(-1e9, scores.dtype))
-    if mask is not None:
-        scores = scores + mask
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
+    with jax.named_scope("attention/scores"):
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q, k)
+                  / jnp.sqrt(depth).astype(dtype))
+    with jax.named_scope("attention/softmax"):  # the masks included
+        tri = jnp.tril(jnp.ones((S, S), jnp.bool_))
+        scores = jnp.where(tri[None, None], scores,
+                           jnp.asarray(-1e9, scores.dtype))
+        if mask is not None:
+            scores = scores + mask
+        probs = jax.nn.softmax(scores.astype(jnp.float32),
+                               axis=-1).astype(dtype)
     if dropout_rng is not None and dropout_rate > 0.0:
-        keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
-                                    probs.shape)
-        probs = probs * keep / (1.0 - dropout_rate)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        with jax.named_scope("attention/dropout"):
+            keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
+                                        probs.shape)
+            probs = probs * keep / (1.0 - dropout_rate)
+    with jax.named_scope("attention/context"):
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def checkpointed_causal_attention_impl():
@@ -138,7 +144,8 @@ def checkpointed_causal_attention_impl():
                 q_, k_, v_, mask, dtype=dtype
             )
         )
-        return core(q, k, v)
+        with jax.named_scope("attention"):
+            return core(q, k, v)
 
     return impl
 
@@ -157,7 +164,8 @@ def flash_causal_attention_impl():
                 "set attention_probs_dropout_prob=0"
             )
         del mask  # full sequences in the causal LM path
-        return flash_attention(q, k, v, causal=True)
+        with jax.named_scope("attention"):
+            return flash_attention(q, k, v, causal=True)
 
     return impl
 
@@ -209,41 +217,43 @@ class GptBlock(nn.Module):
 
         y = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="ln_2")(x)
-        if cfg.num_experts > 0:
-            # lazy import: models<->parallel would otherwise cycle
-            # (parallel.sp imports this module)
-            from dear_pytorch_tpu.parallel.ep import MoeMlp
+        with jax.named_scope("mlp"):
+            if cfg.num_experts > 0:
+                # lazy import: models<->parallel would otherwise cycle
+                # (parallel.sp imports this module)
+                from dear_pytorch_tpu.parallel.ep import MoeMlp
 
-            B_, S_, H_ = y.shape
-            # Decode flattens only B tokens, which would collapse the
-            # expert capacity (C = max(int(cf*B/E), 1)) and silently zero
-            # colliding tokens' MLP outputs — use a drop-free factor there.
-            # Note capacity DROPS are not replayed incrementally: decode
-            # logits match training-time logits exactly iff training was
-            # drop-free too (expert_capacity_factor >= num_experts).
-            cf = (float(cfg.num_experts) if decode
-                  else cfg.expert_capacity_factor)
-            y = MoeMlp(
-                num_experts=cfg.num_experts,
-                mlp_dim=cfg.intermediate_size,
-                capacity_factor=cf,
-                dtype=cfg.dtype, name="moe",
-            )(y.reshape(B_ * S_, H_)).reshape(B_, S_, H_)
-        elif self.projection_impl is not None:
-            from dear_pytorch_tpu.models.bert import ProjDense
+                B_, S_, H_ = y.shape
+                # Decode flattens only B tokens, which would collapse the
+                # expert capacity (C = max(int(cf*B/E), 1)) and silently
+                # zero colliding tokens' MLP outputs — use a drop-free
+                # factor there. Note capacity DROPS are not replayed
+                # incrementally: decode logits match training-time logits
+                # exactly iff training was drop-free too
+                # (expert_capacity_factor >= num_experts).
+                cf = (float(cfg.num_experts) if decode
+                      else cfg.expert_capacity_factor)
+                y = MoeMlp(
+                    num_experts=cfg.num_experts,
+                    mlp_dim=cfg.intermediate_size,
+                    capacity_factor=cf,
+                    dtype=cfg.dtype, name="moe",
+                )(y.reshape(B_ * S_, H_)).reshape(B_, S_, H_)
+            elif self.projection_impl is not None:
+                from dear_pytorch_tpu.models.bert import ProjDense
 
-            y = ProjDense(cfg.intermediate_size,
-                          impl=self.projection_impl, dtype=cfg.dtype,
-                          kernel_init=init, name="mlp_in")(y)
-            y = nn.gelu(y, approximate=True)
-            y = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, kernel_init=init,
-                         name="mlp_out")(y)
-        else:
-            y = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype,
-                         kernel_init=init, name="mlp_in")(y)
-            y = nn.gelu(y, approximate=True)
-            y = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, kernel_init=init,
-                         name="mlp_out")(y)
+                y = ProjDense(cfg.intermediate_size,
+                              impl=self.projection_impl, dtype=cfg.dtype,
+                              kernel_init=init, name="mlp_in")(y)
+                y = nn.gelu(y, approximate=True)
+                y = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                             kernel_init=init, name="mlp_out")(y)
+            else:
+                y = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype,
+                             kernel_init=init, name="mlp_in")(y)
+                y = nn.gelu(y, approximate=True)
+                y = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                             kernel_init=init, name="mlp_out")(y)
         y = nn.Dropout(cfg.hidden_dropout_prob, deterministic=not train)(y)
         return x + y
 
@@ -510,11 +520,13 @@ def gpt_lm_loss(logits, input_ids, *, vocab_size: Optional[int] = None):
     naive form costs ~3 GB of extra HBM round-trips per step; this form
     reads the logits once. Same-value + same-gradient property is pinned
     by tests/test_gpt.py::test_gpt_lm_loss_streamed_equivalence."""
-    logits = logits[:, :-1]
-    targets = input_ids[:, 1:]
-    V = logits.shape[-1]
-    valid = logits[..., :vocab_size] if (vocab_size is not None
-                                         and vocab_size < V) else logits
-    lse = jax.scipy.special.logsumexp(valid, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - tgt)
+    with jax.named_scope("loss"):
+        logits = logits[:, :-1]
+        targets = input_ids[:, 1:]
+        V = logits.shape[-1]
+        valid = logits[..., :vocab_size] if (vocab_size is not None
+                                             and vocab_size < V) else logits
+        lse = jax.scipy.special.logsumexp(valid, axis=-1)
+        tgt = jnp.take_along_axis(logits, targets[..., None],
+                                  axis=-1)[..., 0]
+        return jnp.mean(lse - tgt)

@@ -548,10 +548,11 @@ def make_flash_attention_impl():
                 "flash attention kernel has no attention-dropout path; "
                 "set attention_probs_dropout_prob=0"
             )
-        kv_mask = None
-        if mask is not None:
-            # model masks are ADDITIVE [B,1,1,S]; kernel wants validity
-            kv_mask = mask.reshape(mask.shape[0], mask.shape[-1]) > -1.0
-        return flash_attention(q, k, v, kv_mask=kv_mask)
+        with jax.named_scope("attention"):
+            kv_mask = None
+            if mask is not None:
+                # model masks are ADDITIVE [B,1,1,S]; kernel wants validity
+                kv_mask = mask.reshape(mask.shape[0], mask.shape[-1]) > -1.0
+            return flash_attention(q, k, v, kv_mask=kv_mask)
 
     return impl

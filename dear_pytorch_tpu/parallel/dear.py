@@ -122,6 +122,12 @@ MODES = ("dear", "dear-fused", "allreduce", "rsag", "rb", "bytescheduler",
 #: when a phase is excluded, exactly as in the reference.
 EXCLUDABLE = ("reducescatter", "allgather")
 
+#: Host spans on the profiler's own clock (`dear.step`, and the three
+#: stretches of the hierarchical step). With no `jax.profiler` session
+#: active, entering one costs well under a microsecond
+#: (scripts/check_telemetry_overhead.py gates it).
+_annotate = jax.profiler.TraceAnnotation
+
 
 class DearState(NamedTuple):
     """Carried training state.
@@ -581,35 +587,34 @@ def build_train_step(
         if mode == "fsdp":
             params = None  # gathered inside the differentiated fn
         elif sharded:
-            if "allgather" in excl:  # ablation: fake the gather with zeros
-                full_bufs = [
-                    lax.dynamic_update_slice_in_dim(
-                        jnp.zeros((b.padded_size,), cast_shard(s).dtype),
-                        cast_shard(s),
-                        idx * b.shard_size,
-                        axis=0,
-                    )
-                    for b, s in zip(plan.buckets, state.buffers)
-                ]
-            elif fused:
-                # Pallas ring all-gather: chunk t+1 streams over the ICI
-                # while chunk t lands (bit-identical to lax.all_gather)
-                full_bufs = [
-                    CM.ring_all_gather(cast_shard(s), axis_name)
-                    for s in state.buffers
-                ]
-            else:
-                full_bufs = [
-                    C.all_gather(cast_shard(s), axis_name)
-                    for s in state.buffers
-                ]
+            full_bufs = []
+            for g, (b, s) in enumerate(zip(plan.buckets, state.buffers)):
+                with jax.named_scope(f"dear/bucket{g}/gather"):
+                    if "allgather" in excl:
+                        # ablation: fake the gather with zeros
+                        full = lax.dynamic_update_slice_in_dim(
+                            jnp.zeros((b.padded_size,), cast_shard(s).dtype),
+                            cast_shard(s),
+                            idx * b.shard_size,
+                            axis=0,
+                        )
+                    elif fused:
+                        # Pallas ring all-gather: chunk t+1 streams over
+                        # the ICI while chunk t lands (bit-identical to
+                        # lax.all_gather)
+                        full = CM.ring_all_gather(cast_shard(s), axis_name)
+                    else:
+                        full = C.all_gather(cast_shard(s), axis_name)
+                full_bufs.append(full)
             # With gather_dtype, leaves STAY in gather_dtype (identical to
             # the fsdp path): the model's own cast is then the identity,
             # and the two sharded schedules see the same numerics.
-            params = F.unpack_all(full_bufs, plan,
-                                  cast=gather_dtype is None)
+            with jax.named_scope("dear/unpack"):
+                params = F.unpack_all(full_bufs, plan,
+                                      cast=gather_dtype is None)
         else:
-            params = F.unpack_all(list(state.buffers), plan)
+            with jax.named_scope("dear/unpack"):
+                params = F.unpack_all(list(state.buffers), plan)
         if rng_seed is not None:
             if dcn is not None:
                 # fold a GLOBALLY unique device index: devices at the
@@ -620,10 +625,12 @@ def build_train_step(
                         lax.axis_index(dcn_slice_axis)] * world + idx)
             else:
                 rng_idx = idx
-            step_rng = jax.random.fold_in(
-                jax.random.fold_in(jax.random.PRNGKey(rng_seed), state.step),
-                rng_idx,
-            )
+            with jax.named_scope("dear/rng"):
+                step_rng = jax.random.fold_in(
+                    jax.random.fold_in(jax.random.PRNGKey(rng_seed),
+                                       state.step),
+                    rng_idx,
+                )
             extra_args: tuple = (step_rng,)
         else:
             extra_args = ()
@@ -654,12 +661,14 @@ def build_train_step(
                 params internally still creates such an alias — pass
                 gather_dtype matching the model's compute dtype so that
                 cast is the identity.)"""
-                full = [
-                    _named(C.all_gather(cast_shard(s), axis_name))
-                    for s in bufs
-                ]
-                return F.unpack_all(full, plan, wrap=_named,
-                                    cast=gather_dtype is None)
+                full = []
+                for g, s in enumerate(bufs):
+                    with jax.named_scope(f"dear/bucket{g}/gather"):
+                        full.append(
+                            _named(C.all_gather(cast_shard(s), axis_name)))
+                with jax.named_scope("dear/unpack"):
+                    return F.unpack_all(full, plan, wrap=_named,
+                                        cast=gather_dtype is None)
 
             def shard_loss(bufs, mstate, b, extra):
                 return canonical_loss(_named_unpack(bufs), mstate, b, extra)
@@ -759,13 +768,17 @@ def build_train_step(
 
         # fsdp: grads ARE the per-bucket shards already (AD transposed the
         # gathers into reduce-scatters); others: pack the param-tree grads.
-        grad_bufs = (
-            None if mode == "fsdp"
-            else F.pack_all(grads, plan, dtype=comm_dtype)
-        )
+        if mode == "fsdp":
+            grad_bufs = None
+        else:
+            with jax.named_scope("dear/pack"):
+                grad_bufs = F.pack_all(grads, plan, dtype=comm_dtype)
 
-        bucket_grads, new_comp = [], []
-        for g, b in enumerate(plan.buckets):
+        new_comp = []
+
+        def _reduce_bucket(g, b):
+            """Bucket ``g``'s packed gradient buffer -> the gradient this
+            device updates with (traced under ``dear/bucket<g>/reduce``)."""
             gbuf = None if mode == "fsdp" else grad_bufs[g]
             if mode == "fsdp":
                 grad = grads[g].astype(state.buffers[g].dtype) / mean_world
@@ -885,7 +898,12 @@ def build_train_step(
                 grad = C.broadcast(reduced, 0, axis_name).astype(
                     state.buffers[g].dtype
                 ) / mean_world
-            bucket_grads.append(grad)
+            return grad
+
+        bucket_grads = []
+        for g, b in enumerate(plan.buckets):
+            with jax.named_scope(f"dear/bucket{g}/reduce"):
+                bucket_grads.append(_reduce_bucket(g, b))
 
         return (bucket_grads, loss, aux, new_model_state,
                 tuple(new_comp) if compressed else state.comp_state)
@@ -893,20 +911,23 @@ def build_train_step(
     def _apply(state: DearState, bucket_grads, metrics, new_model_state,
                new_comp):
         if clip_norm is not None:
-            sumsq = sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in bucket_grads
-            )
-            if sharded:
-                # each device holds a DISTINCT shard: psum completes the
-                # global square-norm. (Replicated modes hold identical full
-                # gradients — their local sum already IS the global one.)
-                sumsq = lax.psum(sumsq, axis_name)
-            gnorm = jnp.sqrt(sumsq)
-            scale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-12))
-            bucket_grads = [
-                g * scale.astype(g.dtype) for g in bucket_grads
-            ]
+            with jax.named_scope("dear/clip"):
+                sumsq = sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in bucket_grads
+                )
+                if sharded:
+                    # each device holds a DISTINCT shard: psum completes
+                    # the global square-norm. (Replicated modes hold
+                    # identical full gradients — their local sum already
+                    # IS the global one.)
+                    sumsq = lax.psum(sumsq, axis_name)
+                gnorm = jnp.sqrt(sumsq)
+                scale = jnp.minimum(
+                    1.0, clip_norm / jnp.maximum(gnorm, 1e-12))
+                bucket_grads = [
+                    g * scale.astype(g.dtype) for g in bucket_grads
+                ]
             metrics["grad_norm"] = gnorm
 
         layerwise = isinstance(optimizer, LayerwiseShardOptimizer)
@@ -916,8 +937,9 @@ def build_train_step(
             {"step": state.step}
             if getattr(optimizer, "needs_step", False) else {}
         )
-        new_buffers, new_opt = [], []
-        for g, grad in enumerate(bucket_grads):
+        def _update_bucket(g, grad):
+            """The optimizer's update of bucket ``g`` (traced under
+            ``dear/bucket<g>/update``, whichever optimizer was passed)."""
             if fused:
                 # one Pallas kernel: ring reduce-scatter of the bucket's
                 # comm buffer + the optimizer update on the owned shard in
@@ -959,6 +981,12 @@ def build_train_step(
                 new_p, new_o = optimizer.update(
                     grad, state.opt_state[g], state.buffers[g], **step_kw
                 )
+            return new_p, new_o
+
+        new_buffers, new_opt = [], []
+        for g, grad in enumerate(bucket_grads):
+            with jax.named_scope(f"dear/bucket{g}/update"):
+                new_p, new_o = _update_bucket(g, grad)
             new_buffers.append(new_p)
             new_opt.append(new_o)
         if sdc_fp:
@@ -969,15 +997,16 @@ def build_train_step(
             # corruption. psum completes the checksum across shards
             # without leaving the program; the guard fetches the value
             # only at check cadence.
-            fps = []
-            for buf in new_buffers:
-                words = lax.bitcast_convert_type(
-                    buf.astype(jnp.float32), jnp.uint32)
-                s = jnp.sum(words, dtype=jnp.uint32)
-                if sharded:
-                    s = lax.psum(s, axis_name)
-                fps.append(s)
-            metrics["sdc_fp"] = jnp.stack(fps)
+            with jax.named_scope("dear/sdc_fp"):
+                fps = []
+                for buf in new_buffers:
+                    words = lax.bitcast_convert_type(
+                        buf.astype(jnp.float32), jnp.uint32)
+                    s = jnp.sum(words, dtype=jnp.uint32)
+                    if sharded:
+                        s = lax.psum(s, axis_name)
+                    fps.append(s)
+                metrics["sdc_fp"] = jnp.stack(fps)
         next_state = DearState(
             tuple(new_buffers), tuple(new_opt), state.step + 1,
             new_model_state, new_comp,
@@ -987,9 +1016,10 @@ def build_train_step(
     def device_step(state: DearState, batch):
         bucket_grads, loss, aux, new_model_state, new_comp = _fwd_bwd(
             state, batch)
-        metrics = {"loss": lax.pmean(loss, axis_name)}
-        if aux is not None:
-            metrics["aux"] = lax.pmean(aux, axis_name)
+        with jax.named_scope("dear/metrics"):
+            metrics = {"loss": lax.pmean(loss, axis_name)}
+            if aux is not None:
+                metrics["aux"] = lax.pmean(aux, axis_name)
         return _apply(state, bucket_grads, metrics, new_model_state,
                       new_comp)
 
@@ -1169,8 +1199,9 @@ def build_train_step(
         bucket_grads, loss, _aux, _nms, _ncomp = _fwd_bwd(state, batch)
         # aux / model state / compressor state are inert here — the dcn
         # build guards rejected every combination that would produce them
-        return (tuple(bucket_grads),
-                lax.pmean(loss, axis_name).reshape(1))
+        with jax.named_scope("dear/metrics"):
+            loss_sl = lax.pmean(loss, axis_name).reshape(1)
+        return tuple(bucket_grads), loss_sl
 
     def _hier_grads_jitted(state: DearState, batch):
         key = jax.tree.structure((state, batch))
@@ -1226,18 +1257,20 @@ def build_train_step(
         step_no = int(np.asarray(jax.device_get(state.step)))
         ds = _dtrace.get_stream()
         t_bwd = time.monotonic() if ds.enabled else 0.0
-        grads_g, loss_sl = _hier_grads_jitted(state, batch)(state, batch)
-        # bounded-stale mode only (no-op otherwise): start pulling the
-        # peers' partials for THIS step while our backward is still
-        # running on device — a peer up to one round ahead has already
-        # published, so its wire time hides under the compute
-        dcn.prefetch(step_no)
-        # the host leg is the synchronization point of this schedule: the
-        # step number keys the exchange and the partials are its payload,
-        # so these transfers are the leg itself, not a stray sync
-        host = [np.asarray(jax.device_get(g)) for g in grads_g]
-        losses = np.asarray(jax.device_get(loss_sl),
-                            np.float64).reshape(-1)
+        with _annotate("dear.backward"):
+            grads_g, loss_sl = _hier_grads_jitted(state, batch)(state, batch)
+            # bounded-stale mode only (no-op otherwise): start pulling the
+            # peers' partials for THIS step while our backward is still
+            # running on device — a peer up to one round ahead has already
+            # published, so its wire time hides under the compute
+            dcn.prefetch(step_no)
+            # the host leg is the synchronization point of this schedule:
+            # the step number keys the exchange and the partials are its
+            # payload, so these transfers are the leg itself, not a stray
+            # sync
+            host = [np.asarray(jax.device_get(g)) for g in grads_g]
+            losses = np.asarray(jax.device_get(loss_sl),
+                                np.float64).reshape(-1)
         if ds.enabled:
             # the device_get above IS the backward program's wall time
             # (the host leg synchronizes on it) — a compute span on the
@@ -1254,14 +1287,16 @@ def build_train_step(
         }
         scalars = {sid: float(losses[k])
                    for k, sid in enumerate(dcn.local_slices)}
-        means, loss_mean = dcn.exchange(step_no, per_slice, scalars,
-                                        partition_mb=partition_mb)
+        with _annotate("dear.dcn_exchange"):
+            means, loss_mean = dcn.exchange(step_no, per_slice, scalars,
+                                            partition_mb=partition_mb)
         sh = jax.sharding.NamedSharding(mesh, jax.P(axis_name))
         reduced = tuple(jax.device_put(m, sh) for m in means)
         loss_dev = jnp.float32(loss_mean)
         t_apply = time.monotonic() if ds.enabled else 0.0
-        out = _hier_apply_jitted(state, reduced, loss_dev)(
-            state, reduced, loss_dev)
+        with _annotate("dear.apply"):
+            out = _hier_apply_jitted(state, reduced, loss_dev)(
+                state, reduced, loss_dev)
         if ds.enabled:
             # update-program dispatch (async: the device work may drain
             # into the NEXT step's backward; the span records the host
@@ -1273,6 +1308,13 @@ def build_train_step(
         return out
 
     def step(state: DearState, batch):
+        # the one host span on the profiler's clock: any `jax.profiler`
+        # session shows the dispatch beside the device's lines (the
+        # DEAR_TELEMETRY span below keeps a clock of its own)
+        with _annotate("dear.step"):
+            return _step(state, batch)
+
+    def _step(state: DearState, batch):
         tr = _telemetry.get_tracer()
         ds = _dtrace.get_stream()
         if not tr.enabled and not ds.enabled:

@@ -10,16 +10,20 @@ measures both gates, the way `parallel/dear.py`'s ``step()`` and
 enabled paths (counter add + span; ring record) and an UNinstrumented
 baseline loop.
 
-Pure host-side Python — no jax, no devices — so it runs anywhere in
-milliseconds (tier-1 safe; tests/test_observability.py drives `main` with
-small iteration counts). Prints one JSON line:
+Pure host-side Python, no devices, so it runs anywhere in about a second
+(tier-1 safe; tests/test_observability.py drives `main` with small
+iteration counts). The telemetry machinery is loaded standalone, without
+jax; jax itself is imported (no backend is initialised) only for the one
+gate that is jax's own: the `jax.profiler.TraceAnnotation("dear.step")`
+that `ts.step` enters on every call, measured with no profiler session
+active. Prints one JSON line:
 
   {"disabled_ns_per_call": ..., "enabled_ns_per_call": ...,
    "flight_disabled_ns_per_call": ..., "flight_enabled_ns_per_call": ...,
    "baseline_ns_per_call": ..., "disabled_overhead_ns": ...,
    "budget_ns": 1000.0, "ok": true}
 
-``ok`` asserts BOTH disabled gates cost under ``--budget-ns`` (default
+``ok`` asserts EVERY disabled gate costs under ``--budget-ns`` (default
 1 µs — three orders of magnitude below a ~1 ms device step, i.e. the
 "< 1% of step time, unmeasurable" acceptance bar with huge margin).
 
@@ -355,7 +359,18 @@ def main(argv=None) -> int:
     def plan_tuner_finished_gate():
         finished_tuner.step()
 
+    # the profiler span around the dispatch in parallel/dear.py's
+    # ``step()`` (and the three stretches of ``_hier_step``): always
+    # entered, so what it costs with NO profiler session active is part
+    # of every step's host time and sits under the same budget
+    from jax.profiler import TraceAnnotation
+
+    def step_annotation_idle():
+        with TraceAnnotation("dear.step"):
+            pass
+
     baseline_ns = _bench(baseline, args.iters)
+    ann_idle_ns = _bench(step_annotation_idle, args.iters)
     disabled_ns = _bench(disabled_gate, args.iters)
     enabled_ns = _bench(enabled_site, max(args.iters // 10, 1))
     fl_disabled_ns = _bench(flight_disabled_gate, args.iters)
@@ -439,6 +454,7 @@ def main(argv=None) -> int:
         "sdc_disabled_ns_per_call": round(sdc_disabled_ns, 1),
         "sdc_enabled_ns_per_call": round(sdc_enabled_ns, 1),
         "tuner_finished_ns_per_call": round(tuner_finished_ns, 1),
+        "step_annotation_idle_ns_per_call": round(ann_idle_ns, 1),
         "disabled_overhead_ns": round(overhead_ns, 1),
         "budget_ns": args.budget_ns,
         "ok": (not analysis_loaded
@@ -458,7 +474,8 @@ def main(argv=None) -> int:
                and tt_disabled_ns <= args.budget_ns
                and td_disabled_ns <= args.budget_ns
                and sdc_disabled_ns <= args.budget_ns
-               and tuner_finished_ns <= args.budget_ns),
+               and tuner_finished_ns <= args.budget_ns
+               and ann_idle_ns <= args.budget_ns),
     }
     print(json.dumps(out))
     return 0 if out["ok"] else 1

@@ -1,23 +1,17 @@
-"""The round-5 overlap evidence, quantified — two complementary views.
+"""The overlap evidence that needs no chip: the HLO overlappability metric
+at world=8 — the dear-vs-allreduce claim, measured where it exists. For
+every collective op in the compiled (optimized, scheduled) step, the
+fraction of the program's compute ops that are dependency-INDEPENDENT of it
+(neither ancestor nor descendant). Independent compute is what any
+scheduler on any backend may run concurrently with the collective; a
+serialized schedule shows up as a low fraction no matter the hardware. The
+DeAR design claim (reference dear/dear_dopt.py:274-308: RS under backward,
+AG under next forward) passes iff dear's mean fraction exceeds the naive
+allreduce schedule's.
 
-1. **Device-trace table** (scripts/trace_analysis.py) over the committed
-   round-4 on-chip traces (`perf/onchip_r04/trace{,_fsdp}`): ms/step and
-   per-category time. NOTE these were captured at world=1, where the
-   program contains no collective ops at all — exposed collective time
-   is 0.0% *by construction* there, which is a statement about the
-   capture, not evidence of overlap. The conv/fusion split is the useful
-   signal (it feeds the ResNet conv-ceiling analysis in PERF.md).
-
-2. **HLO overlappability metric at world=8** — the actual dear-vs-
-   allreduce claim, measured where it exists: for every collective op in
-   the compiled (optimized, scheduled) step, the fraction of the
-   program's compute ops that are dependency-INDEPENDENT of it (neither
-   ancestor nor descendant). Independent compute is what any scheduler
-   on any backend may run concurrently with the collective; a
-   serialized schedule shows up as a low fraction no matter the
-   hardware. The DeAR design claim (reference dear/dear_dopt.py:274-308:
-   RS under backward, AG under next forward) passes iff dear's mean
-   fraction exceeds the naive allreduce schedule's.
+Exposed collective TIME is a device number: `perfbench` reads it from a
+chip trace (`exposed_collective_ms`, and by leg through the step's named
+scopes: `exposed_reduce_ms`, `exposed_gather_ms`; docs/OBSERVABILITY.md).
 
 Writes perf/overlap_r05/summary.json and exits nonzero if the claim
 fails. Asserted in-suite by tests/test_overlap.py.
@@ -88,32 +82,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(REPO, "perf",
                                                   "overlap_r05"))
-    ap.add_argument("--skip-traces", action="store_true",
-                    help="only the HLO metric (no committed-trace table)")
     args = ap.parse_args(argv)
 
     summary: dict = {"hlo_world8": {}}
-    if not args.skip_traces:
-        from trace_analysis import analyze, find_trace_file
-
-        summary["r04_device_traces_world1"] = {
-            "note": ("world=1 programs contain no collectives; exposure "
-                     "is 0 by construction — see module docstring"),
-        }
-        for label, d in (("dear", "perf/onchip_r04/trace"),
-                         ("fsdp", "perf/onchip_r04/trace_fsdp")):
-            try:
-                rep = analyze(find_trace_file(os.path.join(REPO, d)))
-                summary["r04_device_traces_world1"][label] = {
-                    "ms_per_step": rep["ms_per_step"],
-                    "exposed_collective_pct": rep["exposed_collective_pct"],
-                    "by_category_ms_per_step":
-                        rep["by_category_ms_per_step"],
-                }
-            except Exception as exc:  # noqa: BLE001
-                summary["r04_device_traces_world1"][label] = {
-                    "error": str(exc)[:200]}
-
     for mode in MODES:
         try:
             summary["hlo_world8"][mode] = hlo_overlap_metric(mode)

@@ -478,6 +478,8 @@ def test_overhead_script_fast_and_green(capsys):
     # fingerprint itself is in-program, so this attribute check is the
     # entire disabled cost) sits under the same budget
     assert out["sdc_disabled_ns_per_call"] < 1000.0
+    # the profiler span `ts.step` always enters, with no session active
+    assert out["step_annotation_idle_ns_per_call"] < 1000.0
     # the enabled flight record stays production-cheap too (micro-seconds)
     assert out["flight_enabled_ns_per_call"] < 100_000.0
 
