@@ -408,10 +408,18 @@ def main(argv=None) -> int:
         phase_dp(mesh, cfg, global_batch=32, seq_len=1024, steps=10,
                  seed=args.seed)
     else:
-        dense = phase_train(mesh, cfg, batch_size=8, seq_len=1024, steps=10,
+        train = phase_train(mesh, cfg, batch_size=8, seq_len=1024, steps=10,
                             seed=args.seed)
+        if "tpu_custom_call" not in train["text"]:
+            raise AssertionError(
+                "the default train step compiled without the flash kernel "
+                "(models.gpt.flash_core_applies should hold at S=1024)")
         phase_reference(mesh, gpt2_config(jnp.float32, num_layers=2),
                         batch_size=8, seq_len=1024, steps=10, seed=args.seed)
+        # the default core is the kernel on a TPU: name the dense program
+        dense = phase_train(mesh, cfg, batch_size=8, seq_len=1024, steps=5,
+                            seed=args.seed, label="dense",
+                            attention_impl=causal_dot_product_attention)
         flash = phase_flash(mesh, cfg, batch_size=8, seq_len=1024, steps=5,
                             seed=args.seed, dense=dense)
         if not flash["kernel_in_program"]:

@@ -16,6 +16,7 @@ gelu(tanh) MLPs.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -24,6 +25,18 @@ import jax.numpy as jnp
 from jax import lax
 
 from dear_pytorch_tpu.models.bert import dot_product_attention
+
+# by module name: `dear_pytorch_tpu.ops` re-exports a `flash_attention`
+# FUNCTION that shadows the module attribute
+_flash = importlib.import_module("dear_pytorch_tpu.ops.flash_attention")
+
+#: Shortest sequence the default core hands to the flash kernel. Causal bf16
+#: forward + backward of one layer, 12 heads of 64, on a v5e, dense / kernel
+#: (scripts/flash_ab.py, PR 30): S=512 (batch 16) 0.742 / 0.725 ms, a tie;
+#: S=768 (8) 1.567 / 0.623; S=1024 (16) 5.857 / 1.959; S=2048 (4) 5.420 /
+#: 1.573; S=4096 (2) 10.272 / 2.557. XLA's dense program is at its best at
+#: 512 (26% of the matmul floor; 13-15% from 768 up).
+FLASH_MIN_SEQ = 768
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,6 +134,37 @@ def causal_dot_product_attention(q, k, v, mask, *, dropout_rng=None,
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def flash_core_applies(q, k, mask, dropout_rng, dropout_rate) -> bool:
+    """Whether `causal_attention` runs the Pallas flash kernel: a function
+    of what the call can observe (backend, dropout, mask, shape), in this
+    one place. The kernel has no dropout and no additive-mask path, pays
+    off from `FLASH_MIN_SEQ` up, tiles sequences in multiples of 128, and
+    off the TPU would run in Pallas' interpreter (`_interpret` is the one
+    predicate: an ahead-of-time compile for a described TPU from a CPU
+    host patches that)."""
+    seq = q.shape[1]
+    return (not _flash._interpret()
+            and not (dropout_rng is not None and dropout_rate > 0.0)
+            and mask is None
+            and k.shape[1] == seq
+            and seq >= FLASH_MIN_SEQ and seq % 128 == 0)
+
+
+def causal_attention(q, k, v, mask, *, dropout_rng=None, dropout_rate=0.0,
+                     dtype=jnp.float32):
+    """The default causal attention core of `GptBlock`: the flash kernel
+    where `flash_core_applies` (scores, mask, softmax and context stay in
+    VMEM; bf16 operands, f32 accumulation and softmax), else the dense
+    program `causal_dot_product_attention`, unchanged. Same calling
+    convention as both."""
+    if flash_core_applies(q, k, mask, dropout_rng, dropout_rate):
+        with jax.named_scope("attention"):
+            return _flash.flash_attention(q, k, v, causal=True)
+    return causal_dot_product_attention(
+        q, k, v, mask, dropout_rng=dropout_rng, dropout_rate=dropout_rate,
+        dtype=dtype)
+
+
 def checkpointed_causal_attention_impl():
     """Dense causal attention with the probs tensor RECOMPUTED in the
     backward pass (jax.checkpoint over the core) — the flash kernel's
@@ -154,8 +198,6 @@ def flash_causal_attention_impl():
     """Causal attention via the Pallas flash kernel (attention dropout is
     not supported inside the kernel — use for inference/benchmarks or
     dropout-free training)."""
-    from dear_pytorch_tpu.ops.flash_attention import flash_attention
-
     def impl(q, k, v, mask, *, dropout_rng=None, dropout_rate=0.0,
              dtype=jnp.float32):
         if dropout_rng is not None and dropout_rate > 0.0:
@@ -165,7 +207,7 @@ def flash_causal_attention_impl():
             )
         del mask  # full sequences in the causal LM path
         with jax.named_scope("attention"):
-            return flash_attention(q, k, v, causal=True)
+            return _flash.flash_attention(q, k, v, causal=True)
 
     return impl
 
@@ -204,7 +246,12 @@ class GptBlock(nn.Module):
             ctx = self._decode_attend(q, k, v, decode_positions,
                                       prefill_lengths)
         else:
-            impl = self.attention_impl or causal_dot_product_attention
+            impl = self.attention_impl or causal_attention
+            if self.attention_impl is None and self.is_initializing():
+                # `init` keeps the parameters and discards this output: it
+                # takes the dense program, whose trace costs less set-up
+                # time than lowering a kernel for a result nobody reads
+                impl = causal_dot_product_attention
             ctx = impl(q, k, v, None, dropout_rng=dropout_rng,
                        dropout_rate=(cfg.attention_probs_dropout_prob
                                      if train else 0.0),

@@ -4,42 +4,72 @@ The hot op of every transformer in the zoo: the whole
 score-softmax-weighted-sum pipeline stays in VMEM per (query-block,
 key-block) tile, the S×S score matrix is never materialized in HBM
 (memory O(S·D) instead of O(S²)), and the MXU sees back-to-back
-[bq,D]×[D,bk] / [bq,bk]×[bk,D] matmuls (Dao et al. 2022, blockwise online
+[bq,W]×[W,bk] / [bq,bk]×[bk,W] matmuls (Dao et al. 2022, blockwise online
 softmax — same math as `parallel.ring_attention`, which distributes ACROSS
 chips what this kernel tiles WITHIN one).
 
-STATUS (PR 24): the kernels compile for a TPU v5e and `chip_smoke.py`
-checks them on the chip — forward and q/k/v gradients against dense
-attention at S=1024, and a GPT-2 train step whose compiled text carries
-the `tpu_custom_call`. tests/test_chip_compile.py keeps the v5e compile
-in tier-1. The memory claim above is structural; SPEED against XLA's
-fused attention is not measured — the smoke prints one timing, labelled
-as such, and `python scripts/flash_ab.py` is the A/B tool (ROADMAP A2).
+STATUS (PR 30): on `GptBlock`'s default training path wherever
+`models.gpt.flash_core_applies` (a TPU, no live attention dropout, no
+additive mask, S a multiple of 128 from 768 up); both GPT cells of
+BENCHMARK.json run it (24 `tpu_custom_call`s a step). Measured on a v5e
+with `python scripts/flash_ab.py --causal` at the cell's shape
+(16, 1024, 12, 64), bf16, one layer: forward 0.78 ms, forward + backward
+1.96 ms, against XLA's dense program at 1.95 / 5.86 ms and the Pallas
+kernel JAX ships at 1.03 / 5.76 ms (PERF.md §6 has the table and what each
+repair bought). tests/test_chip_compile.py keeps the v5e compile in tier-1;
+`chip_smoke.py` checks values on the chip.
+
+Layout (what makes it fast on a v5e, whose MXU and vector lanes are 128
+wide while a head is 64): the kernels read q, k, v as ``[B, S, H·D]`` —
+the projections' own layout, a free reshape of ``[B, S, H, D]`` — in
+blocks of ``(1, block, W)`` with ``W`` = 128 lanes = ``G`` = 128/D heads.
+No ``[B,S,H,D] -> [BH,S,D]`` transposes surround the call, no 64-wide
+minor dimension is padded to 128 in HBM, every load and store is
+lane-dense. Inside a block the heads are told apart by zeroing the other
+heads' lanes of the ROW-side operand (q in the forward and dq kernels,
+k and v in the dkv kernel): ``(q ⊙ lanes_g) kᵀ`` contracts 128 lanes and
+is exactly head g's ``q_g k_gᵀ``, at the MXU cost the 64-deep contraction
+had anyway (depth is free up to 128); ``p_g v`` yields head g's output in
+head g's lanes (the others are dropped at the flush). ``D`` a multiple of
+128 is one head a block; a ``H·D`` that 128 does not divide is one block.
+
+Operands go to the MXU in their input dtype with f32 accumulation (bf16
+in, bf16 probabilities for the second matmul, as the dense path does);
+f32 inputs stay f32 at full precision. The softmax is f32 throughout.
 
 Backward is the standard flash recomputation: forward saves only the
-softmax log-sum-exp per row; dQ and dK/dV are computed by two kernels that
-rebuild each P-tile on the fly.
+softmax log-sum-exp per row and the P-tiles are rebuilt on the fly.
+`flash_attention`'s gradient is ONE fused kernel (5 matmuls and one exp a
+tile; dK/dV of the whole key sequence accumulate in VMEM; ``pᵀ do`` and
+``dsᵀ q`` contract the tile's rows, which costs an XLU transpose of the
+left operand and was measured worth it: 1.96 against 2.30 ms a layer).
+Ring attention needs dQ and dK/dV of one (q-block, k-block) pair apart:
+`flash_pair_dq` / `flash_pair_dkv` are two kernels (7 matmuls, 2 exps);
+the dkv one works on the TRANSPOSED tile (``sᵀ = k qᵀ``), so its matmuls
+are all plain and the per-row statistics broadcast along sublanes.
 
-Kernel structure (the part that decides TPU performance): the reduction
-over key/query blocks is a GRID dimension, not an in-kernel loop. The
-innermost grid dim is declared ``arbitrary`` (sequential), the online
-softmax / gradient accumulators live in VMEM scratch that persists across
-those steps, and ``pl.when`` gates the j==0 init and the j==last flush.
-That shape lets Mosaic double-buffer each (1, bk, D) K/V block DMA behind
-the previous tile's compute — the first version of this file instead
-looped over an all-resident K/V block inside one kernel invocation, which
-serialized everything.
+Row statistics (lse, delta, the key-validity mask) travel with rows along
+LANES — ``[B, H/G, G, S]`` f32, 4 bytes a row in HBM (a lane-replicated
+``[.., S, 128]`` form would be 100 MB a layer at GPT-2's shape). The dkv
+kernel uses them as they are; the forward and dq kernels turn a block of
+them into the lane-replicated ``(block, 128)`` column form once per query
+block (one XLU transpose, not one per tile) and keep that in VMEM scratch.
+
+Kernel structure: the reduction over key/query blocks is a GRID dimension,
+not an in-kernel loop. The innermost grid dim is declared ``arbitrary``
+(sequential), the online softmax / gradient accumulators live in VMEM
+scratch that persists across those steps, and ``pl.when`` gates the init,
+the causal skip and the flush; Mosaic double-buffers each block's DMA
+behind the previous tile's compute. Blocks are large (1024 rows: S=1024 is
+one grid step a head group) because a tile's cost was measured to be mostly
+per tile and per row, not per score; inside, a tile is worked through in
+256-row strips, each against the key columns it can see, so the tile the
+causal diagonal crosses does 10/16 of the square's work and the tiles below
+it run without any mask. An absent key mask is absent from the kernel (no
+operand, no compare).
 
 Everything runs under `interpret=True` off-TPU, so the CPU test mesh
 exercises the exact kernel code path.
-
-Layout note (Mosaic, the real-TPU lowering): the last two dims of every
-block must be (8k, 128k) or equal the array's dims — a rank-2 operand
-blocked ``(1, S)`` over a ``[BH, S]`` array is rejected because the
-leading 1 is neither. The per-row vectors (kv mask, lse, delta) therefore
-travel as ``[BH, S, 1]`` inside the kernels (blocks ``(1, bs, 1)``: both
-trailing dims legal), while the public API stays rank-2. interpret=True
-never checks this, which is why only real-chip runs could catch it.
 
 Reference integration point: the model zoo's ``attention_impl`` contract
 (models/bert.py BertSelfAttention); the reference framework has no custom
@@ -53,13 +83,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_BIG = -1e30
-# Row-statistic scratch is kept full-lane-width (bq, 128) with every lane
-# holding the same value: full-width loads/stores are the fast path and
-# sidestep sub-lane masked writes.
+# Row statistics are kept full-lane-width (rows, 128) with every lane
+# holding the same value: full-width loads/stores are the fast path and a
+# (rows, 128) statistic broadcasts over a (rows, 128k) tile by re-use.
 _LANES = 128
 
 
@@ -67,28 +98,170 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# Leading (BH, q-or-k block) grid dims are parallel — Mosaic may split
-# them across cores; the innermost reduction dim must stay sequential
-# because the VMEM scratch accumulators carry across it.
+# (batch, head group, outer block) grid dims are parallel; the innermost
+# reduction dim must stay sequential because the VMEM scratch accumulators
+# carry across it.
 _COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     vmem_limit_bytes=64 * 1024 * 1024,
 )
 
 
-def _bcast_rows(x, bq):
-    """[bq] or [bq, 1] row statistic -> full-width (bq, LANES)."""
-    return jnp.broadcast_to(x.reshape(bq, 1), (bq, _LANES))
-
-
 # ---------------------------------------------------------------------------
-# forward kernel: grid (BH, Sq/bq, Sk/bk); scratch carries the online softmax
+# in-kernel helpers
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                m_s, l_s, acc_s, *, scale, causal, bq, bk, nk):
-    qi, kj = pl.program_id(1), pl.program_id(2)
+def _lanes(x, n):
+    """A lane-replicated (rows, 128) statistic as (rows, n)."""
+    if n == _LANES:
+        return x
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _cols(row):
+    """A (1, rows) statistic, rows along lanes, as lane-replicated
+    (rows, 128): broadcast over sublanes (free), one XLU transpose."""
+    return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))
+
+
+def _head_lanes(g, d, width):
+    """(1, width) bool: the lanes of head ``g`` of a block's ``width``."""
+    lane = lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return (lane >= g * d) & (lane < (g + 1) * d)
+
+
+def _head_rows(x, g, d, heads, scale=None):
+    """Block ``x`` (rows, W) with every lane outside head ``g`` zeroed (and
+    the rest scaled): contracting its 128 lanes against an unmasked
+    operand is head g's own contraction. f32 arithmetic (the v5e VPU has
+    no bf16), result in x's dtype."""
+    if heads == 1 and scale is None:
+        return x
+    y = x.astype(jnp.float32)
+    if scale is not None:
+        y = y * scale
+    if heads > 1:
+        y = jnp.where(_head_lanes(g, d, x.shape[1]), y, 0.0)
+    return y.astype(x.dtype)
+
+
+def _merge_heads(per_head, d):
+    """[(rows, W) per head, valid in that head's lanes] -> one (rows, W)."""
+    out = per_head[0]
+    for g in range(1, len(per_head)):
+        out = jnp.where(_head_lanes(g, d, out.shape[1]), per_head[g], out)
+    return out
+
+
+def _precision(dtype):
+    # f32 operands keep f32 accuracy (the benchmark's reference check runs
+    # the model in f32 through this kernel); bf16 is the MXU's native pass
+    return lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _dot_nt(a, b):
+    """a (m, c) · b (n, c)ᵀ -> (m, n) f32; the MXU takes the transposed
+    right operand natively."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32,
+                           precision=_precision(a.dtype))
+
+
+def _dot(a, b):
+    return lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32,
+                           precision=_precision(a.dtype))
+
+
+#: Rows of one strip of a tile. A tile is worked through in strips of rows
+#: (query rows; key rows in the dkv kernel), each against the columns it can
+#: see: all of them on a tile inside the causal triangle, the columns up to
+#: its own last row on the tile the diagonal crosses. A 1024-row diagonal
+#: tile then does 10/16 of the square's work in four [256, 256..1024]
+#: matmuls a head instead of 16/16, with no more grid steps.
+_STRIP = 256
+
+
+def _strips(rows, cols, diagonal, transposed=False):
+    """[(r0, r1, c0, c1)]: the strips of a (rows, cols) tile and the column
+    range each works on. ``transposed`` (the dkv kernel): rows are keys and
+    see the queries from their own first row on, not up to their last."""
+    h = _STRIP if rows % _STRIP == 0 else rows
+    if not diagonal:
+        return [(r, r + h, 0, cols) for r in range(0, rows, h)]
+    if transposed:
+        return [(r, r + h, r, cols) for r in range(0, rows, h)]
+    return [(r, r + h, 0, r + h) for r in range(0, rows, h)]
+
+
+def _visible(r0, r1, c0, c1, transposed=False):
+    """(r1-r0, c1-c0) bool of a strip of an aligned diagonal tile:
+    key <= query. Rows are queries and columns keys, or the reverse when
+    ``transposed``."""
+    shape = (r1 - r0, c1 - c0)
+    r = lax.broadcasted_iota(jnp.int32, shape, 0) + r0
+    c = lax.broadcasted_iota(jnp.int32, shape, 1) + c0
+    return (r <= c) if transposed else (c <= r)
+
+
+def _for_each_head(heads, body):
+    """``body(g)`` for every head of the block. A `fori_loop`, so the body is
+    traced once (every program that holds a kernel pays its trace again in
+    every run: set-up time), and unrolled when Mosaic lowers it, so the
+    heads' instructions still interleave: a real loop read 130.7 ms a step
+    in `gpt2-124m.s1024` where the unrolled body reads 127.8 (PR 30)."""
+    if heads == 1:
+        body(0)
+    else:
+        lax.fori_loop(0, heads, lambda g, carry: body(g), None, unroll=True)
+
+
+def _scores(qg, k_ref, mask_ref, strip, diagonal):
+    """Masked scores (rows, cols) f32 of one strip: ``qg`` is the query
+    block with one head's lanes kept and the scale folded in."""
+    r0, r1, c0, c1 = strip
+    s = _dot_nt(qg[r0:r1], k_ref[0, c0:c1, :])
+    if mask_ref is not None:
+        s = jnp.where(mask_ref[0, :, c0:c1] > 0, s, -jnp.inf)    # [1, cols]
+    if diagonal:
+        s = jnp.where(_visible(r0, r1, c0, c1), s, -jnp.inf)
+    return s
+
+
+def _on_causal_tiles(causal, key_block, query_block, blocks, tile):
+    """Run ``tile(diagonal)`` if this grid step's (query block, key block)
+    tile has work: always without ``causal``; with it (blocks are square
+    and aligned) the tiles strictly inside the triangle unmasked, the
+    diagonal tile masked, the rest skipped. A sequence of one block
+    (``blocks == 1``: S <= 1024) is its diagonal tile and nothing else: no
+    branch, and half the kernel body to trace and lower in every run."""
+    if not causal:
+        tile(False)
+    elif blocks == 1:
+        tile(True)
+    else:
+        pl.when(key_block < query_block)(lambda: tile(False))
+        pl.when(key_block == query_block)(lambda: tile(True))
+
+
+# ---------------------------------------------------------------------------
+# forward kernel: grid (B, H/G, Sq/bq, Sk/bk); scratch carries the online
+# softmax of each of the block's G heads
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk):
+    q_ref, k_ref, v_ref = refs[:3]
+    mask_ref = refs[3] if has_mask else None
+    o_ref, lse_ref, m_s, l_s, acc_s = refs[3 + has_mask:]
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    bq, width = q_ref.shape[1], q_ref.shape[2]
+    bk = k_ref.shape[1]
 
     @pl.when(kj == 0)
     def _init():
@@ -96,44 +269,40 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    # causal: key blocks strictly after this query block contribute nothing
-    work = (kj * bk <= qi * bq + bq - 1) if causal else True
+    def tile(diagonal):
+        def head(g):
+            qg = _head_rows(q_ref[0], g, d, heads, scale)        # [bq, W]
+            for strip in _strips(bq, bk, diagonal):
+                r0, r1, c0, c1 = strip
+                rows = slice(r0, r1)
+                s = _scores(qg, k_ref, mask_ref, strip, diagonal)  # [h, c]
+                m_prev, l_prev = m_s[g, rows], l_s[g, rows]      # [h, 128]
+                # the running max starts at a finite floor, so a row with
+                # no valid key so far keeps p = exp(-inf - floor) = 0
+                m_next = jnp.maximum(m_prev,
+                                     jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - _lanes(m_next, c1 - c0))
+                alpha = jnp.exp(m_prev - m_next)
+                l_s[g, rows] = alpha * l_prev + jnp.sum(p, axis=1,
+                                                        keepdims=True)
+                m_s[g, rows] = m_next
+                v2 = v_ref[0, c0:c1, :]
+                acc_s[g, rows] = (acc_s[g, rows] * _lanes(alpha, width)
+                                  + _dot(p.astype(v2.dtype), v2))  # [h, W]
 
-    @pl.when(work)
-    def _update():
-        q = q_ref[0].astype(jnp.float32) * scale                 # [bq, D]
-        k = k_ref[0].astype(jnp.float32)                         # [bk, D]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                        # [bq, bk]
-        valid = jnp.broadcast_to(mask_ref[0, :, 0] > 0, s.shape)
-        if causal:
-            q_pos = qi * bq + jax.lax.iota(jnp.int32, bq)
-            k_pos = kj * bk + jax.lax.iota(jnp.int32, bk)
-            valid = valid & (k_pos[None, :] <= q_pos[:, None])
-        s = jnp.where(valid, s, -jnp.inf)
-        bm = jnp.maximum(jnp.max(s, axis=-1), _NEG_BIG)          # [bq]
-        p = jnp.exp(s - bm[:, None])                             # [bq, bk]
-        m_prev = m_s[:, :1]                                      # [bq, 1]
-        m_new = jnp.maximum(m_prev, bm[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        corr = jnp.exp(bm[:, None] - m_new)
-        l_new = l_s[:, :1] * alpha + jnp.sum(p, -1, keepdims=True) * corr
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                        # [bq, D]
-        acc_s[...] = acc_s[...] * alpha + pv * corr
-        m_s[...] = _bcast_rows(m_new, bq)
-        l_s[...] = _bcast_rows(l_new, bq)
+        _for_each_head(heads, head)
+
+    _on_causal_tiles(causal, kj, qi, nk, tile)
 
     @pl.when(kj == nk - 1)
     def _flush():
-        l = jnp.maximum(l_s[:, :1], 1e-30)                       # all-masked
-        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
-        lse_ref[0, :, 0] = m_s[:, 0] + jnp.log(l[:, 0])
+        outs = []
+        for g in range(heads):
+            l = jnp.maximum(l_s[g], 1e-30)                       # all-masked
+            outs.append(acc_s[g] / _lanes(l, width))
+            lse_ref[0, 0, g:g + 1, :] = jnp.transpose(
+                m_s[g] + jnp.log(l))[:1, :]
+        o_ref[0] = _merge_heads(outs, d).astype(o_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -141,103 +310,132 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dq_s, *, scale, causal, bq, bk, nk):
-    qi, kj = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(kj == 0)
-    def _init():
-        dq_s[...] = jnp.zeros_like(dq_s)
-
-    work = (kj * bk <= qi * bq + bq - 1) if causal else True
-
-    @pl.when(work)
-    def _update():
-        q = q_ref[0].astype(jnp.float32) * scale                 # [bq, D]
-        k = k_ref[0].astype(jnp.float32)                         # [bk, D]
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)                       # [bq, D]
-        lse = lse_ref[0, :, 0]                                   # [bq]
-        delta = delta_ref[0, :, 0]                               # [bq]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        valid = jnp.broadcast_to(mask_ref[0, :, 0] > 0, s.shape)
-        if causal:
-            q_pos = qi * bq + jax.lax.iota(jnp.int32, bq)
-            k_pos = kj * bk + jax.lax.iota(jnp.int32, bk)
-            valid = valid & (k_pos[None, :] <= q_pos[:, None])
-        p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)     # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                        # [bq, bk]
-        ds = p * (dp - delta[:, None])
-        dq_s[...] = dq_s[...] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                        # [bq, D]
-
-    @pl.when(kj == nk - 1)
-    def _flush():
-        dq_ref[0] = (dq_s[...] * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_s, dv_s, *,
-                    scale, causal, bq, bk, nq):
-    ki, qi = pl.program_id(1), pl.program_id(2)
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, dk_s, dv_s, *,
+                    scale, causal, heads, d, nq):
+    """dK/dV on the transposed tile ``sᵀ = k qᵀ`` ([bk, bq]). Takes no key
+    mask: a masked key's rows of dk/dv are zeroed by the caller, and no
+    other row sees them."""
+    ki, qi = pl.program_id(2), pl.program_id(3)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(qi == 0)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    # causal: query blocks strictly before this key block contribute nothing
-    work = (qi * bq + bq - 1 >= ki * bk) if causal else True
+    def tile(diagonal):
+        def head(g):
+            kg = _head_rows(k_ref[0], g, d, heads, scale)        # [bk, W]
+            vg = _head_rows(v_ref[0], g, d, heads)
+            # strips of KEY rows; each sees the queries from its own on
+            for k0, k1, q0, q1 in _strips(bk, bq, diagonal, transposed=True):
+                keys = slice(k0, k1)
+                q2, do2 = q_ref[0, q0:q1, :], do_ref[0, q0:q1, :]
+                st = _dot_nt(kg[keys], q2)                       # [h, q]
+                if diagonal:
+                    st = jnp.where(_visible(k0, k1, q0, q1, transposed=True),
+                                   st, -jnp.inf)
+                pt = jnp.exp(st - lse_ref[0, 0, pl.ds(g, 1), q0:q1])  # [1, q]
+                dpt = _dot_nt(vg[keys], do2)
+                dst = pt * (dpt - delta_ref[0, 0, pl.ds(g, 1), q0:q1])
+                dv_s[g, keys] = (dv_s[g, keys]
+                                 + _dot(pt.astype(do2.dtype), do2))  # [h, W]
+                dk_s[g, keys] = dk_s[g, keys] + _dot(dst.astype(q2.dtype), q2)
 
-    @pl.when(work)
-    def _update():
-        k = k_ref[0].astype(jnp.float32)                         # [bk, D]
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32) * scale                 # [bq, D]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, :, 0]                                   # [bq]
-        delta = delta_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                        # [bq, bk]
-        valid = jnp.broadcast_to(mask_ref[0, :, 0] > 0, s.shape)
-        if causal:
-            q_pos = qi * bq + jax.lax.iota(jnp.int32, bq)
-            k_pos = ki * bk + jax.lax.iota(jnp.int32, bk)
-            valid = valid & (k_pos[None, :] <= q_pos[:, None])
-        p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None])
-        dv_s[...] = dv_s[...] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                        # [bk, D]
-        # q is pre-scaled: d(s)/d(k) = q_raw*scale
-        dk_s[...] = dk_s[...] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        _for_each_head(heads, head)
+
+    # causal: query blocks strictly before this key block contribute nothing
+    _on_causal_tiles(causal, ki, qi, nq, tile)
 
     @pl.when(qi == nq - 1)
     def _flush():
-        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+        dk = _merge_heads([dk_s[g] for g in range(heads)], d)
+        dv = _merge_heads([dv_s[g] for g in range(heads)], d)
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+def _dot_tn(a, b):
+    """a (c, m)ᵀ · b (c, n) -> (m, n) f32: Mosaic transposes ``a`` on the
+    XLU first."""
+    return lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32,
+                           precision=_precision(a.dtype))
+
+
+def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk):
+    """dQ of a query block, accumulated over its key blocks; ``with_kv``
+    (the fused backward): dK and dV too, from the same recomputation of each
+    P-tile — 5 matmuls and one exp a tile where a dq + dkv pair makes 7 and
+    2. dK/dV of the whole key sequence then stay in VMEM scratch for a
+    (batch, head group); ``pᵀ do`` and ``dsᵀ q`` contract the tile's rows
+    (a transposed left operand)."""
+    q_ref, k_ref, v_ref = refs[:3]
+    mask_ref = refs[3] if has_mask else None
+    do_ref, lse_ref, delta_ref, dq_ref = refs[3 + has_mask:7 + has_mask]
+    if with_kv:
+        dk_ref, dv_ref, lse_s, delta_s, dq_s, dk_s, dv_s = refs[7 + has_mask:]
+    else:
+        lse_s, delta_s, dq_s = refs[7 + has_mask:]
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    key0 = 0 if nk == 1 else pl.multiple_of(kj * bk, bk)
+
+    if with_kv:
+        @pl.when((qi == 0) & (kj == 0))
+        def _init_kv():
+            dk_s[...] = jnp.zeros_like(dk_s)
+            dv_s[...] = jnp.zeros_like(dv_s)
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_s[...] = jnp.zeros_like(dq_s)
+        for g in range(heads):  # columns once a query block, not once a tile
+            lse_s[g] = _cols(lse_ref[0, 0, g:g + 1, :])
+            delta_s[g] = _cols(delta_ref[0, 0, g:g + 1, :])
+
+    def tile(diagonal):
+        def head(g):
+            qg = _head_rows(q_ref[0], g, d, heads, scale)        # [bq, W]
+            dog = _head_rows(do_ref[0], g, d, heads)
+            for strip in _strips(bq, bk, diagonal):
+                r0, r1, c0, c1 = strip
+                rows = slice(r0, r1)
+                k2 = k_ref[0, c0:c1, :]
+                s = _scores(qg, k_ref, mask_ref, strip, diagonal)  # [h, c]
+                p = jnp.exp(s - _lanes(lse_s[g, rows], c1 - c0))
+                dp = _dot_nt(dog[rows], v_ref[0, c0:c1, :])
+                ds = (p * (dp - _lanes(delta_s[g, rows], c1 - c0))
+                      ).astype(k2.dtype)
+                dq_s[g, rows] = dq_s[g, rows] + _dot(ds, k2)     # [h, W]
+                if with_kv:
+                    keys = pl.ds(key0 + c0, c1 - c0)
+                    dv_s[g, keys] = dv_s[g, keys] + _dot_tn(
+                        p.astype(k2.dtype), do_ref[0, rows, :])  # [c, W]
+                    dk_s[g, keys] = dk_s[g, keys] + _dot_tn(
+                        ds, q_ref[0, rows, :])
+
+        _for_each_head(heads, head)
+
+    _on_causal_tiles(causal, kj, qi, nk, tile)
+
+    @pl.when(kj == nk - 1)
+    def _flush():
+        dq = _merge_heads([dq_s[g] for g in range(heads)], d)
+        dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+
+    if with_kv:
+        @pl.when((qi == nq - 1) & (kj == nk - 1))
+        def _flush_kv():
+            dk = _merge_heads([dk_s[g] for g in range(heads)], d)
+            dv = _merge_heads([dv_s[g] for g in range(heads)], d)
+            dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
-# host-side wrappers + custom VJP over [BH, S, D]
+# host-side wrappers + custom VJP over [B, S, H·D]
 # ---------------------------------------------------------------------------
 
 
@@ -249,22 +447,33 @@ def _sublane_multiple(dtype) -> int:
     return {32: 8, 16: 16, 8: 32}.get(bits, 8)
 
 
-def _pick_block(s: int, pref: int = 128, dtype=jnp.float32) -> int:
+#: Preferred sequence block of every kernel: S=1024 is one grid step a head
+#: group, worked through in `_STRIP`-row strips. At (16, 1024, 12, 64) causal
+#: bf16 forward + backward on a v5e (scripts/flash_ab.py, PR 30): whole
+#: masked tiles of 256 / 512 / 1024 rows read 3.97 / 3.04 / 3.07 ms, so the
+#: cost of a tile is mostly per tile and per row, not per score.
+_BLOCK = 1024
+
+
+def _pick_block(s: int, pref: Optional[int] = None,
+                dtype=jnp.float32) -> int:
     """Largest divisor of ``s`` that is <= ``pref`` by halving — refusing
     blocks below the dtype's native sublane tile (a bf16 operand blocked
     at 8 rows passes the naive %8 rule but mis-tiles on chip; the CPU
-    interpreter would never notice)."""
-    b = min(s, pref)
+    interpreter would never notice). The per-row statistics travel with
+    rows along lanes, so a block that is not the whole sequence must also
+    be a multiple of 128."""
+    b = min(s, pref or _BLOCK)
     while s % b:
         b //= 2
     b = max(b, 1)
     need = _sublane_multiple(dtype)
-    if b != s and b % need:
+    if b != s and (b % need or b % _LANES):
         raise ValueError(
             f"flash attention: sequence length {s} only tiles into "
             f"{b}-row blocks, below the {jnp.dtype(dtype).name} native "
-            f"sublane tile ({need}); pad the sequence to a multiple of "
-            f"{need} (ideally {pref})"
+            f"sublane tile ({need}) or the 128-lane row of the row "
+            f"statistics; pad the sequence to a multiple of {_LANES}"
         )
     return b
 
@@ -306,81 +515,239 @@ def _check_specs(specs, arrays) -> None:
         check_mosaic_block(tuple(spec.block_shape), tuple(shape), dtype)
 
 
-def _k_index_map(causal, bq, bk):
-    """K/V/mask index map for the (b, qi, kj) grids. Causal grids still
-    step through every (qi, kj) pair, but blocks past the diagonal are
-    ``pl.when``-skipped — clamping the fetch index to the last contributing
-    block means those steps re-request the block already in the window, so
-    Mosaic issues no DMA for them (halves causal K/V traffic)."""
-    if not causal:
-        return lambda b, i, j: (b, j, 0)
-    return lambda b, i, j: (b, jnp.minimum(j, (i * bq + bq - 1) // bk), 0)
+def _group_width(heads: int, d: int) -> int:
+    """Lanes of one block along ``H·D``: one head if it fills whole lane
+    rows, else as many heads as make up 128 lanes, else (a width 128 does
+    not divide) all of them — a block as wide as the array is always legal."""
+    if d % _LANES == 0:
+        return d
+    if _LANES % d == 0 and (heads * d) % _LANES == 0:
+        return _LANES
+    return heads * d
 
 
-def _q_index_map_dkv(causal, bq, bk):
-    """q/do/lse/delta index map for the dkv (b, kj, qi) grids: clamp UP to
-    the first contributing query block (see `_k_index_map`)."""
-    if not causal:
-        return lambda b, j, i: (b, i, 0)
-    return lambda b, j, i: (b, jnp.maximum(i, (j * bk) // bq), 0)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash(q, k, v, kv_mask, scale, causal):
-    o, _ = _flash_fwd_impl(q, k, v, kv_mask, scale, causal)
-    return o
-
-
-def _flash_fwd_impl(q, k, v, kv_mask, scale, causal, out_dtype=None):
-    bh, sq, d = q.shape
-    sk = k.shape[1]
+def _blocks(q, k, causal):
+    sq, sk = q.shape[1], k.shape[1]
+    if causal and sq != sk:
+        raise ValueError(
+            f"causal flash attention needs equal query and key lengths "
+            f"(square aligned tiles), got {sq} and {sk}")
     bq = _pick_block(sq, dtype=q.dtype)
-    bk = _pick_block(sk, dtype=k.dtype)
-    grid = (bh, sq // bq, sk // bk)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=sk // bk
-    )
-    kmap = _k_index_map(causal, bq, bk)
-    in_specs = [
-        pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),   # q
-        pl.BlockSpec((1, bk, d), kmap),                        # k
-        pl.BlockSpec((1, bk, d), kmap),                        # v
-        pl.BlockSpec((1, bk, 1), kmap),                        # mask
+    bk = bq if causal else _pick_block(sk, dtype=k.dtype)
+    return bq, bk
+
+
+def _rows_spec(heads_per_block, block, index_map):
+    """Spec of a per-row statistic ``[B, H/G, G, S]`` (rows along lanes)."""
+    return pl.BlockSpec((1, 1, heads_per_block, block), index_map)
+
+
+def _inner_clamped(causal, outer_first: bool):
+    """Index of the inner (reduction) block a grid step fetches. Causal
+    grids still step through every (outer, inner) pair, but tiles past the
+    diagonal are ``pl.when``-skipped — clamping the fetch index to the
+    diagonal block means those steps re-request the block already in the
+    window, so Mosaic issues no DMA for them. ``outer_first``: the inner
+    blocks run 0..outer (keys under a query block); else outer..n (queries
+    under a key block)."""
+    if not causal:
+        return lambda outer, inner: inner
+    if outer_first:
+        return lambda outer, inner: jnp.minimum(inner, outer)
+    return lambda outer, inner: jnp.maximum(inner, outer)
+
+
+def _query_major(q, k, v, kv_mask, heads, causal):
+    """What the kernels whose grid is (B, H/G, Sq/bq, Sk/bk) share: the
+    geometry, and specs / shapes / operands of q, k, v and the key mask."""
+    b, sq, hd = q.shape
+    sk, d = k.shape[1], hd // heads
+    width = _group_width(heads, d)
+    bq, bk = _blocks(q, k, causal)
+    kj = _inner_clamped(causal, outer_first=True)
+    q_spec = pl.BlockSpec((1, bq, width), lambda b, g, i, j: (b, i, g))
+    k_spec = pl.BlockSpec((1, bk, width),
+                          lambda b, g, i, j: (b, kj(i, j), g))
+    in_specs = [q_spec, k_spec, k_spec]
+    arrays = [(q.shape, q.dtype), (k.shape, k.dtype), (v.shape, v.dtype)]
+    operands = [q, k, v]
+    if kv_mask is not None:
+        in_specs.append(
+            pl.BlockSpec((1, 1, bk), lambda b, g, i, j: (b, 0, kj(i, j))))
+        arrays.append(((b, 1, sk), jnp.int32))
+        operands.append(kv_mask.astype(jnp.int32)[:, None, :])
+    geometry = dict(
+        d=d, width=width, hpb=width // d, groups=hd // width, bq=bq,
+        nq=sq // bq, nk=sk // bk, grid=(b, hd // width, sq // bq, sk // bk),
+        q_spec=q_spec,
+        rows=_rows_spec(width // d, bq, lambda b, g, i, j: (b, g, 0, i)))
+    return geometry, in_specs, arrays, operands
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "scale", "causal", "out_dtype", "interpret"))
+def _fwd_call(q, k, v, kv_mask, *, heads, scale, causal, out_dtype,
+              interpret):
+    """(o ``[B, Sq, H·D]``, lse ``[B, H/G, G, Sq]``). One jitted function
+    a shape, so a model's layers lower one Pallas body between them."""
+    geo, in_specs, arrays, operands = _query_major(q, k, v, kv_mask, heads,
+                                                   causal)
+    hpb, bq, width = geo["hpb"], geo["bq"], geo["width"]
+    out_specs = [geo["q_spec"], geo["rows"]]
+    out_shape = [
+        jax.ShapeDtypeStruct(q.shape, out_dtype),
+        jax.ShapeDtypeStruct((q.shape[0], geo["groups"], hpb, q.shape[1]),
+                             jnp.float32),
     ]
-    out_specs = [
-        pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-    ]
-    out_o_dtype = out_dtype or q.dtype
-    _check_specs(
-        in_specs + out_specs,
-        [((bh, sq, d), q.dtype), ((bh, sk, d), k.dtype),
-         ((bh, sk, d), v.dtype), ((bh, sk, 1), kv_mask.dtype),
-         ((bh, sq, d), out_o_dtype), ((bh, sq, 1), jnp.float32)],
-    )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+    _check_specs(in_specs + out_specs,
+                 arrays + [(o.shape, o.dtype) for o in out_shape])
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          has_mask=kv_mask is not None, heads=hpb,
+                          d=geo["d"], nk=geo["nk"]),
+        grid=geo["grid"],
         in_specs=in_specs,
         out_specs=out_specs,
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), out_dtype or q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # running max m
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # running denom l
-            pltpu.VMEM((bq, d), jnp.float32),        # output accumulator
+            pltpu.VMEM((hpb, bq, _LANES), jnp.float32),   # running max m
+            pltpu.VMEM((hpb, bq, _LANES), jnp.float32),   # running denom l
+            pltpu.VMEM((hpb, bq, width), jnp.float32),    # output accumulator
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
-    )(q, k, v, kv_mask[:, :, None])
-    return o, lse[:, :, 0]
+    )(*operands)
 
 
-def _flash_fwd(q, k, v, kv_mask, scale, causal):
-    o, lse = _flash_fwd_impl(q, k, v, kv_mask, scale, causal)
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "scale", "causal", "out_dtype", "with_kv", "interpret"))
+def _bwd_call(q, k, v, kv_mask, do, lse, delta, *, heads, scale, causal,
+              out_dtype, with_kv, interpret):
+    """dQ ``[B, Sq, H·D]`` given the GLOBAL ``lse``/``delta``
+    (``[B, H/G, G, Sq]``); ``with_kv``: (dQ, dK, dV) from the one fused
+    kernel."""
+    geo, in_specs, arrays, operands = _query_major(q, k, v, kv_mask, heads,
+                                                   causal)
+    hpb, bq, width = geo["hpb"], geo["bq"], geo["width"]
+    in_specs += [geo["q_spec"], geo["rows"], geo["rows"]]
+    arrays += [(do.shape, do.dtype), (lse.shape, lse.dtype),
+               (delta.shape, delta.dtype)]
+    out_specs = [geo["q_spec"]]
+    out_shape = [jax.ShapeDtypeStruct(q.shape, out_dtype)]
+    scratch = [
+        pltpu.VMEM((hpb, bq, _LANES), jnp.float32),       # lse, as columns
+        pltpu.VMEM((hpb, bq, _LANES), jnp.float32),       # delta, as columns
+        pltpu.VMEM((hpb, bq, width), jnp.float32),        # dq accumulator
+    ]
+    if with_kv:
+        sk = k.shape[1]
+        kv_spec = pl.BlockSpec((1, sk, width), lambda b, g, i, j: (b, 0, g))
+        out_specs += [kv_spec, kv_spec]
+        out_shape += [jax.ShapeDtypeStruct(k.shape, out_dtype),
+                      jax.ShapeDtypeStruct(v.shape, out_dtype)]
+        scratch += [pltpu.VMEM((hpb, sk, width), jnp.float32)] * 2  # dk, dv
+    _check_specs(in_specs + out_specs,
+                 arrays + [(o.shape, o.dtype) for o in out_shape])
+    out = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                          has_mask=kv_mask is not None, with_kv=with_kv,
+                          heads=hpb, d=geo["d"], nq=geo["nq"], nk=geo["nk"]),
+        grid=geo["grid"],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        interpret=interpret,
+        # dK/dV accumulate across query blocks too: both block dims in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_COMPILER_PARAMS.vmem_limit_bytes),
+    )(*operands, do, lse, delta)
+    return out if with_kv else out[0]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "scale", "causal", "out_dtype", "interpret"))
+def _dkv_call(q, k, v, kv_mask, do, lse, delta, *, heads, scale, causal,
+              out_dtype, interpret):
+    """(dK, dV) ``[B, Sk, H·D]`` given the GLOBAL ``lse``/``delta``."""
+    b, sq, hd = q.shape
+    sk, d = k.shape[1], hd // heads
+    width = _group_width(heads, d)
+    hpb = width // d
+    bq, bk = _blocks(q, k, causal)
+    nq = sq // bq
+    qi = _inner_clamped(causal, outer_first=False)
+    q_spec = pl.BlockSpec((1, bq, width),
+                          lambda b, g, j, i: (b, qi(j, i), g))
+    k_spec = pl.BlockSpec((1, bk, width), lambda b, g, j, i: (b, j, g))
+    rows = _rows_spec(hpb, bq, lambda b, g, j, i: (b, g, 0, qi(j, i)))
+    in_specs = [q_spec, k_spec, k_spec, q_spec, rows, rows]
+    out_shape = [jax.ShapeDtypeStruct(k.shape, out_dtype),
+                 jax.ShapeDtypeStruct(v.shape, out_dtype)]
+    _check_specs(
+        in_specs + [k_spec, k_spec],
+        [(q.shape, q.dtype), (k.shape, k.dtype), (v.shape, v.dtype),
+         (do.shape, do.dtype), (lse.shape, lse.dtype),
+         (delta.shape, delta.dtype)]
+        + [(o.shape, o.dtype) for o in out_shape])
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+                          heads=hpb, d=d, nq=nq),
+        grid=(b, hd // width, sk // bk, nq),
+        in_specs=in_specs,
+        out_specs=[k_spec, k_spec],
+        out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((hpb, bk, width), jnp.float32),    # dk accumulator
+            pltpu.VMEM((hpb, bk, width), jnp.float32),    # dv accumulator
+        ],
+        interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
+    )(q, k, v, do, lse, delta)
+    if kv_mask is not None:
+        # a masked key has p = 0 in every row: its gradient rows are zero.
+        # The kernel does not look at the mask (its tile is transposed, the
+        # mask would be a lane-sparse column); what it computed for those
+        # rows, inf and NaN included, is dropped here
+        keep = (kv_mask > 0)[:, :, None]
+        dk, dv = jnp.where(keep, dk, 0), jnp.where(keep, dv, 0)
+    return dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash(q, k, v, kv_mask, heads, scale, causal):
+    """Attention over ``[B, S, H·D]`` operands (``kv_mask`` ``[B, Sk]`` or
+    None)."""
+    return _flash_fwd(q, k, v, kv_mask, heads, scale, causal)[0]
+
+
+def _flash_fwd(q, k, v, kv_mask, heads, scale, causal):
+    o, lse = _fwd_call(q, k, v, kv_mask, heads=heads, scale=scale,
+                       causal=causal, out_dtype=q.dtype,
+                       interpret=_interpret())
     return o, (q, k, v, kv_mask, o, lse)
+
+
+def _flash_bwd(heads, scale, causal, res, do):
+    q, k, v, kv_mask, o, lse = res
+    b, sq, hd = q.shape
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(b, sq, heads, hd // heads), axis=-1)
+    delta = delta.transpose(0, 2, 1).reshape(lse.shape)
+    dq, dk, dv = _bwd_call(q, k, v, kv_mask, do, lse, delta, heads=heads,
+                           scale=scale, causal=causal, out_dtype=q.dtype,
+                           with_kv=True, interpret=_interpret())
+    return dq, dk, dv, None
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _folded_rows(x):
+    """``[BH, S]`` statistic of the folded API -> ``[BH, 1, 1, S]``."""
+    return x[:, None, None, :]
 
 
 def flash_pair_fwd(q, k, v, kv_mask, scale, causal, out_dtype=None):
@@ -388,8 +755,10 @@ def flash_pair_fwd(q, k, v, kv_mask, scale, causal, out_dtype=None):
     operands — ring attention's per-step forward building block.
     ``out_dtype`` (default: q's dtype) lets the ring keep the per-block
     contributions in fp32 for its cross-block accumulation."""
-    return _flash_fwd_impl(q, k, v, kv_mask, scale, causal,
-                           out_dtype=out_dtype)
+    o, lse = _fwd_call(q, k, v, kv_mask, heads=1, scale=scale, causal=causal,
+                       out_dtype=jnp.dtype(out_dtype or q.dtype),
+                       interpret=_interpret())
+    return o, lse[:, 0, 0, :]
 
 
 def flash_pair_dq(q, k, v, kv_mask, do, lse, delta, scale, causal,
@@ -397,103 +766,22 @@ def flash_pair_dq(q, k, v, kv_mask, do, lse, delta, scale, causal,
     """dQ for one (q-block, k-block) pair given GLOBAL ``lse``/``delta``
     (folded ``[BH, S, D]`` operands). This is the flash backward's dq leg;
     exposed separately so ring attention can run it per ring step."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    bq = _pick_block(sq, dtype=q.dtype)
-    bk = _pick_block(sk, dtype=k.dtype)
-    kmap = _k_index_map(causal, bq, bk)
-    in_specs = [
-        pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),   # q
-        pl.BlockSpec((1, bk, d), kmap),                        # k
-        pl.BlockSpec((1, bk, d), kmap),                        # v
-        pl.BlockSpec((1, bk, 1), kmap),                        # mask
-        pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),   # do
-        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),   # lse
-        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),   # delta
-    ]
-    out_specs = [pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))]
-    _check_specs(
-        in_specs + out_specs,
-        [((bh, sq, d), q.dtype), ((bh, sk, d), k.dtype),
-         ((bh, sk, d), v.dtype), ((bh, sk, 1), kv_mask.dtype),
-         ((bh, sq, d), do.dtype), ((bh, sq, 1), jnp.float32),
-         ((bh, sq, 1), jnp.float32),
-         ((bh, sq, d), out_dtype or q.dtype)],
-    )
-    return pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nk=sk // bk),
-        grid=(bh, sq // bq, sk // bk),
-        in_specs=in_specs,
-        out_specs=out_specs[0],
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), out_dtype or q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=_interpret(),
-        compiler_params=_COMPILER_PARAMS,
-    )(q, k, v, kv_mask[:, :, None], do, lse[:, :, None],
-      delta[:, :, None])
+    return _bwd_call(q, k, v, kv_mask, do, _folded_rows(lse),
+                     _folded_rows(delta), heads=1, scale=scale,
+                     causal=causal, with_kv=False,
+                     out_dtype=jnp.dtype(out_dtype or q.dtype),
+                     interpret=_interpret())
 
 
 def flash_pair_dkv(q, k, v, kv_mask, do, lse, delta, scale, causal,
                    out_dtype=None):
     """dK/dV for one (q-block, k-block) pair given GLOBAL ``lse``/``delta``
     (see `flash_pair_dq`)."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    bq = _pick_block(sq, dtype=q.dtype)
-    bk = _pick_block(sk, dtype=k.dtype)
-    qmap = _q_index_map_dkv(causal, bq, bk)
-    in_specs = [
-        pl.BlockSpec((1, bq, d), qmap),                        # q
-        pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),   # k
-        pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),   # v
-        pl.BlockSpec((1, bk, 1), lambda b, j, i: (b, j, 0)),   # mask
-        pl.BlockSpec((1, bq, d), qmap),                        # do
-        pl.BlockSpec((1, bq, 1), qmap),                        # lse
-        pl.BlockSpec((1, bq, 1), qmap),                        # delta
-    ]
-    out_specs = [
-        pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-    ]
-    _check_specs(
-        in_specs + out_specs,
-        [((bh, sq, d), q.dtype), ((bh, sk, d), k.dtype),
-         ((bh, sk, d), v.dtype), ((bh, sk, 1), kv_mask.dtype),
-         ((bh, sq, d), do.dtype), ((bh, sq, 1), jnp.float32),
-         ((bh, sq, 1), jnp.float32),
-         ((bh, sk, d), out_dtype or k.dtype),
-         ((bh, sk, d), out_dtype or v.dtype)],
-    )
-    return pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nq=sq // bq),
-        grid=(bh, sk // bk, sq // bq),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), out_dtype or k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), out_dtype or v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        interpret=_interpret(),
-        compiler_params=_COMPILER_PARAMS,
-    )(q, k, v, kv_mask[:, :, None], do, lse[:, :, None],
-      delta[:, :, None])
-
-
-def _flash_bwd(scale, causal, res, do):
-    q, k, v, kv_mask, o, lse = res
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    dq = flash_pair_dq(q, k, v, kv_mask, do, lse, delta, scale, causal)
-    dk, dv = flash_pair_dkv(q, k, v, kv_mask, do, lse, delta, scale, causal)
-    return dq, dk, dv, None
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
+    return _dkv_call(q, k, v, kv_mask, do, _folded_rows(lse),
+                     _folded_rows(delta), heads=1, scale=scale,
+                     causal=causal,
+                     out_dtype=jnp.dtype(out_dtype or k.dtype),
+                     interpret=_interpret())
 
 
 def flash_attention(
@@ -508,31 +796,22 @@ def flash_attention(
     """Tiled exact attention over ``[B, S, H, D]`` inputs.
 
     ``kv_mask``: optional key-validity mask ``[B, S_k]`` (True = attend).
-    Differentiable (flash backward). Sequence lengths must divide by the
-    chosen block (128 or the largest power-of-two divisor).
+    Differentiable (flash backward). ``causal`` needs ``S_q == S_k``.
 
-    Sequence-length constraint (dtype-dependent): the block picked by
-    halving 128 down to a divisor of ``S`` must be at least the dtype's
-    native sublane tile — 8 rows for f32, **16 for bf16/f16**, 32 for
-    8-bit types. A length whose largest such divisor falls below the tile
-    (e.g. ``S=136`` in bf16: largest halving divisor 8) raises
-    ``ValueError`` at trace time on every backend, because on a real TPU
-    that block would mis-tile; pad the sequence to a multiple of 16
-    (ideally 128). ``S`` at or below the preferred block (one block total)
-    is always legal.
+    Sequence-length constraint: a sequence of at most 512 rows is one
+    block and always legal (``S_q = 1`` decode included); a longer one is
+    tiled by halving 512 down to a divisor of ``S``, which must be a
+    multiple of 128 (the row statistics travel with rows along lanes) —
+    else ``ValueError`` at trace time on every backend, because on a real
+    TPU that block would mis-tile; pad the sequence to a multiple of 128.
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
-    scale = D ** -0.5 if scale is None else scale
-    if kv_mask is None:
-        kv_mask = jnp.ones((B, Sk), jnp.int32)
-    # [B,S,H,D] -> [B*H, S, D]; mask -> [B*H, Sk]
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
-
-    mask_bh = jnp.repeat(kv_mask.astype(jnp.int32), H, axis=0)
-    o = _flash(fold(q), fold(k), fold(v), mask_bh, scale, causal)
-    return o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    scale = float(D ** -0.5 if scale is None else scale)
+    # [B,S,H,D] -> [B,S,H·D] is free: no transpose surrounds the kernels
+    o = _flash(q.reshape(B, Sq, H * D), k.reshape(B, Sk, H * D),
+               v.reshape(B, Sk, H * D), kv_mask, H, scale, causal)
+    return o.reshape(B, Sq, H, D)
 
 
 def make_flash_attention_impl():

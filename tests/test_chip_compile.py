@@ -79,8 +79,9 @@ def test_perf_model_knows_the_described_device(topo):
     assert perf_model.device_peak_flops(topo.devices[0]) == 197e12
 
 
-# GPT-2 124M attention at S=1024 (causal) and BERT-Base at S=128
-@pytest.mark.parametrize("shape,causal", [((8, 1024, 12, 64), True),
+# GPT-2 124M attention at S=1024 (causal; the benchmark cell's batch) and
+# BERT-Base at S=128
+@pytest.mark.parametrize("shape,causal", [((16, 1024, 12, 64), True),
                                           ((32, 128, 12, 64), False)])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_kernel_compiles_for_v5e(compiled_kernels, one_chip, shape,
@@ -91,15 +92,59 @@ def test_flash_kernel_compiles_for_v5e(compiled_kernels, one_chip, shape,
         argnums=(0, 1, 2))
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     text = jax.jit(fn).lower(x, x, x).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert text.count(KERNEL) == (1 if direction == "fwd" else 2)
 
 
-def test_dear_step_compiles_for_four_v5e_chips(mesh4):
+#: a Pallas kernel in optimized HLO text
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.mark.parametrize("attn_dropout,kernels", [(0.0, 4), (0.1, 0)],
+                         ids=["no-dropout", "attn-dropout"])
+def test_default_gpt2_step_selects_the_kernel(compiled_kernels, one_chip,
+                                              attn_dropout, kernels):
+    """GPT-2 at published widths, two layers, S=1024, no ``attention_impl``
+    passed: the default core puts a forward and a fused backward kernel into
+    each layer of the gradient program, both under an ``attention`` scope
+    (what `attention_core_ms` and `attention_kernel_calls_per_step` read);
+    with GPT-2's published ``attn_pdrop`` it stays the dense program."""
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        chip_smoke.gpt2_config(jnp.bfloat16, num_layers=2),
+        attention_probs_dropout_prob=attn_dropout)
+    model = chip_smoke.models.GptLmHeadModel(cfg)
+    ids = jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda key: model.init({"params": key}, jnp.zeros((1, 1024),
+                                                          jnp.int32),
+                               train=False)["params"],
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        params)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+
+    def loss(p, ids, rng):
+        logits = model.apply({"params": p}, ids, train=True,
+                             rngs={"dropout": rng})
+        return chip_smoke.models.gpt_lm_loss(logits, ids,
+                                             vocab_size=cfg.vocab_size)
+
+    text = jax.jit(jax.grad(loss)).lower(params, ids, rng).compile().as_text()
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    assert len(calls) == kernels
+    assert all("/attention/" in line for line in calls)
+    assert sum("transpose(jvp(" in line for line in calls) == kernels // 2
+
+
+def test_dear_step_compiles_for_four_v5e_chips(compiled_kernels, mesh4):
     """A 2-layer full-width GPT-2 `dear` step, lowered from shapes alone on
     the described mesh: the program as written asks for reduce-scatter and
     all-gather. What XLA:TPU keeps is pinned as observed on this topology
     (PERF.md, PR 24): the parameter all-gathers survive, every gradient
-    reduce-scatter is rewritten into a combined all-reduce + slice."""
+    reduce-scatter is rewritten into a combined all-reduce + slice. The
+    default attention core's kernels compile inside the `shard_map`."""
     model, loss_fn = chip_smoke.make_loss(
         chip_smoke.gpt2_config(jnp.bfloat16, num_layers=2))
     params = jax.eval_shape(
@@ -124,6 +169,7 @@ def test_dear_step_compiles_for_four_v5e_chips(mesh4):
     assert "stablehlo.reduce_scatter" in asked
     assert "stablehlo.all_gather" in asked
     compiled = lowered.compile()
+    assert compiled.as_text().count(KERNEL) == 4      # 2 layers x (fwd + bwd)
     kept = chip_smoke.count_collectives(compiled.as_text())
     assert kept.get("all-gather", 0) >= 1, kept
     assert kept.get("reduce-scatter", 0) + kept.get("all-reduce", 0) >= 1, kept

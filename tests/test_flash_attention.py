@@ -2,6 +2,8 @@
 backward, causal and padded, f32 and bf16. Runs the EXACT kernel code via
 interpret mode on the CPU test mesh."""
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,10 @@ from dear_pytorch_tpu.ops.flash_attention import (
     make_flash_attention_impl,
 )
 from dear_pytorch_tpu.parallel.ring_attention import full_attention
+
+# by module name: `dear_pytorch_tpu.ops` re-exports a `flash_attention`
+# FUNCTION that shadows the module attribute
+FA = sys.modules["dear_pytorch_tpu.ops.flash_attention"]
 
 B, S, H, D = 2, 64, 4, 16
 
@@ -180,17 +186,191 @@ def test_mosaic_block_rule():
 
 
 def test_wrappers_reject_mosaic_illegal_blocks():
-    """An odd sequence length that forces a tiny sub-tile query block must
-    be rejected at trace time on every backend, not at Mosaic lowering on
-    the chip."""
+    """A sequence longer than one block whose only divisors are tiny
+    sub-tile blocks must be rejected at trace time on every backend, not
+    at Mosaic lowering on the chip."""
     rng = jax.random.PRNGKey(0)
-    # S=132 -> largest halving divisor is 4 (132 = 4*33): below every
+    # S=1028 -> largest halving divisor is 4 (1028 = 4*257): below every
     # dtype's sublane tile
-    q = jax.random.normal(rng, (2, 132, 2, 8), jnp.float32)
+    q = jax.random.normal(rng, (1, 1028, 2, 8), jnp.float32)
     with pytest.raises(ValueError, match="sublane tile"):
         flash_attention(q, q, q)
-    # the ADVICE.md round-4 scenario: S=136 = 8*17 tiles to 8-row blocks,
-    # which PASSES the naive %8 rule but mis-tiles bf16 on real chips
-    qb = jax.random.normal(rng, (2, 136, 2, 8)).astype(jnp.bfloat16)
+    # S=1040 = 16*65 tiles to 16-row blocks: a whole bf16 sublane tile, but
+    # no multiple of the 128 lanes the row statistics travel along
+    qb = jax.random.normal(rng, (1, 1040, 2, 8)).astype(jnp.bfloat16)
     with pytest.raises(ValueError, match="sublane tile"):
         flash_attention(qb, qb, qb)
+    # at most one block of rows is always legal (S=132 = 4*33 included)
+    q = jax.random.normal(rng, (1, 132, 2, 8), jnp.float32)
+    assert flash_attention(q, q, q).shape == q.shape
+
+
+def test_causal_needs_square_tiles():
+    q = jnp.zeros((1, 64, 2, 8))
+    k = jnp.zeros((1, 32, 2, 8))
+    with pytest.raises(ValueError, match="equal query and key lengths"):
+        flash_attention(q, k, k, causal=True)
+
+
+# ---------------------------------------------------------------------------
+# the tiled grid: blocks, head groups, masks, dtypes
+# ---------------------------------------------------------------------------
+
+#: docs/KERNELS.md: bf16 agrees with dense f32 to 5e-2 absolute (the chip
+#: read 7.8e-3 .. 3.1e-2); f32 to summation order
+TOL = {jnp.float32: dict(out=dict(rtol=2e-5, atol=2e-5),
+                         grad=dict(rtol=5e-4, atol=5e-5)),
+       jnp.bfloat16: dict(out=dict(rtol=0, atol=5e-2),
+                          grad=dict(rtol=0, atol=5e-2))}
+
+
+@pytest.fixture
+def blocks_of_128(monkeypatch):
+    """128-row blocks, so that a 256- or 384-row sequence runs the kernels'
+    whole grid at a size the interpreter finishes: tiles under the
+    diagonal (unmasked), on it (masked) and past it (skipped), the scratch
+    carried from block to block. The jitted calls are keyed on shapes, not
+    on the block, so the caches go before and after."""
+    monkeypatch.setattr(FA, "_BLOCK", 128)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _dense(q, k, v, causal, kv_mask):
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+    if causal:
+        tri = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
+        s = jnp.where(tri[None, None], s, -jnp.inf)
+    if kv_mask is not None:
+        s = jnp.where(kv_mask[:, None, None, :], s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _check_against_dense(shape, mode, dtype, key=0):
+    b, s, h, d = shape
+    ks = jax.random.split(jax.random.PRNGKey(key), 4)
+    q, k, v = (jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+               for kk in ks[:3])
+    w = jax.random.normal(ks[3], shape, jnp.float32)   # a generic cotangent
+    causal = "causal" in mode
+    kv_mask = None
+    if "kv_mask" in mode:   # the first key stays valid: no empty causal row
+        kv_mask = (jnp.arange(s)[None, :]
+                   < jnp.array([[s - 37], [s // 2 + 5]][:b])) | (
+                       jnp.arange(s)[None, :] == 0)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32) * w)
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=causal, kv_mask=kv_mask)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    dense = lambda q, k, v: _dense(q, k, v, causal, kv_mask)  # noqa: E731
+    got = flash(q, k, v)
+    assert got.dtype == dtype
+    tol = TOL[dtype]
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(dense(*f32)), **tol["out"])
+    got_g = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want_g = jax.grad(loss(dense), argnums=(0, 1, 2))(*f32)
+    for g, wnt, name in zip(got_g, want_g, "qkv"):
+        assert g.dtype == dtype
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(wnt), err_msg=f"d{name}",
+                                   **tol["grad"])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("mode", ["causal", "kv_mask", "causal+kv_mask"])
+@pytest.mark.parametrize("shape", [
+    (1, 256, 2, 64),     # GPT-2's layout: two 64-wide heads a 128-lane block
+    (2, 256, 1, 128),    # one head a block
+    (1, 256, 4, 16),     # H·D = 64 < 128 lanes: all four heads in one block
+], ids=["2x64", "1x128", "4x16"])
+def test_tiled_grid_matches_dense(blocks_of_128, shape, mode, dtype):
+    _check_against_dense(shape, mode, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("mode", ["causal", "causal+kv_mask"])
+def test_diagonal_tile_in_strips(mode, dtype):
+    """S=512 at the default block is one diagonal tile of two 256-row
+    strips: the first sees 256 keys, the second 512 (and in the dkv kernel
+    the first key strip 512 queries, the second 256)."""
+    _check_against_dense((1, 512, 2, 64), mode, dtype, key=2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("mode", ["causal", "kv_mask"])
+def test_sequence_below_one_block(mode, dtype):
+    """S=40 is one 40-row block, whatever the preferred block."""
+    _check_against_dense((2, 40, 2, 64), mode, dtype, key=1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_single_query_row_over_a_masked_cache(dtype):
+    """The decode tick: one query row over a key cache with a validity
+    mask (`serving.kvcache.cache_attend`, ``use_flash``)."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (3, 1, 4, 32), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (3, 96, 4, 32), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (3, 96, 4, 32), jnp.float32).astype(dtype)
+    valid = jnp.arange(96)[None, :] < jnp.array([[1], [50], [96]])
+    got = flash_attention(q, k, v, kv_mask=valid)
+    want = _dense(*(x.astype(jnp.float32) for x in (q, k, v)), False, valid)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               **TOL[dtype]["out"])
+
+
+def test_block_choice():
+    """Blocks follow from (S, dtype) alone: 1024 rows where S allows, the
+    whole sequence below that, 128-multiples by halving in between; a tile
+    is worked through in 256-row strips, each over the columns it sees."""
+    pick = FA._pick_block
+    assert [pick(s) for s in (1, 40, 512, 1024, 2048, 1536, 1152, 384)] == [
+        1, 40, 512, 1024, 1024, 512, 128, 384]
+    assert FA._strips(1024, 1024, False) == [
+        (0, 256, 0, 1024), (256, 512, 0, 1024), (512, 768, 0, 1024),
+        (768, 1024, 0, 1024)]
+    assert FA._strips(512, 512, True) == [(0, 256, 0, 256),
+                                          (256, 512, 0, 512)]
+    assert FA._strips(512, 512, True, transposed=True) == [
+        (0, 256, 0, 512), (256, 512, 256, 512)]
+    assert FA._strips(384, 384, True) == [(0, 384, 0, 384)]
+    assert FA._group_width(12, 64) == 128      # two heads a block
+    assert FA._group_width(8, 128) == 128      # one
+    assert FA._group_width(4, 16) == 64        # H·D < 128: one block
+    assert FA._group_width(3, 64) == 192       # 128 does not divide H·D
+    assert FA._group_width(1, 64) == 64        # the folded (ring) API
+
+
+def test_pair_kernels_agree_with_the_fused_backward():
+    """Ring attention's entry points (`flash_pair_fwd/dq/dkv`: separate dq
+    and transposed dkv kernels over folded ``[BH, S, D]``) give the
+    gradients `flash_attention`'s fused backward kernel gives, on a
+    diagonal tile of two strips with a key mask."""
+    bh, s, d = 2, 512, 64
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, do = (jax.random.normal(kk, (bh, s, d), jnp.float32)
+                   for kk in ks)
+    mask = (jnp.arange(s)[None, :] < jnp.array([[s], [s - 100]]))
+    scale = d ** -0.5
+    o, lse = FA.flash_pair_fwd(q, k, v, mask, scale, True)
+    delta = jnp.sum(do * o, axis=-1)
+    dq = FA.flash_pair_dq(q, k, v, mask, do, lse, delta, scale, True)
+    dk, dv = FA.flash_pair_dkv(q, k, v, mask, do, lse, delta, scale, True)
+
+    def fused(q, k, v):   # [BH,S,D] as batch BH of one head
+        out = flash_attention(q[:, :, None], k[:, :, None], v[:, :, None],
+                              causal=True, kv_mask=mask)
+        return jnp.sum(out[:, :, 0] * do)
+
+    want = jax.grad(fused, argnums=(0, 1, 2))(q, k, v)
+    for got, w, name in zip((dq, dk, dv), want, "qkv"):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(w),
+                                   rtol=5e-4, atol=5e-5, err_msg=f"d{name}")

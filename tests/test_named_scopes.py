@@ -170,6 +170,43 @@ def test_checkpointed_and_flash_impls_sit_under_attention():
         assert "attention" in _scopes_in(text), impl
 
 
+def test_default_core_kernels_sit_under_attention(monkeypatch):
+    """On a TPU the default GPT core is two Pallas kernels a layer (forward
+    and fused backward), called under the ``attention`` scope of the forward
+    (``jvp``) and the backward (``transpose(jvp)``) pass; the layers share
+    one lowered body a kernel. Read off the step as lowered FOR a TPU from
+    here (no chip, no TPU compiler: tests/test_chip_compile.py compiles)."""
+    monkeypatch.setattr(
+        sys.modules["dear_pytorch_tpu.ops.flash_attention"], "_interpret",
+        lambda: False)
+    cfg = models.GptConfig(
+        vocab_size=VOCAB, hidden_size=128, num_hidden_layers=2,
+        num_attention_heads=2, intermediate_size=128,
+        max_position_embeddings=1024, embd_dropout_prob=0.0,
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0)
+    model = models.GptLmHeadModel(cfg)
+    ids = jnp.zeros((1, 1024), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)}, ids, train=False)["params"])
+
+    def loss(p, ids):
+        return models.gpt_lm_loss(model.apply({"params": p}, ids, train=True),
+                                  ids, vocab_size=VOCAB)
+
+    text = jax.jit(jax.grad(loss)).trace(params, ids).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    sites = [(kernel, locs[loc]) for kernel, loc in re.findall(
+        r"call @(_\w+_call)\(.*loc\((#loc\d+)\)", text)]
+    assert sorted(k for k, _ in sites) == (["_bwd_call"] * 2
+                                            + ["_fwd_call"] * 2)
+    for kernel, name in sites:
+        phase = ("/jvp(GptLmHeadModel)/" if kernel == "_fwd_call"
+                 else "/transpose(jvp(GptLmHeadModel))/")
+        assert phase in name and re.search(r"/h_[01]/attention/", name), name
+
+
 def test_step_is_annotated_on_the_profilers_clock(tmp_path):
     """`ts.step` wraps its dispatch in `TraceAnnotation("dear.step")` on
     every path, the fast one too: a profiler session shows the span."""
