@@ -31,6 +31,14 @@ from dear_pytorch_tpu.models.gpt import (  # noqa: F401
     generate,
     gpt_lm_loss,
 )
+from dear_pytorch_tpu.models.glm_moe import (  # noqa: F401
+    GLM47_FLASH,
+    GLM_MOE_TINY,
+    GlmMoeConfig,
+    GlmMoeLmHeadModel,
+    expert_assignments,
+    glm_moe_lm_loss,
+)
 from dear_pytorch_tpu.models.densenet import (  # noqa: F401
     DenseNet121,
     DenseNet169,
@@ -81,6 +89,13 @@ _GPT_REGISTRY: dict[str, Any] = {
 }
 
 
+# Sparse decoders with latent attention (models/glm_moe.py).
+_GLM_REGISTRY: dict[str, Any] = {
+    "glm47_flash": GLM47_FLASH,
+    "glm_moe_tiny": GLM_MOE_TINY,   # CPU tests and smoke runs only
+}
+
+
 def cnn_names() -> list[str]:
     return sorted(_CNN_REGISTRY)
 
@@ -91,6 +106,10 @@ def bert_names() -> list[str]:
 
 def gpt_names() -> list[str]:
     return sorted(_GPT_REGISTRY)
+
+
+def glm_names() -> list[str]:
+    return sorted(_GLM_REGISTRY)
 
 
 def get_model(name: str, *, dtype=jnp.float32, **kwargs):
@@ -111,9 +130,14 @@ def get_model(name: str, *, dtype=jnp.float32, **kwargs):
             cfg = dataclasses.replace(cfg, dtype=dtype)
         cls = BertForPreTraining if key in _BERT_REGISTRY else GptLmHeadModel
         return cls(cfg, **kwargs)
+    if key in _GLM_REGISTRY:
+        import dataclasses
+
+        return GlmMoeLmHeadModel(
+            dataclasses.replace(_GLM_REGISTRY[key], dtype=dtype), **kwargs)
     raise KeyError(
         f"unknown model {name!r}; CNNs: {cnn_names()}, BERT: {bert_names()}, "
-        f"GPT: {gpt_names()}"
+        f"GPT: {gpt_names()}, GLM: {glm_names()}"
     )
 
 

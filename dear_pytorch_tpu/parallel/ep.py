@@ -14,6 +14,14 @@ Training runs through `parallel.tp.make_tp_train_step` with `EP_RULES`
 
     step = make_tp_train_step(loss_fn, params, mesh=mesh,
                               rules=EP_RULES, tp_axis='ep')
+
+`RoutedExperts` is the other expert layer here, the one present-day sparse
+decoders use (`models/glm_moe.py`): top-k routing over ALL of the model's
+experts, no capacity and no dropped token, computed for the experts THIS
+chip holds (one share of an expert-parallel group). It runs without its
+exchange: what the absent experts would add is left out, nothing stands in
+for the absent chips. Its stacked ``wi`` / ``wo`` keep the leading expert
+dimension `EP_RULES` shards.
 """
 
 from __future__ import annotations
@@ -23,6 +31,7 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 EP_AXIS = "ep"
 
@@ -103,3 +112,147 @@ def aux_load_balance_loss(x, router_kernel, num_experts: int) -> jax.Array:
     frac = jnp.mean(onehot, axis=0)
     mean_prob = jnp.mean(probs, axis=0)
     return num_experts * jnp.sum(frac * mean_prob)
+
+
+# -- dropless top-k experts, one chip's share --------------------------------
+
+
+@jax.custom_vjp
+def _spread(x, order, inverse, valid):
+    """Rows of ``x`` ``[T, H]`` in assignment order ``[T*k, H]``: row ``i``
+    is token ``order[i] // k``. ``order`` is a permutation of the ``T*k``
+    (token, slot) assignments and ``inverse`` its inverse, so the gradient
+    is a gather by ``inverse`` and a sum over a token's ``k`` slots: no
+    scatter-add in either direction. ``valid`` ``[T*k]`` marks the rows
+    some held expert works on; the cotangent of any other row is whatever
+    the grouped matmul left there and is dropped."""
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+def _spread_fwd(x, order, inverse, valid):
+    return _spread(x, order, inverse, valid), (inverse, valid, x.shape[0])
+
+
+def _spread_bwd(res, g):
+    inverse, valid, tokens = res
+    g = jnp.where(valid[:, None], g, 0)[inverse]
+    return g.reshape(tokens, -1, g.shape[-1]).sum(axis=1), None, None, None
+
+
+_spread.defvjp(_spread_fwd, _spread_bwd)
+
+
+@jax.custom_vjp
+def _unpermute(y, order, inverse):
+    """``y[inverse]``: sorted rows back in (token, slot) order; the gradient
+    is ``g[order]``."""
+    return y[inverse]
+
+
+def _unpermute_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _unpermute_bwd(order, g):
+    return g[order], None, None
+
+
+_unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
+
+
+class RoutedExperts(nn.Module):
+    """Top-k routed SwiGLU experts without dropped tokens: the part of
+    ``sum_k w_k * Expert_{idx_k}(x)`` whose experts live here.
+
+    The router scores all ``router_width`` experts of the model; this chip
+    holds ``experts_held`` of them, from ``expert_offset`` on. Scores are
+    ``sigmoid`` (DeepSeek-V3's ``noaux_tc``: the top-k is taken on
+    ``score + router_bias``, the weights on the score alone) or ``softmax``;
+    ``norm_topk_prob`` divides the k weights by their sum, then
+    ``routed_scaling_factor`` scales them. ``router_bias`` (the
+    ``e_score_correction_bias``) is a parameter leaf behind `stop_gradient`:
+    it moves the selection and receives no gradient.
+
+    Dropless under any imbalance: the ``T*k`` assignments are sorted by
+    held expert (absent ones last, in no group) and two grouped matmuls
+    (`jax.lax.ragged_dot`, a Mosaic grouped-matmul kernel on the TPU) run
+    over the held experts' rows, so the buffers are ``T*k`` rows whatever the
+    routing: the worst case, every token choosing k held experts, fits.
+    Rows in no group are masked out explicitly wherever they could reach a
+    result or a gradient (the TPU kernel leaves them unwritten).
+
+    Input ``[T, H]``; returns the routed part ``[T, H]`` (add the shared
+    expert outside: every chip computes that alike). Sows the assignments per
+    held expert ``[experts_held]`` into ``intermediates/assignments``.
+    ``wi`` is ``[E, H, 2F]``, gate then up.
+    """
+
+    router_width: int
+    experts_held: int
+    top_k: int
+    mlp_dim: int
+    expert_offset: int = 0
+    scoring: str = "sigmoid"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    dtype: Any = jnp.float32
+    kernel_init: Callable = nn.initializers.lecun_normal()
+    bias_init: Callable = nn.initializers.zeros
+
+    def route(self, x, router, router_bias):
+        """(idx ``[T, k]`` over all experts, weights ``[T, k]`` f32)."""
+        logits = jnp.dot(x.astype(jnp.float32), router,
+                         precision=lax.Precision.HIGHEST)
+        if self.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        elif self.scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            raise ValueError(f"unknown scoring {self.scoring!r}")
+        _, idx = lax.top_k(scores + lax.stop_gradient(router_bias), self.top_k)
+        weights = jnp.take_along_axis(scores, idx, axis=-1)
+        if self.norm_topk_prob:
+            weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        return idx, weights * self.routed_scaling_factor
+
+    @nn.compact
+    def __call__(self, x):
+        T, H = x.shape
+        E, k, F = self.experts_held, self.top_k, self.mlp_dim
+        if not 0 <= self.expert_offset <= self.router_width - E:
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset + E}) "
+                f"are not among the router's {self.router_width}")
+        router = self.param("router", self.kernel_init,
+                            (H, self.router_width), jnp.float32)
+        router_bias = self.param("router_bias", self.bias_init,
+                                 (self.router_width,), jnp.float32)
+        wi = self.param("wi", self.kernel_init, (E, H, 2 * F), jnp.float32)
+        wo = self.param("wo", self.kernel_init, (E, F, H), jnp.float32)
+
+        with jax.named_scope("route"):
+            idx, weights = self.route(x, router, router_bias)
+        with jax.named_scope("dispatch"):
+            local = idx - self.expert_offset
+            held = (local >= 0) & (local < E)                    # [T, k]
+            group = jnp.where(held, local, E).reshape(T * k)     # absent: E
+            order = jnp.argsort(group, stable=True)
+            inverse = jnp.argsort(order)
+            sizes = jnp.sum(group[:, None] == jnp.arange(E)[None],
+                            axis=0, dtype=jnp.int32)             # [E]
+            self.sow("intermediates", "assignments", sizes)
+            # sorted rows past the held experts' groups belong to no group:
+            # the grouped matmul neither reads nor writes them (on the TPU
+            # they hold whatever the buffer held), forward and backward
+            valid = jnp.arange(T * k) < jnp.sum(sizes)
+            xs = _spread(x.astype(self.dtype), order, inverse, valid)
+        with jax.named_scope("experts"):
+            gate_up = lax.ragged_dot(xs, wi.astype(self.dtype), sizes)
+            gate_up = jnp.where(valid[:, None], gate_up, 0)
+            act = jax.nn.silu(gate_up[:, :F]) * gate_up[:, F:]
+            ys = lax.ragged_dot(act, wo.astype(self.dtype), sizes)
+        with jax.named_scope("combine"):
+            back = _unpermute(ys, order, inverse).reshape(T, k, H)
+            # a row outside every group is no expert's output: leave it out
+            back = jnp.where(held[..., None], back.astype(jnp.float32), 0.0)
+            return jnp.sum(back * weights[..., None], axis=1).astype(x.dtype)

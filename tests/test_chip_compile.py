@@ -79,10 +79,13 @@ def test_perf_model_knows_the_described_device(topo):
     assert perf_model.device_peak_flops(topo.devices[0]) == 197e12
 
 
-# GPT-2 124M attention at S=1024 (causal; the benchmark cell's batch) and
-# BERT-Base at S=128
+# GPT-2 124M attention at S=1024 (causal; the benchmark cell's batch),
+# BERT-Base at S=128, and GLM-4.7-Flash's latent attention at S=4096: 20
+# heads of 256, the one-head-a-block branch (the fused backward then holds
+# dK/dV of 4096 rows at 256 lanes in VMEM, 8.4 MB f32 of the 64 MB limit)
 @pytest.mark.parametrize("shape,causal", [((16, 1024, 12, 64), True),
-                                          ((32, 128, 12, 64), False)])
+                                          ((32, 128, 12, 64), False),
+                                          ((1, 4096, 20, 256), True)])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_kernel_compiles_for_v5e(compiled_kernels, one_chip, shape,
                                        causal, direction):

@@ -295,6 +295,21 @@ def test_tiled_grid_matches_dense(blocks_of_128, shape, mode, dtype):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
+def test_one_head_a_block_at_256_lanes(blocks_of_128, dtype):
+    """D = 256 (the latent-attention decoder's head: 192 + 64 for q and k,
+    256 for v) is the ``D % 128 == 0`` branch: one head a block, two lane
+    rows wide, no head mask; three blocks a sequence, so dK/dV accumulate
+    over the query blocks."""
+    _check_against_dense((1, 384, 2, 256), "causal", dtype, key=3)
+
+
+def test_one_head_a_block_at_the_default_block():
+    """S=512, D=256 at the default block: one diagonal tile in strips."""
+    _check_against_dense((1, 512, 2, 256), "causal", jnp.float32, key=4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
 @pytest.mark.parametrize("mode", ["causal", "causal+kv_mask"])
 def test_diagonal_tile_in_strips(mode, dtype):
     """S=512 at the default block is one diagonal tile of two 256-row
