@@ -1,6 +1,6 @@
 """Smallest end-to-end proof that the DeAR train step runs on the TPU.
 
-    python chip_smoke.py             # one chip: train, reference, flash
+    python chip_smoke.py             # one chip: train, reference, flash, dropout
     python chip_smoke.py --chips 4   # four chips: dear vs allreduce, only
 
 One process, GPT-2 124M at its published widths (12 layers, hidden 768,
@@ -41,7 +41,10 @@ from dear_pytorch_tpu.models.gpt import (
     causal_dot_product_attention,
     flash_causal_attention_impl,
 )
-from dear_pytorch_tpu.ops.flash_attention import flash_attention
+from dear_pytorch_tpu.ops.flash_attention import (
+    dropout_keep_mask,
+    flash_attention,
+)
 from dear_pytorch_tpu.ops.fused_sgd import fused_sgd
 from dear_pytorch_tpu.parallel import build_train_step
 from dear_pytorch_tpu.utils import perf_model
@@ -239,6 +242,24 @@ def phase_reference(mesh, cfg, batch_size: int, seq_len: int, steps: int,
     return {"dear": dear, "plain": plain, "max_diff": diff}
 
 
+def _with_grads(attend, q, k, v, do):
+    """(out, dq, dk, dv) of ``attend(q, k, v)`` under the cotangent ``do``."""
+    out, vjp = jax.vjp(attend, q, k, v)
+    return (out,) + vjp(do.astype(out.dtype))
+
+
+def _max_abs_errors(got, want, tol: float, what: str) -> dict:
+    """Max abs error of (out, dq, dk, dv) ``got`` against ``want``, each
+    asserted within ``tol``."""
+    errs = {}
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w)
+        errs[name] = float(np.max(np.abs(g - w)))
+        np.testing.assert_allclose(g, w, rtol=tol, atol=tol,
+                                   err_msg=f"{what} {name}")
+    return errs
+
+
 def check_flash_kernel(shape, seed: int, tol: float = FLASH_TOL):
     """Causal flash forward + q/k/v gradients at ``(B, S, H, D)`` in bf16
     against dense attention computed in f32 (highest matmul precision) on
@@ -247,14 +268,10 @@ def check_flash_kernel(shape, seed: int, tol: float = FLASH_TOL):
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
     q, k, v, do = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in keys)
 
-    def with_grads(attend, q, k, v, do):
-        out, vjp = jax.vjp(attend, q, k, v)
-        return (out,) + vjp(do.astype(out.dtype))
-
     flash = jax.jit(functools.partial(
-        with_grads, functools.partial(flash_attention, causal=True)))
+        _with_grads, functools.partial(flash_attention, causal=True)))
     dense = jax.jit(functools.partial(
-        with_grads, lambda q, k, v: causal_dot_product_attention(
+        _with_grads, lambda q, k, v: causal_dot_product_attention(
             q, k, v, None, dtype=jnp.float32)))
     t0 = time.perf_counter()
     compiled = flash.lower(q, k, v, do).compile()
@@ -263,12 +280,7 @@ def check_flash_kernel(shape, seed: int, tol: float = FLASH_TOL):
     got = compiled(q, k, v, do)
     with jax.default_matmul_precision("highest"):
         want = dense(*(x.astype(jnp.float32) for x in (q, k, v, do)))
-    errs = {}
-    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
-        g, w = np.asarray(g, np.float32), np.asarray(w)
-        errs[name] = float(np.max(np.abs(g - w)))
-        np.testing.assert_allclose(g, w, rtol=tol, atol=tol,
-                                   err_msg=f"flash {name} vs dense")
+    errs = _max_abs_errors(got, want, tol, "flash vs dense:")
     log(f"[flash] max abs error vs dense f32 at {shape} (bf16, tolerance "
         f"{tol}): " + ", ".join(f"{n} {e:.2e}" for n, e in errs.items()))
     return errs, "tpu_custom_call" in compiled.as_text()
@@ -300,6 +312,92 @@ def phase_flash(mesh, cfg, batch_size: int, seq_len: int, steps: int,
     log(f"[flash] step time flash {res['warm_step_s'] * 1e3:.1f} ms vs dense "
         f"{dense['warm_step_s'] * 1e3:.1f} ms (smoke timings, not a benchmark)")
     return res
+
+
+#: the kernel against dense f32 attention on f32 inputs, both at the
+#: highest matmul precision: what is left is summation order and the exp
+DROPOUT_F32_TOL = 1e-3
+DROPOUT_RATE = 0.1
+
+
+def dense_dropped_attention(q, k, v, kv_mask, keep, rate):
+    """Dense f32 attention over ``[B, S, H, D]`` whose probabilities are
+    dropped by the ready mask ``keep`` ``[B, H, S, S]``."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+    s = jnp.where(kv_mask[:, None, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1) * keep / (1.0 - rate)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def check_dropout_kernel(shape, dtype, seed: int, tol: float):
+    """The kernels with attention-probabilities dropout live (non-causal,
+    a key-padding mask: BERT's call) at ``(B, S, H, D)`` against the dense
+    f32 program under `dropout_keep_mask`'s mask for the same key: output
+    and the three gradients. Returns the max abs errors."""
+    b, s, h, _ = shape
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k, v, do = (jax.random.normal(kk, shape, dtype) for kk in keys[:4])
+    rng = keys[4]
+    kv_mask = jnp.arange(s)[None, :] < (s - 5 * jnp.arange(b)[:, None])
+    keep = dropout_keep_mask(rng, b, h, s, s, DROPOUT_RATE)
+
+    flash = jax.jit(functools.partial(
+        _with_grads, functools.partial(
+            flash_attention, kv_mask=kv_mask, dropout_rng=rng,
+            dropout_rate=DROPOUT_RATE)))
+    dense = jax.jit(functools.partial(
+        _with_grads, functools.partial(
+            dense_dropped_attention, kv_mask=kv_mask, keep=keep,
+            rate=DROPOUT_RATE)))
+    got = flash(q, k, v, do)
+    with jax.default_matmul_precision("highest"):
+        want = dense(*(x.astype(jnp.float32) for x in (q, k, v, do)))
+    errs = _max_abs_errors(
+        got, want, tol, "dropout kernel vs dense under the same mask:")
+    log(f"[dropout] max abs error vs dense f32 under the same mask at "
+        f"{shape} ({jnp.dtype(dtype).name}, tolerance {tol}): "
+        + ", ".join(f"{n} {e:.2e}" for n, e in errs.items()))
+    return errs
+
+
+def applied_keep_counts(shape, seed: int):
+    """How many probabilities of each row the compiled kernel kept: with
+    ``q`` = 0 every probability is 1/S, and with ``v`` = 1 each output row
+    reads kept / ((1 - rate) S), so f32 gives the count back exactly.
+    Returns (the kernel's counts ``[B, S, H]``, the dense mask's)."""
+    b, s, h, _ = shape
+    rng = jax.random.PRNGKey(seed)
+    zeros, ones = jnp.zeros(shape, jnp.float32), jnp.ones(shape, jnp.float32)
+    out = jax.jit(functools.partial(
+        flash_attention, dropout_rate=DROPOUT_RATE))(zeros, zeros, ones,
+                                                     dropout_rng=rng)
+    got = np.rint(np.asarray(out[..., 0], np.float64)
+                  * (1.0 - DROPOUT_RATE) * s).astype(np.int64)
+    keep = dropout_keep_mask(rng, b, h, s, s, DROPOUT_RATE)
+    return got, np.asarray(keep.sum(-1)).transpose(0, 2, 1)
+
+
+def phase_dropout(shape, seed: int):
+    """Attention-probabilities dropout inside the flash kernels, as BERT's
+    default core calls them: values and gradients in bf16 and f32 against
+    the dense program under the same mask, and the mask the compiled
+    kernel really applied (per-row keep counts, and their share)."""
+    errs = {jnp.dtype(dtype).name: check_dropout_kernel(shape, dtype, seed,
+                                                        tol)
+            for dtype, tol in ((jnp.bfloat16, FLASH_TOL),
+                               (jnp.float32, DROPOUT_F32_TOL))}
+    got, want = applied_keep_counts(shape, seed)
+    share = got.sum() / (got.size * shape[1])
+    log(f"[dropout] keep share the kernel applied at {shape}: {share:.6f} "
+        f"of {got.size * shape[1]} scores (the dense mask: "
+        f"{want.sum() / (want.size * shape[1]):.6f}; nominal "
+        f"{1.0 - DROPOUT_RATE}); rows whose count differs from the dense "
+        f"mask's: {int((got != want).sum())} of {got.size}")
+    if not np.array_equal(got, want):
+        raise AssertionError(
+            "the compiled kernel kept other probabilities than "
+            "dropout_keep_mask says")
+    return {"errors": errs, "keep_share": float(share)}
 
 
 def _check_spread(tree, mesh, what: str) -> None:
@@ -425,6 +523,8 @@ def main(argv=None) -> int:
         if not flash["kernel_in_program"]:
             raise AssertionError(
                 "the flash train step compiled without a tpu_custom_call")
+        # BERT-Large's attention at the benchmark cell's shape
+        phase_dropout((16, 512, 16, 64), seed=args.seed)
     log(f"total: {time.perf_counter() - t_start:.0f} s")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

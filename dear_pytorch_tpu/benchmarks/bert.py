@@ -100,10 +100,13 @@ def main(argv=None) -> runner.BenchResult:
 
         projection_impl = make_ring_projection_impl(DP_AXIS)
     cfg_over = model.config
-    # impls with no attention-prob-dropout path: dropout>0 would silently
-    # measure their dense/ring FALLBACK instead of the requested kernel
-    kernel_attn = (args.flash_attention
-                   or args.sp_attention in ("ring_flash", "ulysses"))
+    # impls with no attention-prob-dropout path (the sequence-parallel
+    # engines; the one-chip flash kernel drops probabilities itself):
+    # dropout>0 would silently measure their dense/ring FALLBACK instead of
+    # the requested kernel
+    kernel_attn = sp > 1 and (args.flash_attention
+                              or args.sp_attention in ("ring_flash",
+                                                       "ulysses"))
     if args.num_hidden_layers is not None or kernel_attn or args.dropout0:
         import dataclasses
 

@@ -112,9 +112,11 @@ def main(argv=None) -> runner.BenchResult:
                          f"max_position_embeddings "
                          f"{cfg.max_position_embeddings}")
     attention_impl = None
-    kernel_attn = (args.flash_attention
-                   or args.sp_attention in ("ring_flash", "ulysses",
-                                            "zigzag"))
+    # the one-chip flash kernel drops probabilities itself; the
+    # sequence-parallel engines have no dropout path
+    kernel_attn = sp > 1 and (args.flash_attention
+                              or args.sp_attention in ("ring_flash",
+                                                       "ulysses", "zigzag"))
     if kernel_attn and cfg.attention_probs_dropout_prob:
         runner.log("kernel attention: attention_probs_dropout_prob "
                    f"{cfg.attention_probs_dropout_prob} -> 0.0 "

@@ -133,6 +133,24 @@ def dot_product_attention(q, k, v, mask, *, dropout_rng=None,
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def attention(q, k, v, mask, *, dropout_rng=None, dropout_rate=0.0,
+              dtype=jnp.float32):
+    """The default attention core of `BertSelfAttention`: the Pallas flash
+    kernel where `models.gpt.flash_core_applies` says so (one predicate for
+    both families: a TPU, no mask or the key-padding mask, S a multiple of
+    128 from the measured minimum up; the probabilities are dropped inside
+    the kernel), else `dot_product_attention`, unchanged. Same calling
+    convention as both."""
+    from dear_pytorch_tpu.models import gpt   # gpt imports this module
+
+    if gpt.flash_core_applies(q, k, mask, dropout_rng, dropout_rate,
+                              causal=False):
+        return gpt.flash_core(q, k, v, mask, dropout_rng, dropout_rate,
+                              False)
+    return dot_product_attention(q, k, v, mask, dropout_rng=dropout_rng,
+                                 dropout_rate=dropout_rate, dtype=dtype)
+
+
 class BertSelfAttention(nn.Module):
     config: BertConfig
     attention_impl: Optional[Callable] = None
@@ -162,7 +180,11 @@ class BertSelfAttention(nn.Module):
             dropout_rng = None
             if train and cfg.attention_probs_dropout_prob > 0.0:
                 dropout_rng = self.make_rng("dropout")
-            impl = self.attention_impl or dot_product_attention
+            impl = self.attention_impl or attention
+            if self.attention_impl is None and self.is_initializing():
+                # `init` keeps the parameters and discards this output: no
+                # kernel is traced and lowered for it (as `GptBlock`)
+                impl = dot_product_attention
             ctx = impl(q, k, v, mask, dropout_rng=dropout_rng,
                        dropout_rate=(cfg.attention_probs_dropout_prob
                                      if train else 0.0),

@@ -30,13 +30,27 @@ from dear_pytorch_tpu.models.bert import dot_product_attention
 # FUNCTION that shadows the module attribute
 _flash = importlib.import_module("dear_pytorch_tpu.ops.flash_attention")
 
-#: Shortest sequence the default core hands to the flash kernel. Causal bf16
-#: forward + backward of one layer, 12 heads of 64, on a v5e, dense / kernel
-#: (scripts/flash_ab.py, PR 30): S=512 (batch 16) 0.742 / 0.725 ms, a tie;
-#: S=768 (8) 1.567 / 0.623; S=1024 (16) 5.857 / 1.959; S=2048 (4) 5.420 /
-#: 1.573; S=4096 (2) 10.272 / 2.557. XLA's dense program is at its best at
-#: 512 (26% of the matmul floor; 13-15% from 768 up).
-FLASH_MIN_SEQ = 768
+#: Shortest sequence a default core hands to the flash kernel, by (causal,
+#: attention dropout live): the first S at which the kernel beat XLA's dense
+#: program on a v5e, bf16 forward + backward of one layer, dense / kernel ms
+#: (`scripts/flash_ab.py`; docs/KERNELS.md has the whole table).
+FLASH_MIN_SEQ = {
+    # `--causal`, 12 heads of 64 (PR 30): S=512 (batch 16) 0.742 / 0.725, a
+    # tie; S=768 (8) 1.567 / 0.623; S=1024 (16) 5.857 / 1.959; S=2048 (4)
+    # 5.420 / 1.573; S=4096 (2) 10.272 / 2.557. XLA's dense program is at
+    # its best at 512 (26% of the matmul floor; 13-15% from 768 up)
+    (True, False): 768,
+    # `--causal --dropout 0.1`, batch 16, 12 heads of 64 (PR 32): S=256
+    # 0.719 / 0.506; S=512 2.840 / 0.844; S=1024 refused (24.4 GB) / 2.284:
+    # the dense program pays two threefry masks of S^2 elements
+    (True, True): 256,
+    # `--kv-mask`, batch 16, 16 heads of 64 (PR 32): S=128 0.490 / 0.501 and
+    # S=256 0.507 / 0.500, ties; S=512 1.886 / 1.096
+    (False, False): 512,
+    # `--kv-mask --dropout 0.1`, the same shapes (PR 32): S=128 0.549 /
+    # 0.508; S=256 0.931 / 0.738; S=512 4.335 / 1.363
+    (False, True): 128,
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,32 +148,47 @@ def causal_dot_product_attention(q, k, v, mask, *, dropout_rng=None,
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def flash_core_applies(q, k, mask, dropout_rng, dropout_rate) -> bool:
-    """Whether `causal_attention` runs the Pallas flash kernel: a function
-    of what the call can observe (backend, dropout, mask, shape), in this
-    one place. The kernel has no dropout and no additive-mask path, pays
-    off from `FLASH_MIN_SEQ` up, tiles sequences in multiples of 128, and
-    off the TPU would run in Pallas' interpreter (`_interpret` is the one
+def flash_core_applies(q, k, mask, dropout_rng, dropout_rate,
+                       causal: bool = True) -> bool:
+    """Whether a default attention core (`causal_attention` here,
+    `models.bert.attention` with ``causal=False``) runs the Pallas flash
+    kernel: a function of what the call can observe (backend, dropout,
+    mask, shape), in this one place for both families. The kernel takes no
+    mask or the additive key-padding mask ``[B, 1, 1, S]`` (as its
+    ``kv_mask``; BERT's ``[B, 1, S, S]`` serving mask stays dense), drops
+    probabilities itself, pays off from `FLASH_MIN_SEQ`'s entry for
+    (causal, dropout live) up, tiles sequences in multiples of 128, and off
+    the TPU would run in Pallas' interpreter (`_interpret` is the one
     predicate: an ahead-of-time compile for a described TPU from a CPU
     host patches that)."""
     seq = q.shape[1]
+    live = dropout_rng is not None and dropout_rate > 0.0
     return (not _flash._interpret()
-            and not (dropout_rng is not None and dropout_rate > 0.0)
-            and mask is None
+            and (mask is None or mask.shape == (q.shape[0], 1, 1, seq))
             and k.shape[1] == seq
-            and seq >= FLASH_MIN_SEQ and seq % 128 == 0)
+            and seq >= FLASH_MIN_SEQ[causal, live] and seq % 128 == 0)
+
+
+def flash_core(q, k, v, mask, dropout_rng, dropout_rate, causal: bool):
+    """The kernel as a default core calls it where `flash_core_applies`:
+    under a bare ``attention`` scope (what `attention_core_ms` and the
+    kernel counters read), the additive mask as key validity."""
+    with jax.named_scope("attention"):
+        return _flash.flash_attention(
+            q, k, v, causal=causal,
+            kv_mask=None if mask is None else _flash.key_validity(mask),
+            dropout_rng=dropout_rng, dropout_rate=dropout_rate)
 
 
 def causal_attention(q, k, v, mask, *, dropout_rng=None, dropout_rate=0.0,
                      dtype=jnp.float32):
     """The default causal attention core of `GptBlock`: the flash kernel
-    where `flash_core_applies` (scores, mask, softmax and context stay in
-    VMEM; bf16 operands, f32 accumulation and softmax), else the dense
-    program `causal_dot_product_attention`, unchanged. Same calling
+    where `flash_core_applies` (scores, mask, softmax, dropout and context
+    stay in VMEM; bf16 operands, f32 accumulation and softmax), else the
+    dense program `causal_dot_product_attention`, unchanged. Same calling
     convention as both."""
     if flash_core_applies(q, k, mask, dropout_rng, dropout_rate):
-        with jax.named_scope("attention"):
-            return _flash.flash_attention(q, k, v, causal=True)
+        return flash_core(q, k, v, mask, dropout_rng, dropout_rate, True)
     return causal_dot_product_attention(
         q, k, v, mask, dropout_rng=dropout_rng, dropout_rate=dropout_rate,
         dtype=dtype)
@@ -195,19 +224,13 @@ def checkpointed_causal_attention_impl():
 
 
 def flash_causal_attention_impl():
-    """Causal attention via the Pallas flash kernel (attention dropout is
-    not supported inside the kernel — use for inference/benchmarks or
-    dropout-free training)."""
+    """Causal attention via the Pallas flash kernel, whatever
+    `flash_core_applies` says (attention dropout included: the kernel's
+    own mask, `ops.flash_attention.dropout_keep_mask`)."""
     def impl(q, k, v, mask, *, dropout_rng=None, dropout_rate=0.0,
              dtype=jnp.float32):
-        if dropout_rng is not None and dropout_rate > 0.0:
-            raise ValueError(
-                "flash attention kernel has no attention-dropout path; "
-                "set attention_probs_dropout_prob=0"
-            )
         del mask  # full sequences in the causal LM path
-        with jax.named_scope("attention"):
-            return _flash.flash_attention(q, k, v, causal=True)
+        return flash_core(q, k, v, None, dropout_rng, dropout_rate, True)
 
     return impl
 

@@ -8,16 +8,31 @@ key-block) tile, the S×S score matrix is never materialized in HBM
 softmax — same math as `parallel.ring_attention`, which distributes ACROSS
 chips what this kernel tiles WITHIN one).
 
-STATUS (PR 30): on `GptBlock`'s default training path wherever
-`models.gpt.flash_core_applies` (a TPU, no live attention dropout, no
-additive mask, S a multiple of 128 from 768 up); both GPT cells of
-BENCHMARK.json run it (24 `tpu_custom_call`s a step). Measured on a v5e
-with `python scripts/flash_ab.py --causal` at the cell's shape
-(16, 1024, 12, 64), bf16, one layer: forward 0.78 ms, forward + backward
-1.96 ms, against XLA's dense program at 1.95 / 5.86 ms and the Pallas
-kernel JAX ships at 1.03 / 5.76 ms (PERF.md §6 has the table and what each
-repair bought). tests/test_chip_compile.py keeps the v5e compile in tier-1;
-`chip_smoke.py` checks values on the chip.
+STATUS (PR 32): on the default training path of `GptBlock`, `GlmBlock` and
+`BertSelfAttention` wherever `models.gpt.flash_core_applies` (a TPU, no
+mask or the key-padding mask, S a multiple of 128 from the minimum
+`models.gpt.FLASH_MIN_SEQ` gives for (causal, dropout live)); all four
+cells of BENCHMARK.json run it (24 / 24 / 12 `tpu_custom_call`s a step in
+the GPT and GLM cells, 48 with dropout in BERT-Large's). Measured on a v5e
+with `python scripts/flash_ab.py`, bf16, one layer, forward / forward +
+backward: `--causal` at (16, 1024, 12, 64) 0.78 / 1.96 ms against XLA's
+dense program at 1.95 / 5.86 and the Pallas kernel JAX ships at 1.03 /
+5.76 (PR 30); `--kv-mask --dropout 0.1` at BERT-Large's (16, 512, 16, 64)
+0.57 / 1.36 ms against the dense program with its threefry masks at 1.82 /
+4.34 (PR 32; 0.42 / 1.10 without dropout). PERF.md §6 and docs/KERNELS.md
+have the tables. tests/test_chip_compile.py keeps the v5e compiles in
+tier-1; `chip_smoke.py` checks values, and the mask applied, on the chip.
+
+Attention-probabilities dropout lives INSIDE the kernels (PR 32): the keep
+decision of a score is a counter-based hash of (two seed words from the
+layer's dropout key, batch row, head, absolute query row, absolute key
+column), computed on the strip a kernel holds and, by the same function,
+on the whole ``[B, H, Sq, Sk]`` by `dropout_keep_mask`, so a dense program
+reproduces the kernels' mask exactly, on the CPU and on the chip. The row
+sum and the saved lse come from the undropped probabilities; the backward
+kernel rebuilds the mask; no S² tensor is kept. Without a key or at rate
+0 the kernels hold no dropout code (no operand, no hash, no select). The
+ring's pair kernels (`flash_pair_*`) have no dropout path.
 
 Layout (what makes it fast on a v5e, whose MXU and vector lanes are 128
 wide while a head is 64): the kernels read q, k, v as ``[B, S, H·D]`` —
@@ -250,16 +265,95 @@ def _on_causal_tiles(causal, key_block, query_block, blocks, tile):
 
 
 # ---------------------------------------------------------------------------
+# attention-probabilities dropout: the keep decision of one score is a pure
+# integer function of (seed words, batch row, head, absolute query row,
+# absolute key column), so the kernels (on the strip they hold) and a dense
+# program (`dropout_keep_mask`, on the whole [B, H, Sq, Sk]) draw the same
+# mask whatever the tiling
+# ---------------------------------------------------------------------------
+
+_MUL_BATCH, _MUL_HEAD = 0x9E3779B1, 0x85EBCA77
+_MUL_ROW, _MUL_COL = 0xC2B2AE3D, 0x27D4EB2F
+
+
+def _mix(x):
+    """A 32-bit finalizer (xor-shift / multiply rounds, "lowbias32"): every
+    input bit reaches every output bit."""
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(0x846CA68B)
+    return x ^ (x >> 16)
+
+
+def _keep(seed0, seed1, batch, head, rows, cols, rate):
+    """bool, the broadcast of (batch, head, rows, cols): which scores stay.
+    ``seed0``/``seed1`` are uint32 scalars, the coordinates uint32 (scalars
+    or arrays that broadcast against each other: the kernels pass scalar
+    batch and head, a (r, 1) column of rows and a (1, c) row of columns).
+    keep <=> 32 hashed bits < round((1 - rate) * 2**32): |p_keep - (1 -
+    rate)| <= 2**-32. The two seed words each give a (batch, head) stream
+    word; a row's word is mixed once per row (a thin column), so a score
+    costs one xor, one `_mix` and one compare."""
+    def stream(seed, salt):
+        return _mix(_mix(seed + batch * jnp.uint32(_MUL_BATCH))
+                    ^ (head * jnp.uint32(_MUL_HEAD) + jnp.uint32(salt)))
+
+    row_word = _mix(stream(seed0, 0) + rows * jnp.uint32(_MUL_ROW))
+    col_word = stream(seed1, 1) + cols * jnp.uint32(_MUL_COL)
+    threshold = min(round((1.0 - rate) * 2 ** 32), 2 ** 32 - 1)
+    return _mix(row_word ^ col_word) < jnp.uint32(threshold)
+
+
+def _keep_strip(seed_ref, batch, head, row0, col0, shape, rate):
+    """`_keep` on a (rows, cols) strip whose first score is (row0, col0) of
+    the whole sequence pair."""
+    u32 = lambda x: jnp.asarray(x, jnp.int32).astype(jnp.uint32)  # noqa: E731
+    rows = lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0) + row0
+    cols = lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1) + col0
+    return _keep(seed_ref[0], seed_ref[1], u32(batch), u32(head), u32(rows),
+                 u32(cols), rate)
+
+
+def dropout_seed_words(rng):
+    """Two uint32 words from a dropout key (typed or raw ``uint32[2]``; a
+    wider key's words are folded by xor): the kernels' seed operand."""
+    if jnp.issubdtype(rng.dtype, jax.dtypes.prng_key):
+        rng = jax.random.key_data(rng)
+    words = rng.astype(jnp.uint32).reshape(-1, 2)
+    return functools.reduce(jnp.bitwise_xor, list(words))
+
+
+def dropout_keep_mask(seed, batch, heads, sq, sk, rate):
+    """The ``[B, H, Sq, Sk]`` bool keep mask the kernels apply for ``seed``
+    (a dropout key or its two words), built outside any kernel: a dense
+    program that drops with it reproduces the kernels' result."""
+    seed = dropout_seed_words(jnp.asarray(seed))
+    grid = lambda n, axis: lax.broadcasted_iota(  # noqa: E731
+        jnp.uint32, tuple(n if a == axis else 1 for a in range(4)), axis)
+    keep = _keep(seed[0], seed[1], grid(batch, 0), grid(heads, 1),
+                 grid(sq, 2), grid(sk, 3), rate)
+    return jnp.broadcast_to(keep, (batch, heads, sq, sk))
+
+
+# ---------------------------------------------------------------------------
 # forward kernel: grid (B, H/G, Sq/bq, Sk/bk); scratch carries the online
 # softmax of each of the block's G heads
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk):
+def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk, rate=0.0):
+    """``rate`` > 0: the first operand is the two dropout seed words (SMEM);
+    the row sum and the saved lse come from the undropped ``p``, the context
+    from ``p ⊙ keep`` (its ``1 / (1 - rate)`` is applied once, at the flush).
+    At rate 0 none of that is in the kernel."""
+    seed_ref, refs = (refs[0], refs[1:]) if rate else (None, refs)
     q_ref, k_ref, v_ref = refs[:3]
     mask_ref = refs[3] if has_mask else None
     o_ref, lse_ref, m_s, l_s, acc_s = refs[3 + has_mask:]
     qi, kj = pl.program_id(2), pl.program_id(3)
+    if rate:    # the mask's batch row and head group (asked for out here:
+        bi, gi = pl.program_id(0), pl.program_id(1)    # not inside a loop)
     bq, width = q_ref.shape[1], q_ref.shape[2]
     bk = k_ref.shape[1]
 
@@ -286,6 +380,10 @@ def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk):
                 l_s[g, rows] = alpha * l_prev + jnp.sum(p, axis=1,
                                                         keepdims=True)
                 m_s[g, rows] = m_next
+                if rate:
+                    p = jnp.where(_keep_strip(
+                        seed_ref, bi, gi * heads + g, qi * bq + r0,
+                        kj * bk + c0, p.shape, rate), p, 0.0)
                 v2 = v_ref[0, c0:c1, :]
                 acc_s[g, rows] = (acc_s[g, rows] * _lanes(alpha, width)
                                   + _dot(p.astype(v2.dtype), v2))  # [h, W]
@@ -299,7 +397,8 @@ def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk):
         outs = []
         for g in range(heads):
             l = jnp.maximum(l_s[g], 1e-30)                       # all-masked
-            outs.append(acc_s[g] / _lanes(l, width))
+            kept = l * (1.0 - rate) if rate else l
+            outs.append(acc_s[g] / _lanes(kept, width))
             lse_ref[0, 0, g:g + 1, :] = jnp.transpose(
                 m_s[g] + jnp.log(l))[:1, :]
         o_ref[0] = _merge_heads(outs, d).astype(o_ref.dtype)
@@ -364,13 +463,22 @@ def _dot_tn(a, b):
                            precision=_precision(a.dtype))
 
 
-def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk):
+def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk,
+                rate=0.0):
     """dQ of a query block, accumulated over its key blocks; ``with_kv``
     (the fused backward): dK and dV too, from the same recomputation of each
     P-tile — 5 matmuls and one exp a tile where a dq + dkv pair makes 7 and
     2. dK/dV of the whole key sequence then stay in VMEM scratch for a
     (batch, head group); ``pᵀ do`` and ``dsᵀ q`` contract the tile's rows
-    (a transposed left operand)."""
+    (a transposed left operand).
+
+    ``rate`` > 0 (the first operand is then the two seed words, SMEM): the
+    forward's keep mask is rebuilt from the same function. With ``r`` the
+    rate, ``dp = (do vᵀ) ⊙ keep / (1-r)``, ``ds = p ⊙ (dp - delta)``, ``dv =
+    (p ⊙ keep)ᵀ do / (1-r)``; the kernel works on ``(1-r) ds = p ⊙ ((do vᵀ)
+    ⊙ keep - (1-r) delta)`` and applies each ``1 / (1-r)`` at a flush, so a
+    score pays the hash and two selects and no multiply."""
+    seed_ref, refs = (refs[0], refs[1:]) if rate else (None, refs)
     q_ref, k_ref, v_ref = refs[:3]
     mask_ref = refs[3] if has_mask else None
     do_ref, lse_ref, delta_ref, dq_ref = refs[3 + has_mask:7 + has_mask]
@@ -379,6 +487,8 @@ def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk):
     else:
         lse_s, delta_s, dq_s = refs[7 + has_mask:]
     qi, kj = pl.program_id(2), pl.program_id(3)
+    if rate:
+        bi, gi = pl.program_id(0), pl.program_id(1)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
     key0 = 0 if nk == 1 else pl.multiple_of(kj * bk, bk)
 
@@ -393,7 +503,8 @@ def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk):
         dq_s[...] = jnp.zeros_like(dq_s)
         for g in range(heads):  # columns once a query block, not once a tile
             lse_s[g] = _cols(lse_ref[0, 0, g:g + 1, :])
-            delta_s[g] = _cols(delta_ref[0, 0, g:g + 1, :])
+            delta = _cols(delta_ref[0, 0, g:g + 1, :])
+            delta_s[g] = delta * (1.0 - rate) if rate else delta
 
     def tile(diagonal):
         def head(g):
@@ -406,32 +517,40 @@ def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk):
                 s = _scores(qg, k_ref, mask_ref, strip, diagonal)  # [h, c]
                 p = jnp.exp(s - _lanes(lse_s[g, rows], c1 - c0))
                 dp = _dot_nt(dog[rows], v_ref[0, c0:c1, :])
+                kept = p                          # p ⊙ keep, what dv sees
+                if rate:
+                    keep = _keep_strip(
+                        seed_ref, bi, gi * heads + g, qi * bq + r0,
+                        kj * bk + c0, p.shape, rate)
+                    dp, kept = jnp.where(keep, dp, 0.0), jnp.where(keep, p,
+                                                                   0.0)
                 ds = (p * (dp - _lanes(delta_s[g, rows], c1 - c0))
                       ).astype(k2.dtype)
                 dq_s[g, rows] = dq_s[g, rows] + _dot(ds, k2)     # [h, W]
                 if with_kv:
                     keys = pl.ds(key0 + c0, c1 - c0)
                     dv_s[g, keys] = dv_s[g, keys] + _dot_tn(
-                        p.astype(k2.dtype), do_ref[0, rows, :])  # [c, W]
+                        kept.astype(k2.dtype), do_ref[0, rows, :])  # [c, W]
                     dk_s[g, keys] = dk_s[g, keys] + _dot_tn(
                         ds, q_ref[0, rows, :])
 
         _for_each_head(heads, head)
 
     _on_causal_tiles(causal, kj, qi, nk, tile)
+    undrop = 1.0 / (1.0 - rate)
 
     @pl.when(kj == nk - 1)
     def _flush():
         dq = _merge_heads([dq_s[g] for g in range(heads)], d)
-        dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+        dq_ref[0] = (dq * (scale * undrop)).astype(dq_ref.dtype)
 
     if with_kv:
         @pl.when((qi == nq - 1) & (kj == nk - 1))
         def _flush_kv():
             dk = _merge_heads([dk_s[g] for g in range(heads)], d)
             dv = _merge_heads([dv_s[g] for g in range(heads)], d)
-            dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-            dv_ref[0] = dv.astype(dv_ref.dtype)
+            dk_ref[0] = (dk * (scale * undrop)).astype(dk_ref.dtype)
+            dv_ref[0] = (dv * undrop if rate else dv).astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -584,15 +703,31 @@ def _query_major(q, k, v, kv_mask, heads, causal):
     return geometry, in_specs, arrays, operands
 
 
+def _dropout_call(seed, rate, name):
+    """What a live dropout rate adds to a `pallas_call`, as (the kernel's
+    keywords, the leading in_specs, the leading operands, the call's
+    keywords): the rate, the two seed words as a first operand in SMEM, and
+    a name that says the kernel draws a mask (how
+    `dropout_kernel_calls_per_step` tells it from one that does not). At
+    rate 0 all four are empty: the call is the call without dropout."""
+    if not rate:
+        return {}, [], [], {}
+    return ({"rate": rate}, [pl.BlockSpec(memory_space=pltpu.SMEM)], [seed],
+            {"name": f"{name}_dropout"})
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "heads", "scale", "causal", "out_dtype", "interpret"))
-def _fwd_call(q, k, v, kv_mask, *, heads, scale, causal, out_dtype,
-              interpret):
+    "heads", "scale", "causal", "out_dtype", "interpret", "rate"))
+def _fwd_call(q, k, v, kv_mask, seed=None, *, heads, scale, causal,
+              out_dtype, interpret, rate=0.0):
     """(o ``[B, Sq, H·D]``, lse ``[B, H/G, G, Sq]``). One jitted function
-    a shape, so a model's layers lower one Pallas body between them."""
+    a shape, so a model's layers lower one Pallas body between them.
+    ``seed`` (``uint32[2]``) with a ``rate`` > 0: probabilities dropout."""
     geo, in_specs, arrays, operands = _query_major(q, k, v, kv_mask, heads,
                                                    causal)
     hpb, bq, width = geo["hpb"], geo["bq"], geo["width"]
+    drop, seed_spec, seed_operand, named = _dropout_call(seed, rate,
+                                                         "flash_fwd")
     out_specs = [geo["q_spec"], geo["rows"]]
     out_shape = [
         jax.ShapeDtypeStruct(q.shape, out_dtype),
@@ -604,9 +739,9 @@ def _fwd_call(q, k, v, kv_mask, *, heads, scale, causal, out_dtype,
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           has_mask=kv_mask is not None, heads=hpb,
-                          d=geo["d"], nk=geo["nk"]),
+                          d=geo["d"], nk=geo["nk"], **drop),
         grid=geo["grid"],
-        in_specs=in_specs,
+        in_specs=seed_spec + in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -616,19 +751,22 @@ def _fwd_call(q, k, v, kv_mask, *, heads, scale, causal, out_dtype,
         ],
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
-    )(*operands)
+        **named,
+    )(*seed_operand, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "heads", "scale", "causal", "out_dtype", "with_kv", "interpret"))
-def _bwd_call(q, k, v, kv_mask, do, lse, delta, *, heads, scale, causal,
-              out_dtype, with_kv, interpret):
+    "heads", "scale", "causal", "out_dtype", "with_kv", "interpret", "rate"))
+def _bwd_call(q, k, v, kv_mask, do, lse, delta, seed=None, *, heads, scale,
+              causal, out_dtype, with_kv, interpret, rate=0.0):
     """dQ ``[B, Sq, H·D]`` given the GLOBAL ``lse``/``delta``
     (``[B, H/G, G, Sq]``); ``with_kv``: (dQ, dK, dV) from the one fused
-    kernel."""
+    kernel. ``seed`` and ``rate``: the forward call's."""
     geo, in_specs, arrays, operands = _query_major(q, k, v, kv_mask, heads,
                                                    causal)
     hpb, bq, width = geo["hpb"], geo["bq"], geo["width"]
+    drop, seed_spec, seed_operand, named = _dropout_call(seed, rate,
+                                                         "flash_bwd")
     in_specs += [geo["q_spec"], geo["rows"], geo["rows"]]
     arrays += [(do.shape, do.dtype), (lse.shape, lse.dtype),
                (delta.shape, delta.dtype)]
@@ -651,9 +789,10 @@ def _bwd_call(q, k, v, kv_mask, do, lse, delta, *, heads, scale, causal,
     out = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, causal=causal,
                           has_mask=kv_mask is not None, with_kv=with_kv,
-                          heads=hpb, d=geo["d"], nq=geo["nq"], nk=geo["nk"]),
+                          heads=hpb, d=geo["d"], nq=geo["nq"], nk=geo["nk"],
+                          **drop),
         grid=geo["grid"],
-        in_specs=in_specs,
+        in_specs=seed_spec + in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
@@ -663,7 +802,8 @@ def _bwd_call(q, k, v, kv_mask, do, lse, delta, *, heads, scale, causal,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
             vmem_limit_bytes=_COMPILER_PARAMS.vmem_limit_bytes),
-    )(*operands, do, lse, delta)
+        **named,
+    )(*seed_operand, *operands, do, lse, delta)
     return out if with_kv else out[0]
 
 
@@ -716,30 +856,31 @@ def _dkv_call(q, k, v, kv_mask, do, lse, delta, *, heads, scale, causal,
     return dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, kv_mask, heads, scale, causal):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, kv_mask, seed, heads, scale, causal, rate):
     """Attention over ``[B, S, H·D]`` operands (``kv_mask`` ``[B, Sk]`` or
-    None)."""
-    return _flash_fwd(q, k, v, kv_mask, heads, scale, causal)[0]
+    None; ``seed`` ``uint32[2]`` where ``rate`` > 0, else None)."""
+    return _flash_fwd(q, k, v, kv_mask, seed, heads, scale, causal, rate)[0]
 
 
-def _flash_fwd(q, k, v, kv_mask, heads, scale, causal):
-    o, lse = _fwd_call(q, k, v, kv_mask, heads=heads, scale=scale,
+def _flash_fwd(q, k, v, kv_mask, seed, heads, scale, causal, rate):
+    o, lse = _fwd_call(q, k, v, kv_mask, seed, heads=heads, scale=scale,
                        causal=causal, out_dtype=q.dtype,
-                       interpret=_interpret())
-    return o, (q, k, v, kv_mask, o, lse)
+                       interpret=_interpret(), rate=rate)
+    return o, (q, k, v, kv_mask, seed, o, lse)
 
 
-def _flash_bwd(heads, scale, causal, res, do):
-    q, k, v, kv_mask, o, lse = res
+def _flash_bwd(heads, scale, causal, rate, res, do):
+    q, k, v, kv_mask, seed, o, lse = res
     b, sq, hd = q.shape
     delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
                     .reshape(b, sq, heads, hd // heads), axis=-1)
     delta = delta.transpose(0, 2, 1).reshape(lse.shape)
-    dq, dk, dv = _bwd_call(q, k, v, kv_mask, do, lse, delta, heads=heads,
-                           scale=scale, causal=causal, out_dtype=q.dtype,
-                           with_kv=True, interpret=_interpret())
-    return dq, dk, dv, None
+    dq, dk, dv = _bwd_call(q, k, v, kv_mask, do, lse, delta, seed,
+                           heads=heads, scale=scale, causal=causal,
+                           out_dtype=q.dtype, with_kv=True,
+                           interpret=_interpret(), rate=rate)
+    return dq, dk, dv, None, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -792,11 +933,17 @@ def flash_attention(
     causal: bool = False,
     scale: Optional[float] = None,
     kv_mask: Optional[jax.Array] = None,
+    dropout_rng: Optional[jax.Array] = None,
+    dropout_rate: float = 0.0,
 ) -> jax.Array:
     """Tiled exact attention over ``[B, S, H, D]`` inputs.
 
     ``kv_mask``: optional key-validity mask ``[B, S_k]`` (True = attend).
     Differentiable (flash backward). ``causal`` needs ``S_q == S_k``.
+    ``dropout_rng`` with a static ``dropout_rate`` > 0: dropout of the
+    attention probabilities inside the kernels, by the mask
+    `dropout_keep_mask` builds for the same key; without a key or at rate 0
+    the kernels hold no dropout code.
 
     Sequence-length constraint: a sequence of at most 512 rows is one
     block and always legal (``S_q = 1`` decode included); a longer one is
@@ -808,30 +955,31 @@ def flash_attention(
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     scale = float(D ** -0.5 if scale is None else scale)
+    rate = float(dropout_rate) if dropout_rng is not None else 0.0
+    if not 0.0 <= rate < 1.0:
+        raise ValueError(f"dropout_rate must lie in [0, 1), got {rate}")
+    seed = dropout_seed_words(dropout_rng) if rate else None
     # [B,S,H,D] -> [B,S,H·D] is free: no transpose surrounds the kernels
     o = _flash(q.reshape(B, Sq, H * D), k.reshape(B, Sk, H * D),
-               v.reshape(B, Sk, H * D), kv_mask, H, scale, causal)
+               v.reshape(B, Sk, H * D), kv_mask, seed, H, scale, causal, rate)
     return o.reshape(B, Sq, H, D)
+
+
+def key_validity(mask):
+    """The kernels' ``kv_mask`` ``[B, S]`` from the models' ADDITIVE
+    key-padding mask ``[B, 1, 1, S]`` (0 = attend, -1e9 = padding)."""
+    return mask.reshape(mask.shape[0], mask.shape[-1]) > -1.0
 
 
 def make_flash_attention_impl():
     """Model-zoo ``attention_impl`` (models/bert.py contract) backed by the
-    kernel. Attention-prob dropout is not expressible in the tiled kernel,
-    so a live dropout rate raises (as `models.gpt.flash_causal_attention_impl`
-    does): a caller who asked for the kernel must never time the dense path
-    under its name. Zero ``attention_probs_dropout_prob`` to use it."""
+    kernel, attention-probabilities dropout included (the kernels' own
+    mask: `dropout_keep_mask`)."""
 
     def impl(q, k, v, mask, dropout_rng=None, dropout_rate=0.0, dtype=None):
-        if dropout_rng is not None and dropout_rate > 0.0:
-            raise ValueError(
-                "flash attention kernel has no attention-dropout path; "
-                "set attention_probs_dropout_prob=0"
-            )
         with jax.named_scope("attention"):
-            kv_mask = None
-            if mask is not None:
-                # model masks are ADDITIVE [B,1,1,S]; kernel wants validity
-                kv_mask = mask.reshape(mask.shape[0], mask.shape[-1]) > -1.0
-            return flash_attention(q, k, v, kv_mask=kv_mask)
+            return flash_attention(
+                q, k, v, kv_mask=None if mask is None else key_validity(mask),
+                dropout_rng=dropout_rng, dropout_rate=dropout_rate)
 
     return impl

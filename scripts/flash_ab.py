@@ -7,6 +7,7 @@ a CPU run would time the Pallas interpreter.
     python scripts/flash_ab.py            # full sweep, prints a table
     python scripts/flash_ab.py --causal   # the GPT shapes
     python scripts/flash_ab.py --causal --shapes 16x1024x12x64 --blocks 512 --strips 128
+    python scripts/flash_ab.py --dropout 0.1 --shapes 16x512x16x64 --hw-prng
 
 Three columns over (batch, seq, heads, head_dim) shapes, all taking and
 returning the model's ``[B, S, H, D]``: XLA's dense program (what the model
@@ -20,7 +21,13 @@ forward+backward; the causal half is not discounted, as in the
 benchmark's `flops_per_token`) at the chip's bf16 peak. ``--blocks`` and
 ``--strips`` time our kernel at other sequence blocks and strip heights than
 its own (a tuning aid: both are constants of the code, not options of the
-program).
+program). ``--dropout RATE`` times attention-probabilities dropout: XLA's
+dense program with the threefry mask the models' dense cores draw against
+our kernel with its own mask (`ops.flash_attention.dropout_keep_mask`),
+whose errors are then against dense f32 under that same mask; JAX's kernel
+has no dropout and is left out. ``--hw-prng`` adds our kernel with the
+chip's own generator in place of the hash (timing only: that mask cannot be
+reproduced off the chip, so it is no path of the program).
 """
 
 from __future__ import annotations
@@ -48,9 +55,10 @@ SHAPES = [  # (batch, seq, heads, head_dim) — flash_attention's [B,S,H,D]
 PEAK_FLOPS = {"TPU v5 lite": 197e12}
 
 
-def xla_attention(q, k, v, causal):
+def xla_attention(q, k, v, causal, keep=None, rate=0.0, kv_mask=None):
     """Plain composed attention over [B, S, H, D] (what the model zoo
-    runs when no kernel applies)."""
+    runs when no kernel applies). ``keep``: a dropout key (the threefry
+    mask the models' dense cores draw) or a ready ``[B, H, S, S]`` mask."""
     import jax
     import jax.numpy as jnp
 
@@ -60,8 +68,28 @@ def xla_attention(q, k, v, causal):
         S = q.shape[1]
         tri = jnp.tril(jnp.ones((S, S), jnp.bool_))
         s = jnp.where(tri[None, None], s, jnp.asarray(-1e9, s.dtype))
+    if kv_mask is not None:     # the models' additive [B, 1, 1, S] form
+        s = s + jnp.where(kv_mask, 0.0, -1e9).astype(s.dtype)[:, None, None]
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    if keep is not None:
+        if keep.dtype != jnp.bool_:
+            keep = jax.random.bernoulli(keep, 1.0 - rate, p.shape)
+        p = p * keep / (1.0 - rate)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
+
+
+def hw_prng_keep(seed_ref, batch, head, row0, col0, shape, rate):
+    """Stand-in for `ops.flash_attention._keep_strip` drawing the strip's
+    bits from the chip's generator, seeded by the strip's coordinates."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    i32 = lambda x: jnp.asarray(x).astype(jnp.int32)  # noqa: E731
+    # the generator takes two seed values: fold the coordinates into them
+    pltpu.prng_seed(i32(seed_ref[0]) ^ (i32(batch) * 4099 + i32(head)),
+                    i32(seed_ref[1]) ^ (i32(row0) * 65599 + i32(col0)))
+    bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
+    return bits < jnp.uint32(round((1.0 - rate) * 2 ** 32))
 
 
 def upstream_attention(q, k, v, causal):
@@ -102,6 +130,12 @@ def main() -> int:
                     "sequence blocks, e.g. '256,512'")
     ap.add_argument("--strips", help="... and at these strip heights "
                     "(at its own block), e.g. '128,512'")
+    ap.add_argument("--dropout", type=float, default=0.0, metavar="RATE",
+                    help="attention-probabilities dropout at this rate")
+    ap.add_argument("--kv-mask", action="store_true", help="hand every impl "
+                    "but JAX's an all-valid key mask (BERT's packed batch)")
+    ap.add_argument("--hw-prng", action="store_true", help="with --dropout: "
+                    "also time our kernel on the chip's own generator")
     args = ap.parse_args()
 
     import jax
@@ -120,6 +154,10 @@ def main() -> int:
         (const, int(x)) for const, given in (("_BLOCK", args.blocks),
                                              ("_STRIP", args.strips))
         for x in (given or "").split(",") if x]
+    rate = args.dropout
+    if args.hw_prng and rate:
+        variants.append(("_keep_strip", hw_prng_keep))
+    rng = jax.random.PRNGKey(42) if rate else None
 
     dtype = jnp.dtype(args.dtype)
     dev = jax.devices()[0]
@@ -127,7 +165,7 @@ def main() -> int:
         raise SystemExit(f"flash_ab.py times TPU kernels; found {dev.platform}")
     peak = PEAK_FLOPS[dev.device_kind]
     print(f"device: {dev.device_kind}  causal={args.causal}  "
-          f"dtype={dtype.name}  iters={args.iters}  "
+          f"dropout={rate}  dtype={dtype.name}  iters={args.iters}  "
           f"floor: full-square model FLOPs at {peak / 1e12:.0f} TFLOP/s")
     print(f"{'shape':>18} {'impl':>14} | {'fwd ms':>8} {'floor%':>6} | "
           f"{'f+b ms':>8} {'floor%':>6} {'vs xla':>6} | max abs err vs "
@@ -144,19 +182,28 @@ def main() -> int:
         k = jax.random.normal(kk, (b, s, h, d)).astype(dtype)
         v = jax.random.normal(kv, (b, s, h, d)).astype(dtype)
         floor_f = 4 * b * h * s * s * d / peak
-        impls = [("xla dense", functools.partial(xla_attention,
-                                                 causal=args.causal), None),
-                 ("jax pallas", functools.partial(upstream_attention,
-                                                  causal=args.causal), None)]
-        impls += [("ours" + (f" {var[0][1:].lower()} {var[1]}" if var else ""),
-                   functools.partial(fa.flash_attention, causal=args.causal),
+        kv_mask = jnp.ones((b, s), jnp.bool_) if args.kv_mask else None
+        impls = [("xla dense", functools.partial(
+            xla_attention, causal=args.causal, keep=rng, rate=rate,
+            kv_mask=kv_mask), None)]
+        if not rate:
+            impls.append(("jax pallas", functools.partial(
+                upstream_attention, causal=args.causal), None))
+        impls += [("ours" + ((" hw prng" if callable(var[1]) else
+                              f" {var[0][1:].lower()} {var[1]}")
+                             if var else ""),
+                   functools.partial(fa.flash_attention, causal=args.causal,
+                                     kv_mask=kv_mask, dropout_rng=rng,
+                                     dropout_rate=rate),
                    var) for var in variants]
+        # the yardstick: dense f32, under our kernel's own mask if any
+        keep = fa.dropout_keep_mask(rng, b, h, s, s, rate) if rate else None
+        dense = functools.partial(xla_attention, causal=args.causal,
+                                  keep=keep, rate=rate)
         with jax.default_matmul_precision("highest"):
             f32 = [x.astype(jnp.float32) for x in (q, k, v)]
-            want = [xla_attention(*f32, args.causal)] + list(
-                grads(functools.partial(xla_attention,
-                                        causal=args.causal))(*f32))
-            want = [np.asarray(w) for w in want]
+            want = [np.asarray(w)
+                    for w in [dense(*f32)] + list(grads(dense)(*f32))]
         base = None
         for name, fn, var in impls:
             if var:
@@ -171,6 +218,8 @@ def main() -> int:
                 errs = " ".join(
                     f"{np.max(np.abs(np.asarray(g, np.float32) - w)):.1e}"
                     for g, w in zip(got, want))
+                if rate and (name == "xla dense" or name.endswith("hw prng")):
+                    errs = "another mask: not compared"
             except Exception as e:  # the compiler's refusal is the result
                 print(f"({b:>2},{s:>5},{h:>3},{d:>3}) {name:>14} | REFUSED: "
                       f"{str(e).splitlines()[0][:200]}")

@@ -12,6 +12,7 @@ TPU's library, so only the xdist worker that is handed this file does.
 """
 
 import functools
+import re
 import sys
 
 import jax
@@ -100,9 +101,77 @@ def test_flash_kernel_compiles_for_v5e(compiled_kernels, one_chip, shape,
 
 #: a Pallas kernel in optimized HLO text
 KERNEL = 'custom_call_target="tpu_custom_call"'
+#: ... that draws a dropout mask (the name `ops.flash_attention` gives it)
+DROPOUT_KERNEL = re.compile(r"/flash_(?:fwd|bwd)_dropout/")
 
 
-@pytest.mark.parametrize("attn_dropout,kernels", [(0.0, 4), (0.1, 0)],
+# BERT-Large's attention in the benchmark cell: probabilities dropout
+# inside the kernels, a key mask, not causal
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_dropout_kernels_compile_for_v5e(compiled_kernels, one_chip,
+                                         direction):
+    def attend(q, k, v, kv_mask, rng):
+        return flash_attention(q, k, v, kv_mask=kv_mask, dropout_rng=rng,
+                               dropout_rate=0.1)
+
+    fn = attend if direction == "fwd" else jax.grad(
+        lambda q, k, v, m, rng: attend(q, k, v, m, rng).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+    x = jax.ShapeDtypeStruct((16, 512, 16, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv_mask = jax.ShapeDtypeStruct((16, 512), jnp.bool_, sharding=one_chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(fn).lower(x, x, x, kv_mask, rng).compile().as_text()
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    assert len(calls) == (1 if direction == "fwd" else 2)
+    assert all(DROPOUT_KERNEL.search(line) for line in calls)
+
+
+def test_default_bert_large_step_selects_the_dropout_kernels(
+        compiled_kernels, one_chip):
+    """BERT-Large at published widths and dropout, two layers, S=512, no
+    ``attention_impl`` passed: the default core puts a forward and a fused
+    backward kernel with dropout into each layer of the gradient program,
+    under an ``attention`` scope (what `attention_core_ms` and
+    `dropout_kernel_calls_per_step` read)."""
+    import dataclasses
+
+    from dear_pytorch_tpu import models
+
+    cfg = dataclasses.replace(models.get_model(
+        "bert_large", dtype=jnp.bfloat16).config, num_hidden_layers=2)
+    assert cfg.attention_probs_dropout_prob == 0.1
+    model = models.BertForPreTraining(cfg)
+    shape = (4, 512)
+    ids = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda key: model.init({"params": key}, jnp.zeros((1, 512),
+                                                          jnp.int32),
+                               train=False)["params"],
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        params)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    nsp = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+
+    def loss(p, ids, labels, nsp_labels, rng):
+        logits, nsp_logits = model.apply(
+            {"params": p}, ids, jnp.zeros_like(ids), jnp.ones_like(ids),
+            train=True, rngs={"dropout": rng})
+        return models.bert_pretraining_loss(logits, nsp_logits, labels,
+                                            nsp_labels)
+
+    text = jax.jit(jax.grad(loss)).lower(params, ids, ids, nsp,
+                                         rng).compile().as_text()
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    assert len(calls) == 4
+    assert all("/attention/" in line and DROPOUT_KERNEL.search(line)
+               for line in calls)
+    assert sum("transpose(jvp(" in line for line in calls) == 2
+
+
+@pytest.mark.parametrize("attn_dropout,kernels", [(0.0, 4), (0.1, 4)],
                          ids=["no-dropout", "attn-dropout"])
 def test_default_gpt2_step_selects_the_kernel(compiled_kernels, one_chip,
                                               attn_dropout, kernels):
@@ -110,7 +179,8 @@ def test_default_gpt2_step_selects_the_kernel(compiled_kernels, one_chip,
     passed: the default core puts a forward and a fused backward kernel into
     each layer of the gradient program, both under an ``attention`` scope
     (what `attention_core_ms` and `attention_kernel_calls_per_step` read);
-    with GPT-2's published ``attn_pdrop`` it stays the dense program."""
+    with GPT-2's published ``attn_pdrop`` they are the kernels that drop
+    probabilities themselves, without it the kernels without."""
     import dataclasses
 
     cfg = dataclasses.replace(
@@ -139,6 +209,8 @@ def test_default_gpt2_step_selects_the_kernel(compiled_kernels, one_chip,
     assert len(calls) == kernels
     assert all("/attention/" in line for line in calls)
     assert sum("transpose(jvp(" in line for line in calls) == kernels // 2
+    assert all(bool(DROPOUT_KERNEL.search(line)) == (attn_dropout > 0)
+               for line in calls)
 
 
 def test_dear_step_compiles_for_four_v5e_chips(compiled_kernels, mesh4):

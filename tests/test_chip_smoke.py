@@ -64,6 +64,27 @@ def test_flash_phase_agrees_with_dense_and_reports_the_kernel(dense):
     assert res["kernel_in_program"] is False
 
 
+def test_dropout_phase_checks_values_and_the_applied_mask(monkeypatch):
+    """The dropout phase at a tiny shape (two strips a tile): kernel and
+    dense program agree under the same mask in bf16 and f32, the applied
+    keep counts are the dense mask's, and a kernel that kept everything
+    would be caught."""
+    res = chip_smoke.phase_dropout((2, 128, 2, 64), seed=0)
+    assert max(res["errors"]["bfloat16"].values()) < chip_smoke.FLASH_TOL
+    assert max(res["errors"]["float32"].values()) < 1e-4
+    assert abs(res["keep_share"] - 0.9) < 0.01
+    fa = sys.modules["dear_pytorch_tpu.ops.flash_attention"]
+    monkeypatch.setattr(
+        fa, "_keep_strip",
+        lambda seed, b, h, r, c, shape, rate: jnp.ones(shape, jnp.bool_))
+    jax.clear_caches()
+    got, want = chip_smoke.applied_keep_counts((1, 128, 2, 64), seed=0)
+    assert (got == 128).all() and (want < 128).any()
+    with pytest.raises(AssertionError, match="under the same mask"):
+        chip_smoke.phase_dropout((1, 128, 2, 64), seed=0)
+    jax.clear_caches()
+
+
 def test_dp_phase_spreads_the_work_over_four_devices():
     res = chip_smoke.phase_dp(_mesh(4), _tiny(jnp.bfloat16), global_batch=8,
                               seq_len=64, steps=6, seed=0)

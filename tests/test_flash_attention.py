@@ -102,24 +102,29 @@ def test_bf16_inputs():
     )
 
 
-def test_bert_impl_contract_and_dropout_refusal():
-    """The attention_impl adapter matches the dense model path exactly at
-    dropout 0 and REFUSES a live dropout rate (the kernel has no dropout
-    path; a silent dense fall-through would be timed under its name)."""
+def test_bert_impl_contract_with_dropout():
+    """The attention_impl adapter matches the dense model path at dropout
+    0, and with a live rate drops the probabilities inside the kernel: the
+    dense path under `dropout_keep_mask`'s mask for the same key."""
     from dear_pytorch_tpu.models.bert import dot_product_attention
 
     impl = make_flash_attention_impl()
     q, k, v = _qkv(jax.random.PRNGKey(5))
     additive = jnp.where(
-        jnp.arange(S)[None, None, None, :] < 50, 0.0, _big := -1e9
+        jnp.arange(S)[None, None, None, :] < 50, 0.0, -1e9
     ) * jnp.ones((B, 1, 1, 1))
     got = impl(q, k, v, additive)
     want = dot_product_attention(q, k, v, additive)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-    with pytest.raises(ValueError, match="no attention-dropout path"):
-        impl(q, k, v, additive, dropout_rng=jax.random.PRNGKey(9),
-             dropout_rate=0.5)
+    rng = jax.random.PRNGKey(9)
+    got = impl(q, k, v, additive, dropout_rng=rng, dropout_rate=0.5)
+    keep = FA.dropout_keep_mask(rng, B, H, S, S, 0.5)
+    want = _dense(q, k, v, False, additive[:, 0, 0] > -1.0, keep, 0.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    assert not np.allclose(np.asarray(got),
+                           np.asarray(impl(q, k, v, additive)), atol=1e-3)
 
 
 def test_bert_end_to_end_with_flash_impl():
@@ -237,19 +242,27 @@ def blocks_of_128(monkeypatch):
     jax.clear_caches()
 
 
-def _dense(q, k, v, causal, kv_mask):
+def _dense(q, k, v, causal, kv_mask, keep=None, rate=0.0):
+    """Dense attention; ``keep`` ``[B, H, Sq, Sk]`` drops probabilities."""
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
     if causal:
         tri = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
         s = jnp.where(tri[None, None], s, -jnp.inf)
     if kv_mask is not None:
         s = jnp.where(kv_mask[:, None, None, :], s, -jnp.inf)
-    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    p = jax.nn.softmax(s, axis=-1)
+    if keep is not None:
+        p = p * keep / (1.0 - rate)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _check_against_dense(shape, mode, dtype, key=0):
+def _check_against_dense(shape, mode, dtype, key=0, rate=0.0):
+    """``rate`` > 0: the kernel drops probabilities with a key, the dense
+    program with `dropout_keep_mask`'s mask for that key."""
     b, s, h, d = shape
     ks = jax.random.split(jax.random.PRNGKey(key), 4)
+    rng = jax.random.PRNGKey(key + 100) if rate else None
+    keep = FA.dropout_keep_mask(rng, b, h, s, s, rate) if rate else None
     q, k, v = (jax.random.normal(kk, shape, jnp.float32).astype(dtype)
                for kk in ks[:3])
     w = jax.random.normal(ks[3], shape, jnp.float32)   # a generic cotangent
@@ -264,9 +277,11 @@ def _check_against_dense(shape, mode, dtype, key=0):
         return lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32) * w)
 
     flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, causal=causal, kv_mask=kv_mask)
+        q, k, v, causal=causal, kv_mask=kv_mask, dropout_rng=rng,
+        dropout_rate=rate)
     f32 = [x.astype(jnp.float32) for x in (q, k, v)]
-    dense = lambda q, k, v: _dense(q, k, v, causal, kv_mask)  # noqa: E731
+    dense = lambda q, k, v: _dense(  # noqa: E731
+        q, k, v, causal, kv_mask, keep, rate)
     got = flash(q, k, v)
     assert got.dtype == dtype
     tol = TOL[dtype]
@@ -389,3 +404,161 @@ def test_pair_kernels_agree_with_the_fused_backward():
     for got, w, name in zip((dq, dk, dv), want, "qkv"):
         np.testing.assert_allclose(np.asarray(got), np.asarray(w),
                                    rtol=5e-4, atol=5e-5, err_msg=f"d{name}")
+
+
+# ---------------------------------------------------------------------------
+# attention-probabilities dropout inside the kernels
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """128-row blocks worked through in 64-row strips: a 256-row sequence
+    is two blocks each way and two strips a tile, so a mask drawn from
+    tile-relative coordinates would repeat."""
+    monkeypatch.setattr(FA, "_BLOCK", 128)
+    monkeypatch.setattr(FA, "_STRIP", 64)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("mode", ["full", "kv_mask", "causal",
+                                  "causal+kv_mask"])
+@pytest.mark.parametrize("shape", [
+    (2, 256, 2, 64),     # two 64-wide heads a 128-lane block
+    (1, 256, 2, 128),    # one head a block, two head groups
+], ids=["2x64", "1x128"])
+def test_dropout_kernel_matches_dense_under_the_same_mask(small_tiles, shape,
+                                                          mode, dtype):
+    """Output, dq, dk, dv of the kernels with dropout live against the
+    dense f32 program that drops with `dropout_keep_mask`'s mask."""
+    _check_against_dense(shape, mode, dtype, key=5, rate=0.1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_dropout_kernel_at_the_default_tiles(dtype):
+    """S=512 at the default block: one tile of two 256-row strips, the
+    shape of BERT-Large's call (a key mask, not causal)."""
+    _check_against_dense((1, 512, 2, 64), "kv_mask", dtype, key=6, rate=0.1)
+
+
+def _kernel_mask(shape, rng, rate):
+    """The mask the forward kernel applies, read off its output: with q = 0
+    every probability is 1/S, and with v's row j the j-th unit vector
+    (D >= S) output row i reads keep[i, :] / ((1 - rate) S)."""
+    b, s, h, d = shape
+    zeros = jnp.zeros(shape, jnp.float32)
+    v = jnp.broadcast_to(jnp.eye(s, d, dtype=jnp.float32)[None, :, None, :],
+                         shape)
+    out = flash_attention(zeros, zeros, v, dropout_rng=rng,
+                          dropout_rate=rate)
+    return np.asarray(out[..., :s] * ((1.0 - rate) * s) > 0.5).transpose(
+        0, 2, 1, 3)
+
+
+def test_the_mask_does_not_depend_on_the_tiling(monkeypatch):
+    """Absolute coordinates: the kernel's mask is `dropout_keep_mask`'s
+    at the default block and strip and at smaller ones."""
+    shape, rate = (2, 256, 2, 256), 0.1
+    rng = jax.random.PRNGKey(3)
+    want = np.asarray(FA.dropout_keep_mask(rng, 2, 2, 256, 256, rate))
+    np.testing.assert_array_equal(_kernel_mask(shape, rng, rate), want)
+    for block, strip in ((128, 64), (128, 128)):
+        monkeypatch.setattr(FA, "_BLOCK", block)
+        monkeypatch.setattr(FA, "_STRIP", strip)
+        jax.clear_caches()
+        np.testing.assert_array_equal(_kernel_mask(shape, rng, rate), want)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("how", ["rate 0", "no key"])
+def test_no_live_dropout_is_the_undropped_kernel(how):
+    """Rate 0 or no key: the same kernels as without the arguments (no seed
+    operand, no hash, no select in the traced program) and so the same
+    bits; a live rate adds them."""
+    q, k, v = _qkv(jax.random.PRNGKey(8))
+    kw = (dict(dropout_rng=jax.random.PRNGKey(1), dropout_rate=0.0)
+          if how == "rate 0" else dict(dropout_rng=None, dropout_rate=0.1))
+
+    def program(**kw):
+        return str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            **kw).sum(),
+            argnums=(0, 1, 2)))(q, k, v))
+
+    plain = program()
+    assert program(**kw) == plain
+    assert "shift_right_logical" not in plain and "_dropout" not in plain
+    live = program(dropout_rng=jax.random.PRNGKey(1), dropout_rate=0.1)
+    assert "shift_right_logical" in live
+    assert "flash_fwd_dropout" in live and "flash_bwd_dropout" in live
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention(q, k, v, causal=True, **kw)),
+        np.asarray(flash_attention(q, k, v, causal=True)))
+
+
+def test_keep_mask_statistics():
+    """An i.i.d. Bernoulli(0.9) mask, as far as a test can tell: the share
+    within 4 sigma of 0.9, neighbours along rows and columns uncorrelated,
+    and masks of other seed words, batch rows and heads unrelated."""
+    rate, shape = 0.1, (2, 4, 512, 512)
+    n = float(np.prod(shape))
+    seed = jnp.array([0x1234ABCD, 0x0BADCAFE], jnp.uint32)
+    keep = np.asarray(FA.dropout_keep_mask(seed, *shape, rate))
+    assert keep.shape == shape and keep.dtype == np.bool_
+    sigma = (rate * (1 - rate) / n) ** 0.5
+    assert abs(keep.mean() - (1 - rate)) < 4 * sigma
+
+    def correlation(a, b):
+        return np.corrcoef(a.ravel().astype(np.float64),
+                           b.ravel().astype(np.float64))[0, 1]
+
+    pairs = {
+        "column neighbours": (keep[..., :-1], keep[..., 1:]),
+        "row neighbours": (keep[..., :-1, :], keep[..., 1:, :]),
+        "diagonal neighbours": (keep[..., :-1, :-1], keep[..., 1:, 1:]),
+        "batch rows": (keep[0], keep[1]),
+        "heads 0, 1": (keep[:, 0], keep[:, 1]),
+        "heads 1, 3": (keep[:, 1], keep[:, 3]),
+    }
+    for word in (0, 1):
+        other = seed.at[word].add(1)
+        pairs[f"seed word {word} + 1"] = (
+            keep, np.asarray(FA.dropout_keep_mask(other, *shape, rate)))
+    for what, (a, b) in pairs.items():
+        assert abs(correlation(a, b)) < 4 / a.size ** 0.5, what
+    # every row and every column is dropped from: no axis shares a decision
+    assert (~keep).any(axis=-1).all() and (~keep).any(axis=-2).all()
+
+
+@pytest.mark.parametrize("rate", [0.1, 0.5, 0.25])
+def test_keep_share_follows_the_rate(rate):
+    keep = np.asarray(FA.dropout_keep_mask(jax.random.PRNGKey(4), 1, 2, 512,
+                                           512, rate))
+    sigma = (rate * (1 - rate) / keep.size) ** 0.5
+    assert abs(keep.mean() - (1 - rate)) < 4 * sigma
+
+
+def test_seed_words_of_typed_and_raw_keys():
+    """A typed key and its raw ``uint32[2]`` data seed the same mask; a
+    wider key's words are folded into two."""
+    raw = jax.random.PRNGKey(11)
+    typed = jax.random.wrap_key_data(raw)
+    np.testing.assert_array_equal(np.asarray(FA.dropout_seed_words(raw)),
+                                  np.asarray(raw))
+    np.testing.assert_array_equal(np.asarray(FA.dropout_seed_words(typed)),
+                                  np.asarray(raw))
+    wide = jnp.arange(4, dtype=jnp.uint32) + 7
+    np.testing.assert_array_equal(np.asarray(FA.dropout_seed_words(wide)),
+                                  np.asarray(wide[:2] ^ wide[2:]))
+
+
+def test_dropout_rate_must_be_below_one():
+    q = jnp.zeros((1, 8, 1, 8))
+    with pytest.raises(ValueError, match="dropout_rate"):
+        flash_attention(q, q, q, dropout_rng=jax.random.PRNGKey(0),
+                        dropout_rate=1.0)
