@@ -73,11 +73,6 @@ METHOD_ARGS: dict[str, list[str]] = {
     "bytescheduler": ["--mode", "bytescheduler", "--threshold", "25",
                       "--partition", "4"],
     "fsdp": ["--mode", "fsdp", "--threshold", "25"],
-    # time-breakdown ablations (reference dear/batch.sh:18-43)
-    "dear-noag": ["--mode", "dear", "--threshold", "25",
-                  "--exclude-parts", "allgather"],
-    "dear-nors": ["--mode", "dear", "--threshold", "25",
-                  "--exclude-parts", "reducescatter"],
     "eftopk-mc": ["--mode", "allreduce", "--threshold", "25",
                   "--compressor", "eftopk", "--density", "0.01",
                   "--momentum-correction", "0.9"],

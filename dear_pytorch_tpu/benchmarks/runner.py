@@ -277,9 +277,6 @@ def add_common_args(parser) -> None:
                              "<=0 disables the limit (single bucket)")
     parser.add_argument("--nearby-layers", type=int, default=None,
                         help="fuse every k layers instead of by threshold")
-    parser.add_argument("--exclude-parts", type=str, default="",
-                        help="comma list of {reducescatter,allgather} "
-                             "(time-breakdown ablations, dear/batch.sh)")
     parser.add_argument("--compressor", type=str, default="none",
                         help="gradient compressor (reference "
                              "dear/compression.py registry)")
@@ -496,14 +493,6 @@ def log_mfu(ts, state, batch, result: BenchResult,
     return value
 
 
-def parse_exclude_parts(s: str) -> tuple[str, ...]:
-    parts = tuple(p.strip() for p in s.split(",") if p.strip())
-    for p in parts:
-        if p not in ("reducescatter", "allgather"):
-            raise SystemExit(f"--exclude-parts: unknown part {p!r}")
-    return parts
-
-
 def threshold_mb(args) -> Optional[float]:
     return None if args.threshold is None or args.threshold <= 0 else float(args.threshold)
 
@@ -548,7 +537,6 @@ def config_from_args(args, *, fp16_comm: bool = True,
         mode=args.mode,
         threshold_mb=threshold_mb(args),
         nearby_layers=args.nearby_layers,
-        exclude_parts=parse_exclude_parts(args.exclude_parts),
         autotune=args.autotune,
         compressor=args.compressor if use_compression else None,
         density=args.density,

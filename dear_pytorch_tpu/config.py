@@ -34,7 +34,6 @@ class DearConfig:
     # schedule (replaces the reference's one-directory-per-method layout)
     mode: str = "dear"    # dear | dear-fused | allreduce | rsag | rb |
     #                       bytescheduler | fsdp
-    exclude_parts: tuple = ()               # ('reducescatter'|'allgather')*
     partition_mb: float = 4.0               # bytescheduler chunk size (MB)
 
     # tensor fusion (dear/dopt_rsag.py:37-40)
@@ -150,8 +149,6 @@ class DearConfig:
             return raw.lower() in ("1", "true", "yes")
         if name in ("comm_dtype", "gather_dtype"):
             return _COMM_DTYPES[raw.lower()]
-        if name == "exclude_parts":
-            return tuple(p for p in raw.split(",") if p)
         if name == "flags":
             return [int(x) for x in raw.split(",")]
         if name == "bo_bound":
@@ -201,7 +198,6 @@ class DearConfig:
         separate because the autotuner owns them when enabled)."""
         return dict(
             mode=self.mode,
-            exclude_parts=self.exclude_parts,
             optimizer=self.optimizer(),
             comm_dtype=self.comm_dtype,
             gather_dtype=self.gather_dtype,

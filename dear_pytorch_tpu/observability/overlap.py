@@ -192,8 +192,9 @@ def audit_train_step(
 
     ``alpha``/``beta`` come from a `CommunicationProfiler.fit()` on the
     target mesh (or a synthetic model in tests). ``compute_time_s`` is the
-    communication-free step time — measure it with the 'dear' mode's
-    ``exclude_parts`` ablation (what `report.main` does), or pass None to
+    communication-free step time — measure it as the same step on a
+    one-device mesh with one device's share of the batch (what
+    `report.main` does), or pass None to
     fall back to XLA-counted FLOPs over the device's known peak (TPU only;
     when neither exists the exposure split is reported as None rather
     than guessed).

@@ -2,8 +2,9 @@
 
 ``python -m dear_pytorch_tpu.observability.report`` builds a bucketed MLP
 train step per schedule mode on the 8-device emulated CPU mesh, measures
-(a) per-mode step time, (b) communication-free compute time via the 'dear'
-schedule's ``exclude_parts`` ablation, and (c) a live α-β interconnect fit
+(a) per-mode step time, (b) communication-free compute time as the same
+step on one device with one device's share of the batch, and (c) a live α-β
+interconnect fit
 (`overlap.fit_interconnect`), then prints the per-mode overlap-efficiency
 report — ideal vs measured step time, exposed vs hidden communication per
 bucket — and optionally writes the same content as JSON.
@@ -258,22 +259,24 @@ def main(argv=None) -> int:
     batch = (jnp.zeros((args.batch, args.width)),
              jnp.zeros((args.batch, args.width)))
 
-    def build(mode: str, **kw):
+    def build(mode: str, mesh=mesh):
         return build_train_step(
             loss, params, mesh=mesh, mode=mode, nearby_layers=1,
-            optimizer=fused_sgd(lr=0.01, momentum=0.9), donate=False, **kw,
+            optimizer=fused_sgd(lr=0.01, momentum=0.9), donate=False,
         )
 
     print(f"fitting interconnect alpha-beta on {mesh} ...", flush=True)
     alpha, beta = OV.fit_interconnect(mesh)
 
-    # communication-free compute time: the 'dear' schedule's ablation
-    # switches (reference exclude_parts) — a measured number, not a model
-    ts_compute = build("dear",
-                      exclude_parts=("reducescatter", "allgather"))
+    # communication-free compute time: the same builder on a one-device
+    # mesh with one device's share of the batch (world 1 has no collectives
+    # and its numerics are real) — a measured number, not a model
+    ts_compute = build("dear", jax.sharding.Mesh(
+        mesh.devices.reshape(-1)[:1], mesh.axis_names))
+    share = jax.tree.map(lambda x: x[:max(1, x.shape[0] // world)], batch)
     compute_s, _ = OV.measure_step_time(
-        ts_compute, ts_compute.init(params), batch, steps=args.steps)
-    print(f"compute-only step (exclude_parts ablation): "
+        ts_compute, ts_compute.init(params), share, steps=args.steps)
+    print(f"compute-only step (one device, 1/{world} of the batch): "
           f"{compute_s * _MS:.3f} ms", flush=True)
 
     reports: dict[str, OverlapReport] = {}

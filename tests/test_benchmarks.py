@@ -66,19 +66,18 @@ def test_imagenet_scanned_protocol(mesh, capsys):
 
 
 def test_imagenet_modes_and_ablations(mesh):
-    # baseline schedule + exclude-parts ablation parse & run
+    # baseline schedules parse & run; the reference's exclude-parts
+    # ablation is gone with its flag (the device trace gives the breakdown)
     imagenet_bench.main(
         ["--model", "mnistnet", "--batch-size", "4", "--mode", "allreduce"]
         + TINY
     )
     imagenet_bench.main(
-        ["--model", "mnistnet", "--batch-size", "4",
-         "--exclude-parts", "allgather"] + TINY
+        ["--model", "mnistnet", "--batch-size", "4", "--mode", "rb"] + TINY
     )
-    with pytest.raises(SystemExit):
-        imagenet_bench.main(
-            ["--model", "mnistnet", "--exclude-parts", "bogus"] + TINY
-        )
+    for bad in (["--exclude-parts", "allgather"], ["--mode", "bogus"]):
+        with pytest.raises(SystemExit):
+            imagenet_bench.main(["--model", "mnistnet"] + bad + TINY)
 
 
 def test_bert_cli_output_contract(mesh, capsys):

@@ -214,10 +214,6 @@ def test_compression_mode_guards(mesh):
     with pytest.raises(ValueError, match="top-k"):
         build_train_step(loss_fn, params, mesh=mesh, mode="allreduce",
                          compressor="signum", gtopk=True)
-    with pytest.raises(ValueError, match="exclude_parts"):
-        build_train_step(loss_fn, params, mesh=mesh, mode="dear",
-                         compressor="eftopk", density=0.1,
-                         exclude_parts=("allgather",))
 
 
 def test_qint8_roundtrip_and_error_feedback():

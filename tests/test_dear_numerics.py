@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dear_pytorch_tpu.ops import fusion as F
 from dear_pytorch_tpu.ops.fused_sgd import fused_sgd, from_optax
 from dear_pytorch_tpu.parallel import build_train_step
 
@@ -159,28 +160,87 @@ def test_no_fusion_mode(mesh, world, problem):
     )
 
 
-def test_exclude_parts_runs(mesh, world, problem):
-    # ablation instruments must execute (numerics intentionally garbage)
+#: What each schedule's legs issue PER BUCKET in the lowered step, as
+#: (reduce_scatter, all_gather, all_reduce) — `parallel/schedules.py`'s
+#: table read as collectives. 'rb' is a reduce and a broadcast, each one
+#: all-reduce (`comm.collectives`); 'bytescheduler' is one RS+AG pair a
+#: PARTITION, counted from `chunk_bounds` below (None here); 'fsdp' gathers in the forward and again in the backward for
+#: every bucket the backward still needs (None: 1 to 2 a bucket), its
+#: reduce-scatter being the gather's transpose; 'dear-fused' issues no XLA
+#: reduction at all (its rings are Pallas kernels; off the TPU their
+#: interpreter stands in with all-gathers, so that column is not pinned).
+_LEGS = {
+    "dear": (1, 1, 0),
+    "dear-fused": (0, None, 0),
+    "allreduce": (0, 0, 1),
+    "rsag": (1, 1, 0),
+    "rb": (0, 0, 2),
+    "bytescheduler": (None, None, 0),
+    "fsdp": (1, None, 0),
+}
+
+
+@pytest.mark.parametrize("nworld", [1, 4])
+@pytest.mark.parametrize("mode", sorted(_LEGS))
+def test_schedule_issues_its_declared_collectives(problem, mode, nworld):
+    """Each schedule's lowered step holds exactly the collectives its legs
+    declare per bucket, plus the one all-reduce of the scalar loss. At
+    world 1 the same legs are traced over groups of one device: nothing
+    crosses a device boundary."""
+    import re
+
+    from dear_pytorch_tpu.parallel.dear import MODES
+
+    assert sorted(_LEGS) == sorted(MODES)
     params, batches, _, _ = problem
-    for excl in (("reducescatter",), ("allgather",)):
-        ts = build_train_step(
-            _loss_fn,
-            params,
-            mesh=mesh,
-            mode="dear",
-            threshold_mb=None,
-            exclude_parts=excl,
-            donate=False,
-        )
-        state = ts.init(params)
-        state, metrics = ts.step(state, batches[0])
-        assert np.isfinite(float(metrics["loss"]))
-    with pytest.raises(ValueError):
-        build_train_step(
-            _loss_fn, params, mesh=mesh, mode="allreduce",
-            exclude_parts=("allgather",),
-        )
-    with pytest.raises(ValueError):
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:nworld]), ("dp",))
+    # bucket 1 holds 528 padded floats: two partitions of 0.0012 MB
+    ts = build_train_step(
+        _loss_fn, params, mesh=mesh, mode=mode, threshold_mb=0.0008,
+        partition_mb=0.0012, donate=False)
+    nb = ts.plan.num_buckets
+    assert nb == 3
+    txt = ts.lower(ts.init(params), batches[0]).as_text()
+    ops = re.findall(
+        r'"stablehlo\.(reduce_scatter|all_gather|all_reduce|all_to_all|'
+        r'collective_permute|collective_broadcast)"\(.*?replica_groups = '
+        r'dense<[^>]*> : tensor<(\d+)x(\d+)xi64>', txt)
+    count = {k: sum(1 for o in ops if o[0] == k) for k in
+             ("reduce_scatter", "all_gather", "all_reduce")}
+    assert len(ops) == sum(count.values()), ops  # no other collective
+    assert all((g, n) == ("1", str(nworld)) for _, g, n in ops), ops
+    rs, ag, ar = _LEGS[mode]
+    if mode == "bytescheduler":
+        # one RS+AG pair a partition, not a bucket
+        parts = sum(len(F.chunk_bounds(b.padded_size, 4, 0.0012))
+                    for b in ts.plan.buckets)
+        assert parts > nb
+        assert (count["reduce_scatter"], count["all_gather"]) == (parts,
+                                                                  parts)
+    else:
+        assert count["reduce_scatter"] == rs * nb
+        if mode == "fsdp":
+            assert nb <= count["all_gather"] <= 2 * nb
+        elif ag is not None:
+            assert count["all_gather"] == ag * nb
+    assert count["all_reduce"] == ar * nb + 1  # + the loss's pmean
+
+
+def test_removed_options_are_type_errors(mesh, problem):
+    """`exclude_parts` (the time-breakdown ablation; the device trace's
+    exposed_reduce_ms / exposed_gather_ms give that breakdown) and
+    `opt_spec_fn` (no caller) are gone from the builder's signature."""
+    import inspect
+
+    params, _, _, _ = problem
+    with pytest.raises(TypeError, match="exclude_parts"):
+        build_train_step(_loss_fn, params, mesh=mesh, exclude_parts=())
+    with pytest.raises(TypeError, match="opt_spec_fn"):
+        build_train_step(_loss_fn, params, mesh=mesh, opt_spec_fn=None)
+    kwonly = [p for p in inspect.signature(build_train_step)
+              .parameters.values() if p.kind is p.KEYWORD_ONLY]
+    assert len(kwonly) == 26
+    with pytest.raises(ValueError, match="mode must be one of"):
         build_train_step(_loss_fn, params, mesh=mesh, mode="bogus")
 
 

@@ -154,5 +154,3 @@ def test_fsdp_option_validation(mesh, problem):
         _build(params, mesh, "fsdp", comm_dtype=jnp.bfloat16)
     with pytest.raises(ValueError, match="gather_dtype"):
         _build(params, mesh, "allreduce", gather_dtype=jnp.bfloat16)
-    with pytest.raises(ValueError, match="dear"):
-        _build(params, mesh, "fsdp", exclude_parts=("allgather",))

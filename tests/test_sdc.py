@@ -222,7 +222,21 @@ def test_fingerprint_catches_what_loss_bits_miss(mesh, monkeypatch):
     ts = build_train_step(
         _loss_fn, params, mesh=mesh, threshold_mb=0.0008, donate=False,
         optimizer=fused_sgd(lr=0.05, momentum=0.9))
-    clean = dirty = ts.init(params)
+    clean = ts.init(params)
+    # `flip_state_bucket` SETS the low mantissa bit (idempotent `|=`), so
+    # on an element whose bit is already 1 it corrupts nothing — and for
+    # PRNGKey(0) under this JAX the target is such an element
+    # (0x3d96fb4d): the step-0 fingerprints then agree, which is what
+    # this test failed on, alone or under six workers, on every tree.
+    # Clear the bit in the shared starting state so that the injected
+    # corruption is a real one by construction.
+    b0 = ts.plan.buckets[0]
+    words = np.array(jax.device_get(clean.buffers[0])).view(np.uint32)
+    words[b0.size - 1] &= ~np.uint32(1)
+    clean = clean._replace(buffers=(jax.device_put(
+        words.view(np.float32), clean.buffers[0].sharding),)
+        + clean.buffers[1:])
+    dirty = clean
     batches = [_data(jax.random.PRNGKey(100 + i)) for i in range(4)]
     loss_blind_steps = 0
     flipped_bucket = None
