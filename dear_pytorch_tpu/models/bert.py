@@ -24,6 +24,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from dear_pytorch_tpu.models.losses import token_cross_entropy
+
 
 @dataclasses.dataclass(frozen=True)
 class BertConfig:
@@ -400,14 +402,10 @@ def bert_pretraining_loss(logits, nsp_logits, masked_lm_labels,
     CrossEntropyLoss(ignore_index=-1) on flattened logits, summed).
     """
     with jax.named_scope("loss"):
-        V = logits.shape[-1]
-        flat_logits = logits.reshape(-1, V)
-        flat_labels = masked_lm_labels.reshape(-1)
-        valid = flat_labels != ignore_index
-        safe = jnp.where(valid, flat_labels, 0)
-        logp = jax.nn.log_softmax(flat_logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, safe[:, None], axis=-1)[:, 0]
-        mlm_loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
+        valid = masked_lm_labels != ignore_index
+        nll, count = token_cross_entropy(
+            logits, jnp.where(valid, masked_lm_labels, 0), valid)
+        mlm_loss = nll / jnp.maximum(count, 1)
         nsp_logp = jax.nn.log_softmax(nsp_logits, axis=-1)
         nsp_loss = -jnp.mean(
             jnp.take_along_axis(nsp_logp,

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from dear_pytorch_tpu.models.gpt import causal_attention
+from dear_pytorch_tpu.models.losses import next_token_cross_entropy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -242,28 +243,18 @@ class GlmMoeLmHeadModel(nn.Module):
             return logits, head(h, "ln_mtp_f")
 
 
-def _next_token_loss(logits, input_ids, ahead: int):
-    """Mean cross-entropy of ``logits[:, i]`` against token ``i + ahead``,
-    streamed as `models.gpt.gpt_lm_loss` is (logsumexp less the target's
-    logit; the log-probabilities are never materialised)."""
-    logits = logits[:, :-ahead]
-    targets = input_ids[:, ahead:]
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - tgt)
-
-
 def glm_moe_lm_loss(outputs, input_ids, *, mtp_loss_weight: float = 0.3):
     """``L_main + mtp_loss_weight * L_mtp`` of `GlmMoeLmHeadModel`'s
     ``(logits, mtp_logits)``: next-token cross-entropy, and the prediction
-    module's against the token two ahead."""
+    module's against the token two ahead (the targets shifted, the logits
+    never sliced: `models.losses.next_token_cross_entropy`)."""
     logits, mtp_logits = outputs
     with jax.named_scope("loss"):
-        loss = _next_token_loss(logits, input_ids, 1)
+        loss = next_token_cross_entropy(logits, input_ids)
     if mtp_logits is not None:
         with jax.named_scope("mtp"), jax.named_scope("loss"):
-            loss = loss + mtp_loss_weight * _next_token_loss(
-                mtp_logits, input_ids, 2)
+            loss = loss + mtp_loss_weight * next_token_cross_entropy(
+                mtp_logits, input_ids, ahead=2)
     return loss
 
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dear_pytorch_tpu.models.bert import dot_product_attention
+from dear_pytorch_tpu.models.losses import next_token_cross_entropy
 
 # by module name: `dear_pytorch_tpu.ops` re-exports a `flash_attention`
 # FUNCTION that shadows the module attribute
@@ -584,19 +585,14 @@ def gpt_lm_loss(logits, input_ids, *, vocab_size: Optional[int] = None):
 
     Streamed formulation: ``nll = logsumexp(valid logits) - logit[target]``
     — the identical function to masking + log_softmax + gather (log_softmax
-    IS x - logsumexp(x)), but it never materializes the [B, S, V] log-prob
-    tensor and excludes the padded tail by reduction *slicing* rather than
-    a full-tensor where-mask. At GPT-2 scale ([8, 1024, 50264] f32) the
-    naive form costs ~3 GB of extra HBM round-trips per step; this form
-    reads the logits once. Same-value + same-gradient property is pinned
-    by tests/test_gpt.py::test_gpt_lm_loss_streamed_equivalence."""
+    IS x - logsumexp(x)). Nothing of the logits' size is sliced: the
+    *targets* are shifted, the last position takes weight 0 (and so an
+    exactly zero gradient), and the padded tail is masked by column index
+    (`models.losses` says why: slicing ``[:, :-1]`` and
+    ``[..., :vocab_size]`` cost the v5e three logits-sized buffers and
+    23 ms a step at GPT-2 scale, PERF.md PR 34). Same-value +
+    same-gradient property is pinned by
+    tests/test_gpt.py::test_gpt_lm_loss_streamed_equivalence."""
     with jax.named_scope("loss"):
-        logits = logits[:, :-1]
-        targets = input_ids[:, 1:]
-        V = logits.shape[-1]
-        valid = logits[..., :vocab_size] if (vocab_size is not None
-                                             and vocab_size < V) else logits
-        lse = jax.scipy.special.logsumexp(valid, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None],
-                                  axis=-1)[..., 0]
-        return jnp.mean(lse - tgt)
+        return next_token_cross_entropy(logits, input_ids,
+                                        valid_vocab=vocab_size)

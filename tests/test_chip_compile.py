@@ -213,6 +213,34 @@ def test_default_gpt2_step_selects_the_kernel(compiled_kernels, one_chip,
                for line in calls)
 
 
+def test_gpt2_head_and_loss_keep_one_bf16_logits_buffer(one_chip):
+    """GPT-2 124M's tied head and `gpt_lm_loss` at the benchmark cell's
+    shapes, value and both gradients. XLA:TPU fuses the row maximum into
+    the head matmul's epilogue and the softmax gradient into the two
+    backward matmuls' operands, so the one buffer of the logits' extent is
+    the matmul's own bf16 output. With the logits sliced
+    (`[:, :-1]`, `[..., :vocab_size]`; until PR 34) the entry computation
+    held two f32 buffers of that extent and a bf16 one, 6.59e9 bytes of
+    temporaries. A JAX or libtpu that undoes the fusion fails here, not in
+    a cell."""
+    B, S, H, V, vocab = 16, 1024, 768, 50264, 50257
+
+    def loss(x, table, ids):
+        logits = jnp.einsum("bsh,vh->bsv", x, table).astype(jnp.float32)
+        return chip_smoke.models.gpt_lm_loss(logits, ids, vocab_size=vocab)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        jax.ShapeDtypeStruct((B, S, H), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((V, H), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    logits_sized = re.findall(
+        rf"(\w+)\[{B},(?:{S}|{S - 1}),(?:{V}|{vocab})\]", entry)
+    assert logits_sized and set(logits_sized) == {"bf16"}, set(logits_sized)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
 def test_dear_step_compiles_for_four_v5e_chips(compiled_kernels, mesh4):
     """A 2-layer full-width GPT-2 `dear` step, lowered from shapes alone on
     the described mesh: the program as written asks for reduce-scatter and
