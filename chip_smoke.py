@@ -242,6 +242,11 @@ def phase_reference(mesh, cfg, batch_size: int, seq_len: int, steps: int,
     return {"dear": dear, "plain": plain, "max_diff": diff}
 
 
+#: LFM2-8B-A1B's attention layer in the benchmark cell: 32 Q heads over 8
+#: K/V heads of 64 at S=8192, the grouped kernels
+GROUPED_SHAPE = ((1, 8192, 32, 64), 8)
+
+
 def _with_grads(attend, q, k, v, do):
     """(out, dq, dk, dv) of ``attend(q, k, v)`` under the cotangent ``do``."""
     out, vjp = jax.vjp(attend, q, k, v)
@@ -260,13 +265,21 @@ def _max_abs_errors(got, want, tol: float, what: str) -> dict:
     return errs
 
 
-def check_flash_kernel(shape, seed: int, tol: float = FLASH_TOL):
+def check_flash_kernel(shape, seed: int, tol: float = FLASH_TOL,
+                       kv_heads=None):
     """Causal flash forward + q/k/v gradients at ``(B, S, H, D)`` in bf16
     against dense attention computed in f32 (highest matmul precision) on
-    the same bf16-rounded inputs. Returns the max abs error per tensor and
-    whether the compiled program carries a Mosaic kernel."""
+    the same bf16-rounded inputs. ``kv_heads`` fewer than ``H``: the grouped
+    kernels, k and v ``[B, S, kv_heads, D]``; the dense program (which
+    repeats them) then runs one K/V head and its Q heads at a time (the f32
+    scores of 32 heads at S=8192 are 8.6 GB). Returns the max abs error per
+    tensor and whether the compiled program carries a Mosaic kernel."""
+    b, s, h, d = shape
+    groups = kv_heads or 1
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
-    q, k, v, do = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in keys)
+    q, do = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in keys[::3])
+    k, v = (jax.random.normal(kk, (b, s, kv_heads or h, d), jnp.bfloat16)
+            for kk in keys[1:3])
 
     flash = jax.jit(functools.partial(
         _with_grads, functools.partial(flash_attention, causal=True)))
@@ -275,11 +288,16 @@ def check_flash_kernel(shape, seed: int, tol: float = FLASH_TOL):
             q, k, v, None, dtype=jnp.float32)))
     t0 = time.perf_counter()
     compiled = flash.lower(q, k, v, do).compile()
-    log(f"[flash] kernel fwd+bwd compile at {shape}: "
-        f"{time.perf_counter() - t0:.1f} s")
+    log(f"[flash] kernel fwd+bwd compile at {shape}"
+        + (f" over {kv_heads} K/V heads" if kv_heads else "")
+        + f": {time.perf_counter() - t0:.1f} s")
     got = compiled(q, k, v, do)
+    splits = [np.array_split(x.astype(jnp.float32), groups, axis=2)
+              for x in (q, k, v, do)]
     with jax.default_matmul_precision("highest"):
-        want = dense(*(x.astype(jnp.float32) for x in (q, k, v, do)))
+        parts = [dense(*(split[j] for split in splits))
+                 for j in range(groups)]
+    want = [np.concatenate(t, axis=2) for t in zip(*parts)]
     errs = _max_abs_errors(got, want, tol, "flash vs dense:")
     log(f"[flash] max abs error vs dense f32 at {shape} (bf16, tolerance "
         f"{tol}): " + ", ".join(f"{n} {e:.2e}" for n, e in errs.items()))
@@ -287,19 +305,25 @@ def check_flash_kernel(shape, seed: int, tol: float = FLASH_TOL):
 
 
 def phase_flash(mesh, cfg, batch_size: int, seq_len: int, steps: int,
-                seed: int, dense: dict):
+                seed: int, dense: dict, grouped=GROUPED_SHAPE):
     """The flash path (what ``--flash-attention`` selects): the kernel
-    against dense attention at the model's attention shape, then the same
-    train step as `phase_train` with `flash_causal_attention_impl`.
+    against dense attention at the model's attention shape and, with fewer
+    K/V heads, at ``grouped`` (``((B, S, H, D), kv_heads)``: the grouped
+    kernels, values of the forward pass and of all three gradients), then
+    the same train step as `phase_train` with `flash_causal_attention_impl`.
     ``dense`` is `phase_train`'s result on the same config, batch and seed:
     the two must start at the same loss."""
     heads = cfg.num_attention_heads
     shape = (batch_size, seq_len, heads, cfg.hidden_size // heads)
     errs, kernel_alone = check_flash_kernel(shape, seed)
+    grouped_errs, grouped_kernel = check_flash_kernel(
+        grouped[0], seed, kv_heads=grouped[1])
+    kernel_alone = kernel_alone and grouped_kernel
     res = phase_train(mesh, cfg, batch_size, seq_len, steps, seed,
                       attention_impl=flash_causal_attention_impl(),
                       label="flash")
     res["kernel_errors"] = errs
+    res["grouped_kernel_errors"] = grouped_errs
     res["kernel_in_program"] = (kernel_alone
                                 and "tpu_custom_call" in res["text"])
     log(f"[flash] tpu_custom_call in the compiled train step: "

@@ -1,16 +1,22 @@
-"""Synthetic causal-LM pre-training benchmark for the sparse latent-attention
-decoder (`models/glm_moe.py`: GLM-4.x / DeepSeek-V3 family), measured with
-the same harness and output format as `benchmarks/gpt.py`.
+"""Synthetic causal-LM pre-training benchmark for the sparse decoders: the
+latent-attention one (`models/glm_moe.py`: GLM-4.x / DeepSeek-V3 family) and
+the short-convolution hybrid (`models/lfm2_moe.py`: LFM2-MoE family), the
+family picked by ``--model``; measured with the same harness and output
+format as `benchmarks/gpt.py`.
 
 One chip runs its share of an expert-parallel deployment: ``--num-layers``
-of the published depth, ``--experts-held`` of the routed experts from
-``--expert-offset`` on (the router keeps its width), ``--vocab-size`` ids of
-the vocabulary. Example, the benchmark cell's share (BENCHMARK.json,
-``glm-4.7-flash-ep8.s4096``):
+of the published depth (LFM2: from ``--first-layer`` on), ``--experts-held``
+of the routed experts from ``--expert-offset`` on (the router keeps its
+width), ``--vocab-size`` ids of the vocabulary. Examples, the benchmark
+cells' shares (BENCHMARK.json, ``glm-4.7-flash-ep8.s4096`` and
+``lfm2-8b-a1b-ep4.s8192``):
 
   python -m dear_pytorch_tpu.benchmarks.glm --model glm47_flash \\
       --num-layers 5 --experts-held 8 --vocab-size 19360 \\
       --sequence-len 4096 --batch-size 2 --fp16 --momentum 0.9
+  python -m dear_pytorch_tpu.benchmarks.glm --model lfm2_8b_a1b \\
+      --first-layer 1 --num-layers 5 --experts-held 8 --vocab-size 16384 \\
+      --sequence-len 8192 --batch-size 1 --fp16 --momentum 0.9
 """
 
 from __future__ import annotations
@@ -31,14 +37,18 @@ from dear_pytorch_tpu.observability import tracer as T
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
-        description="TPU Synthetic sparse-decoder (GLM-MoE) Benchmark",
+        description="TPU Synthetic sparse-decoder (GLM-MoE, LFM2-MoE) "
+                    "Benchmark",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter,
     )
     p.add_argument("--model", type=str, default="glm47_flash",
-                   help=f"one of {models.glm_names()}")
+                   help=f"one of {models.glm_names() + models.lfm2_names()}")
     p.add_argument("--sequence-len", type=int, default=4096)
     p.add_argument("--num-layers", type=int, default=None,
                    help="blocks run here (the leading dense layer first)")
+    p.add_argument("--first-layer", type=int, default=0,
+                   help="LFM2: the published layer the blocks start at (its "
+                        "layer_types and dense layers follow)")
     p.add_argument("--experts-held", type=int, default=None,
                    help="routed experts this chip holds (default: all); the "
                         "router scores all of the model's either way")
@@ -55,17 +65,34 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-def config_from_args(args, dtype) -> models.GlmMoeConfig:
+def config_from_args(args, dtype):
+    """The model's config (`GlmMoeConfig` or `Lfm2MoeConfig`) cut to this
+    chip's share."""
     cfg = models.get_model(args.model, dtype=dtype).config
-    given = {"num_layers": args.num_layers,
-             "experts_held": args.experts_held,
+    given = {"experts_held": args.experts_held,
              "vocab_size": args.vocab_size}
-    cfg = dataclasses.replace(
+    if isinstance(cfg, models.Lfm2MoeConfig):
+        first = args.first_layer
+        kinds = cfg.layer_types[first:][:args.num_layers]
+        given.update(layer_types=kinds, num_hidden_layers=len(kinds),
+                     num_dense_layers=max(cfg.num_dense_layers - first, 0))
+    elif args.first_layer:
+        raise ValueError("--first-layer is the LFM2 family's")
+    else:
+        given["num_layers"] = args.num_layers
+        if args.no_mtp:
+            given["num_nextn_predict_layers"] = 0
+    return dataclasses.replace(
         cfg, expert_offset=args.expert_offset, remat=args.remat,
         **{k: v for k, v in given.items() if v is not None})
-    if args.no_mtp:
-        cfg = dataclasses.replace(cfg, num_nextn_predict_layers=0)
-    return cfg
+
+
+def loss_of(cfg, outputs, input_ids):
+    """The family's training loss of its model's outputs."""
+    if isinstance(cfg, models.Lfm2MoeConfig):
+        return models.lfm2_moe_lm_loss(outputs, input_ids)
+    return models.glm_moe_lm_loss(outputs, input_ids,
+                                  mtp_loss_weight=cfg.mtp_loss_weight)
 
 
 def main(argv=None) -> runner.BenchResult:
@@ -76,7 +103,9 @@ def main(argv=None) -> runner.BenchResult:
     world = backend.dp_size(mesh)
 
     cfg = config_from_args(args, jnp.bfloat16 if args.fp16 else jnp.float32)
-    model = models.GlmMoeLmHeadModel(cfg)
+    lfm2 = isinstance(cfg, models.Lfm2MoeConfig)
+    model = (models.Lfm2MoeLmHeadModel if lfm2
+             else models.GlmMoeLmHeadModel)(cfg)
     global_bs = args.batch_size * world
     batch = data.synthetic_gpt_batch(
         jax.random.PRNGKey(0), global_bs, seq_len=args.sequence_len,
@@ -94,10 +123,8 @@ def main(argv=None) -> runner.BenchResult:
         del rng
         outputs, collections = model.apply(
             {"params": p}, b["input_ids"], mutable=["intermediates"])
-        loss = models.glm_moe_lm_loss(outputs, b["input_ids"],
-                                      mtp_loss_weight=cfg.mtp_loss_weight)
-        return loss, models.expert_assignments(
-            cfg, collections["intermediates"])
+        return (loss_of(cfg, outputs, b["input_ids"]),
+                models.expert_assignments(cfg, collections["intermediates"]))
 
     dear_cfg = runner.config_from_args(args, world=world)
     ts, stepper = runner.build_stepper(dear_cfg, loss_fn, params, mesh,
@@ -105,12 +132,15 @@ def main(argv=None) -> runner.BenchResult:
     state = ts.init(params)
     del params
 
-    held = cfg.experts_held or cfg.n_routed_experts
+    scored = cfg.num_experts if lfm2 else cfg.n_routed_experts
+    held = cfg.experts_held or scored
+    depth = (f"layers {', '.join(cfg.layer_types)}" if lfm2 else
+             f"{cfg.num_layers} layer(s), {cfg.num_nextn_predict_layers} "
+             "prediction module(s)")
     runner.log(f"{args.model} causal-LM pretraining, sequence len: "
-               f"{args.sequence_len}; {cfg.num_layers} layer(s), experts "
+               f"{args.sequence_len}; {depth}, experts "
                f"[{cfg.expert_offset}, {cfg.expert_offset + held}) of "
-               f"{cfg.n_routed_experts}, {cfg.vocab_size} ids, "
-               f"{cfg.num_nextn_predict_layers} prediction module(s)")
+               f"{scored}, {cfg.vocab_size} ids")
     runner.log(f"Batch size: {args.batch_size} (per dp rank), "
                f"{global_bs} global "
                f"({global_bs * args.sequence_len} tokens/step)")

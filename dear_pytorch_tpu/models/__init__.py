@@ -31,13 +31,21 @@ from dear_pytorch_tpu.models.gpt import (  # noqa: F401
     generate,
     gpt_lm_loss,
 )
+from dear_pytorch_tpu.models import glm_moe as _glm_moe
+from dear_pytorch_tpu.models import lfm2_moe as _lfm2_moe
 from dear_pytorch_tpu.models.glm_moe import (  # noqa: F401
     GLM47_FLASH,
     GLM_MOE_TINY,
     GlmMoeConfig,
     GlmMoeLmHeadModel,
-    expert_assignments,
     glm_moe_lm_loss,
+)
+from dear_pytorch_tpu.models.lfm2_moe import (  # noqa: F401
+    LFM2_8B_A1B,
+    LFM2_MOE_TINY,
+    Lfm2MoeConfig,
+    Lfm2MoeLmHeadModel,
+    lfm2_moe_lm_loss,
 )
 from dear_pytorch_tpu.models.densenet import (  # noqa: F401
     DenseNet121,
@@ -96,6 +104,14 @@ _GLM_REGISTRY: dict[str, Any] = {
 }
 
 
+# Hybrid sparse decoders: short convolutions among grouped-query attention
+# (models/lfm2_moe.py).
+_LFM2_REGISTRY: dict[str, Any] = {
+    "lfm2_8b_a1b": LFM2_8B_A1B,
+    "lfm2_moe_tiny": LFM2_MOE_TINY,   # CPU tests and smoke runs only
+}
+
+
 def cnn_names() -> list[str]:
     return sorted(_CNN_REGISTRY)
 
@@ -110,6 +126,18 @@ def gpt_names() -> list[str]:
 
 def glm_names() -> list[str]:
     return sorted(_GLM_REGISTRY)
+
+
+def lfm2_names() -> list[str]:
+    return sorted(_LFM2_REGISTRY)
+
+
+def expert_assignments(cfg, intermediates):
+    """``[expert layers, experts held]`` assignments made to each held
+    expert of either sparse decoder, from the ``intermediates`` collection
+    of one ``model.apply(..., mutable=["intermediates"])``."""
+    family = _lfm2_moe if isinstance(cfg, Lfm2MoeConfig) else _glm_moe
+    return family.expert_assignments(cfg, intermediates)
 
 
 def get_model(name: str, *, dtype=jnp.float32, **kwargs):
@@ -130,14 +158,15 @@ def get_model(name: str, *, dtype=jnp.float32, **kwargs):
             cfg = dataclasses.replace(cfg, dtype=dtype)
         cls = BertForPreTraining if key in _BERT_REGISTRY else GptLmHeadModel
         return cls(cfg, **kwargs)
-    if key in _GLM_REGISTRY:
+    if key in _GLM_REGISTRY or key in _LFM2_REGISTRY:
         import dataclasses
 
-        return GlmMoeLmHeadModel(
-            dataclasses.replace(_GLM_REGISTRY[key], dtype=dtype), **kwargs)
+        cfg = _GLM_REGISTRY.get(key) or _LFM2_REGISTRY[key]
+        cls = GlmMoeLmHeadModel if key in _GLM_REGISTRY else Lfm2MoeLmHeadModel
+        return cls(dataclasses.replace(cfg, dtype=dtype), **kwargs)
     raise KeyError(
         f"unknown model {name!r}; CNNs: {cnn_names()}, BERT: {bert_names()}, "
-        f"GPT: {gpt_names()}, GLM: {glm_names()}"
+        f"GPT: {gpt_names()}, GLM: {glm_names()}, LFM2: {lfm2_names()}"
     )
 
 

@@ -126,9 +126,15 @@ def causal_dot_product_attention(q, k, v, mask, *, dropout_rng=None,
                                  dropout_rate=0.0, dtype=jnp.float32):
     """Dense causal attention core (same calling convention as
     models.bert.dot_product_attention; ``mask`` is the additive key-padding
-    mask [B,1,1,S] or None — the causal triangle is applied here)."""
+    mask [B,1,1,S] or None — the causal triangle is applied here). ``k`` and
+    ``v`` may hold fewer heads than ``q`` (grouped-query attention: K/V head
+    ``j`` serves Q heads ``j * H/H_kv`` on); they are repeated here, which
+    the flash kernels never do."""
     depth = q.shape[-1]
     S = q.shape[1]
+    if k.shape[2] != q.shape[2]:
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     with jax.named_scope("attention/scores"):
         scores = (jnp.einsum("bqhd,bkhd->bhqk", q, k)
                   / jnp.sqrt(depth).astype(dtype))
@@ -161,10 +167,13 @@ def flash_core_applies(q, k, mask, dropout_rng, dropout_rate,
     (causal, dropout live) up, tiles sequences in multiples of 128, and off
     the TPU would run in Pallas' interpreter (`_interpret` is the one
     predicate: an ahead-of-time compile for a described TPU from a CPU
-    host patches that)."""
+    host patches that). ``k`` with fewer heads than ``q`` (grouped-query
+    attention) needs heads that tile the kernels' lane blocks
+    (`ops.flash_attention.grouped_heads_tile`)."""
     seq = q.shape[1]
     live = dropout_rng is not None and dropout_rate > 0.0
     return (not _flash._interpret()
+            and _flash.grouped_heads_tile(q.shape[2], k.shape[2], q.shape[3])
             and (mask is None or mask.shape == (q.shape[0], 1, 1, seq))
             and k.shape[1] == seq
             and seq >= FLASH_MIN_SEQ[causal, live] and seq % 128 == 0)

@@ -48,6 +48,16 @@ had anyway (depth is free up to 128); ``p_g v`` yields head g's output in
 head g's lanes (the others are dropped at the flush). ``D`` a multiple of
 128 is one head a block; a ``H·D`` that 128 does not divide is one block.
 
+Grouped-query attention (PR 35): k and v may hold fewer heads than q,
+``[B, S, H_kv·D]``. The grid gains a dimension, (B, K/V block, q sub-block,
+Sq/bq, Sk/bk): a step holds one 128-lane K/V block and one of the ``H/H_kv``
+128-lane blocks of q its heads serve, and the bodies are the equal-heads
+ones but for a lane rotation that brings a Q head to its K/V head's lanes
+(and back at a flush). dK/dV accumulate in VMEM over a K/V block's
+sub-blocks too and leave the kernel summed over each group; no repeat of K
+and V stands before the call. With equal heads none of it is traced: the
+Mosaic modules are the ones the kernels had before (docs/KERNELS.md).
+
 Operands go to the MXU in their input dtype with f32 accumulation (bf16
 in, bf16 probabilities for the second matmul, as the dense path does);
 f32 inputs stay f32 at full precision. The softmax is f32 throughout.
@@ -171,6 +181,40 @@ def _merge_heads(per_head, d):
     for g in range(1, len(per_head)):
         out = jnp.where(_head_lanes(g, d, out.shape[1]), per_head[g], out)
     return out
+
+
+# Grouped-query attention (``group`` = H / H_kv > 1; the module docstring has
+# the layout): a grid step holds one K/V block and ONE of the ``group`` blocks
+# of q its heads serve. With ``group`` 1 nothing below is traced.
+
+
+def _kv_head(sub, g, heads, group):
+    """The K/V head, within its block, of Q head ``g`` of q sub-block
+    ``sub`` (both may be traced)."""
+    return (sub * heads + g) // group
+
+
+def _q_rows(x, g, d, heads, group, sub, scale=None):
+    """`_head_rows` of Q head ``g``; with grouped heads the head sits in the
+    lanes of its K/V head."""
+    if group == 1 or heads == 1:
+        return _head_rows(x, g, d, heads, scale)
+    j = _kv_head(sub, g, heads, group)
+    y = x.astype(jnp.float32)
+    if scale is not None:
+        y = y * scale
+    y = pltpu.roll(y, (j - g + heads) % heads * d, 1)
+    return jnp.where(_head_lanes(j, d, x.shape[1]), y, 0.0).astype(x.dtype)
+
+
+def _back_to_own_lanes(per_head, d, group, sub):
+    """``per_head[g]`` (rows, W) f32, valid in the lanes of head g's K/V
+    head, with head g back in its own lanes (what `_merge_heads` takes)."""
+    heads = len(per_head)
+    if group == 1 or heads == 1:
+        return per_head
+    return [pltpu.roll(x, (g - _kv_head(sub, g, heads, group) + heads)
+                       % heads * d, 1) for g, x in enumerate(per_head)]
 
 
 def _precision(dtype):
@@ -342,7 +386,8 @@ def dropout_keep_mask(seed, batch, heads, sq, sk, rate):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk, rate=0.0):
+def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk, rate=0.0,
+                group=1):
     """``rate`` > 0: the first operand is the two dropout seed words (SMEM);
     the row sum and the saved lse come from the undropped ``p``, the context
     from ``p ⊙ keep`` (its ``1 / (1 - rate)`` is applied once, at the flush).
@@ -351,9 +396,13 @@ def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk, rate=0.0):
     q_ref, k_ref, v_ref = refs[:3]
     mask_ref = refs[3] if has_mask else None
     o_ref, lse_ref, m_s, l_s, acc_s = refs[3 + has_mask:]
-    qi, kj = pl.program_id(2), pl.program_id(3)
+    # grouped heads: grid (B, K/V block, q sub-block, Sq/bq, Sk/bk)
+    sub = pl.program_id(2) if group > 1 else 0
+    qi, kj = pl.program_id(2 + (group > 1)), pl.program_id(3 + (group > 1))
     if rate:    # the mask's batch row and head group (asked for out here:
         bi, gi = pl.program_id(0), pl.program_id(1)    # not inside a loop)
+        if group > 1:
+            gi = gi * group + sub
     bq, width = q_ref.shape[1], q_ref.shape[2]
     bk = k_ref.shape[1]
 
@@ -365,7 +414,7 @@ def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk, rate=0.0):
 
     def tile(diagonal):
         def head(g):
-            qg = _head_rows(q_ref[0], g, d, heads, scale)        # [bq, W]
+            qg = _q_rows(q_ref[0], g, d, heads, group, sub, scale)  # [bq, W]
             for strip in _strips(bq, bk, diagonal):
                 r0, r1, c0, c1 = strip
                 rows = slice(r0, r1)
@@ -401,7 +450,8 @@ def _fwd_kernel(*refs, scale, causal, has_mask, heads, d, nk, rate=0.0):
             outs.append(acc_s[g] / _lanes(kept, width))
             lse_ref[0, 0, g:g + 1, :] = jnp.transpose(
                 m_s[g] + jnp.log(l))[:1, :]
-        o_ref[0] = _merge_heads(outs, d).astype(o_ref.dtype)
+        o_ref[0] = _merge_heads(_back_to_own_lanes(outs, d, group, sub),
+                                d).astype(o_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -464,7 +514,7 @@ def _dot_tn(a, b):
 
 
 def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk,
-                rate=0.0):
+                rate=0.0, group=1):
     """dQ of a query block, accumulated over its key blocks; ``with_kv``
     (the fused backward): dK and dV too, from the same recomputation of each
     P-tile — 5 matmuls and one exp a tile where a dq + dkv pair makes 7 and
@@ -486,14 +536,21 @@ def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk,
         dk_ref, dv_ref, lse_s, delta_s, dq_s, dk_s, dv_s = refs[7 + has_mask:]
     else:
         lse_s, delta_s, dq_s = refs[7 + has_mask:]
-    qi, kj = pl.program_id(2), pl.program_id(3)
+    sub = pl.program_id(2) if group > 1 else 0
+    qi, kj = pl.program_id(2 + (group > 1)), pl.program_id(3 + (group > 1))
     if rate:
         bi, gi = pl.program_id(0), pl.program_id(1)
+        if group > 1:
+            gi = gi * group + sub
     bq, bk = q_ref.shape[1], k_ref.shape[1]
     key0 = 0 if nk == 1 else pl.multiple_of(kj * bk, bk)
 
     if with_kv:
-        @pl.when((qi == 0) & (kj == 0))
+        first = (qi == 0) & (kj == 0)
+        if group > 1:   # dK/dV accumulate over a K/V block's q sub-blocks too
+            first = first & (sub == 0)
+
+        @pl.when(first)
         def _init_kv():
             dk_s[...] = jnp.zeros_like(dk_s)
             dv_s[...] = jnp.zeros_like(dv_s)
@@ -508,8 +565,8 @@ def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk,
 
     def tile(diagonal):
         def head(g):
-            qg = _head_rows(q_ref[0], g, d, heads, scale)        # [bq, W]
-            dog = _head_rows(do_ref[0], g, d, heads)
+            qg = _q_rows(q_ref[0], g, d, heads, group, sub, scale)  # [bq, W]
+            dog = _q_rows(do_ref[0], g, d, heads, group, sub)
             for strip in _strips(bq, bk, diagonal):
                 r0, r1, c0, c1 = strip
                 rows = slice(r0, r1)
@@ -529,10 +586,14 @@ def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk,
                 dq_s[g, rows] = dq_s[g, rows] + _dot(ds, k2)     # [h, W]
                 if with_kv:
                     keys = pl.ds(key0 + c0, c1 - c0)
+                    # grouped heads: the row-side operands in their K/V
+                    # head's lanes (zero elsewhere; qg carries the scale)
+                    own = group == 1
                     dv_s[g, keys] = dv_s[g, keys] + _dot_tn(
-                        kept.astype(k2.dtype), do_ref[0, rows, :])  # [c, W]
+                        kept.astype(k2.dtype),
+                        do_ref[0, rows, :] if own else dog[rows])  # [c, W]
                     dk_s[g, keys] = dk_s[g, keys] + _dot_tn(
-                        ds, q_ref[0, rows, :])
+                        ds, q_ref[0, rows, :] if own else qg[rows])
 
         _for_each_head(heads, head)
 
@@ -541,15 +602,26 @@ def _bwd_kernel(*refs, scale, causal, has_mask, with_kv, heads, d, nq, nk,
 
     @pl.when(kj == nk - 1)
     def _flush():
-        dq = _merge_heads([dq_s[g] for g in range(heads)], d)
+        dq = _merge_heads(_back_to_own_lanes(
+            [dq_s[g] for g in range(heads)], d, group, sub), d)
         dq_ref[0] = (dq * (scale * undrop)).astype(dq_ref.dtype)
 
     if with_kv:
-        @pl.when((qi == nq - 1) & (kj == nk - 1))
+        last = (qi == nq - 1) & (kj == nk - 1)
+        if group > 1:
+            last = last & (sub == group - 1)
+
+        @pl.when(last)
         def _flush_kv():
-            dk = _merge_heads([dk_s[g] for g in range(heads)], d)
-            dv = _merge_heads([dv_s[g] for g in range(heads)], d)
-            dk_ref[0] = (dk * (scale * undrop)).astype(dk_ref.dtype)
+            if group == 1:
+                dk = _merge_heads([dk_s[g] for g in range(heads)], d)
+                dv = _merge_heads([dv_s[g] for g in range(heads)], d)
+                dk = dk * (scale * undrop)
+            else:   # each head's term is zero outside its K/V head's lanes,
+                dk = sum(dk_s[g] for g in range(heads))   # scaled already
+                dv = sum(dv_s[g] for g in range(heads))
+                dk = dk * undrop if rate else dk
+            dk_ref[0] = dk.astype(dk_ref.dtype)
             dv_ref[0] = (dv * undrop if rate else dv).astype(dv_ref.dtype)
 
 
@@ -678,29 +750,57 @@ def _inner_clamped(causal, outer_first: bool):
 
 def _query_major(q, k, v, kv_mask, heads, causal):
     """What the kernels whose grid is (B, H/G, Sq/bq, Sk/bk) share: the
-    geometry, and specs / shapes / operands of q, k, v and the key mask."""
+    geometry, and specs / shapes / operands of q, k, v and the key mask.
+    Grouped heads (k, v narrower than q by ``group``): the grid is (B, K/V
+    blocks, ``group`` q sub-blocks, Sq/bq, Sk/bk), the block of q, of the
+    output and of the row statistics follows (K/V block, sub-block), the
+    K/V block the K/V block alone."""
     b, sq, hd = q.shape
     sk, d = k.shape[1], hd // heads
-    width = _group_width(heads, d)
+    group = hd // k.shape[2]
+    width = _group_width(heads // group, d)
     bq, bk = _blocks(q, k, causal)
     kj = _inner_clamped(causal, outer_first=True)
-    q_spec = pl.BlockSpec((1, bq, width), lambda b, g, i, j: (b, i, g))
+    if group == 1:
+        def at(index):      # index(b, K/V block, q block, block i, block j)
+            return lambda b, g, i, j: index(b, g, g, i, j)
+    else:
+        def at(index):
+            return lambda b, g, c, i, j: index(b, g, g * group + c, i, j)
+    q_spec = pl.BlockSpec((1, bq, width), at(lambda b, g, c, i, j: (b, i, c)))
     k_spec = pl.BlockSpec((1, bk, width),
-                          lambda b, g, i, j: (b, kj(i, j), g))
+                          at(lambda b, g, c, i, j: (b, kj(i, j), g)))
     in_specs = [q_spec, k_spec, k_spec]
     arrays = [(q.shape, q.dtype), (k.shape, k.dtype), (v.shape, v.dtype)]
     operands = [q, k, v]
     if kv_mask is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 1, bk), lambda b, g, i, j: (b, 0, kj(i, j))))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bk), at(lambda b, g, c, i, j: (b, 0, kj(i, j)))))
         arrays.append(((b, 1, sk), jnp.int32))
         operands.append(kv_mask.astype(jnp.int32)[:, None, :])
+    blocks = (hd // width,) if group == 1 else (k.shape[2] // width, group)
     geometry = dict(
         d=d, width=width, hpb=width // d, groups=hd // width, bq=bq,
-        nq=sq // bq, nk=sk // bk, grid=(b, hd // width, sq // bq, sk // bk),
-        q_spec=q_spec,
-        rows=_rows_spec(width // d, bq, lambda b, g, i, j: (b, g, 0, i)))
+        nq=sq // bq, nk=sk // bk, grid=(b,) + blocks + (sq // bq, sk // bk),
+        q_spec=q_spec, group=group, at=at,
+        rows=_rows_spec(width // d, bq,
+                        at(lambda b, g, c, i, j: (b, c, 0, i))))
     return geometry, in_specs, arrays, operands
+
+
+def _compiler_params(geo, kv_too: bool):
+    """Batch, head-block (and q sub-block) and query-block grid dims are
+    parallel, the key-block reduction sequential; ``kv_too`` (the fused
+    backward): dK/dV accumulate across query blocks, and across a K/V
+    block's q sub-blocks, as well, so those run in order too."""
+    outer = len(geo["grid"]) - 2
+    if kv_too:
+        order = ("parallel",) * 2 + ("arbitrary",) * outer
+    else:
+        order = ("parallel",) * (outer + 1) + ("arbitrary",)
+    return pltpu.CompilerParams(
+        dimension_semantics=order,
+        vmem_limit_bytes=_COMPILER_PARAMS.vmem_limit_bytes)
 
 
 def _dropout_call(seed, rate, name):
@@ -739,7 +839,8 @@ def _fwd_call(q, k, v, kv_mask, seed=None, *, heads, scale, causal,
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           has_mask=kv_mask is not None, heads=hpb,
-                          d=geo["d"], nk=geo["nk"], **drop),
+                          d=geo["d"], nk=geo["nk"], group=geo["group"],
+                          **drop),
         grid=geo["grid"],
         in_specs=seed_spec + in_specs,
         out_specs=out_specs,
@@ -750,7 +851,7 @@ def _fwd_call(q, k, v, kv_mask, seed=None, *, heads, scale, causal,
             pltpu.VMEM((hpb, bq, width), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=_compiler_params(geo, kv_too=False),
         **named,
     )(*seed_operand, *operands)
 
@@ -779,7 +880,8 @@ def _bwd_call(q, k, v, kv_mask, do, lse, delta, seed=None, *, heads, scale,
     ]
     if with_kv:
         sk = k.shape[1]
-        kv_spec = pl.BlockSpec((1, sk, width), lambda b, g, i, j: (b, 0, g))
+        kv_spec = pl.BlockSpec((1, sk, width),
+                               geo["at"](lambda b, g, c, i, j: (b, 0, g)))
         out_specs += [kv_spec, kv_spec]
         out_shape += [jax.ShapeDtypeStruct(k.shape, out_dtype),
                       jax.ShapeDtypeStruct(v.shape, out_dtype)]
@@ -790,18 +892,14 @@ def _bwd_call(q, k, v, kv_mask, do, lse, delta, seed=None, *, heads, scale,
         functools.partial(_bwd_kernel, scale=scale, causal=causal,
                           has_mask=kv_mask is not None, with_kv=with_kv,
                           heads=hpb, d=geo["d"], nq=geo["nq"], nk=geo["nk"],
-                          **drop),
+                          group=geo["group"], **drop),
         grid=geo["grid"],
         in_specs=seed_spec + in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-        # dK/dV accumulate across query blocks too: both block dims in order
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary"),
-            vmem_limit_bytes=_COMPILER_PARAMS.vmem_limit_bytes),
+        compiler_params=_compiler_params(geo, kv_too=True),
         **named,
     )(*seed_operand, *operands, do, lse, delta)
     return out if with_kv else out[0]
@@ -886,6 +984,15 @@ def _flash_bwd(heads, scale, causal, rate, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _equal_heads(q, k):
+    """The ring's pair kernels take folded ``[BH, S, D]`` operands with as
+    many K/V heads as Q heads; grouped heads are `flash_attention`'s."""
+    if q.shape[0] != k.shape[0] or q.shape[2] != k.shape[2]:
+        raise ValueError(
+            f"the pair kernels need H_kv == H: q is folded to {q.shape}, k "
+            f"to {k.shape}; repeat K and V, or use `flash_attention`")
+
+
 def _folded_rows(x):
     """``[BH, S]`` statistic of the folded API -> ``[BH, 1, 1, S]``."""
     return x[:, None, None, :]
@@ -896,6 +1003,7 @@ def flash_pair_fwd(q, k, v, kv_mask, scale, causal, out_dtype=None):
     operands — ring attention's per-step forward building block.
     ``out_dtype`` (default: q's dtype) lets the ring keep the per-block
     contributions in fp32 for its cross-block accumulation."""
+    _equal_heads(q, k)
     o, lse = _fwd_call(q, k, v, kv_mask, heads=1, scale=scale, causal=causal,
                        out_dtype=jnp.dtype(out_dtype or q.dtype),
                        interpret=_interpret())
@@ -907,6 +1015,7 @@ def flash_pair_dq(q, k, v, kv_mask, do, lse, delta, scale, causal,
     """dQ for one (q-block, k-block) pair given GLOBAL ``lse``/``delta``
     (folded ``[BH, S, D]`` operands). This is the flash backward's dq leg;
     exposed separately so ring attention can run it per ring step."""
+    _equal_heads(q, k)
     return _bwd_call(q, k, v, kv_mask, do, _folded_rows(lse),
                      _folded_rows(delta), heads=1, scale=scale,
                      causal=causal, with_kv=False,
@@ -918,6 +1027,7 @@ def flash_pair_dkv(q, k, v, kv_mask, do, lse, delta, scale, causal,
                    out_dtype=None):
     """dK/dV for one (q-block, k-block) pair given GLOBAL ``lse``/``delta``
     (see `flash_pair_dq`)."""
+    _equal_heads(q, k)
     return _dkv_call(q, k, v, kv_mask, do, _folded_rows(lse),
                      _folded_rows(delta), heads=1, scale=scale,
                      causal=causal,
@@ -938,6 +1048,12 @@ def flash_attention(
 ) -> jax.Array:
     """Tiled exact attention over ``[B, S, H, D]`` inputs.
 
+    ``k`` and ``v`` may hold fewer heads than ``q``, ``[B, S, H_kv, D]``
+    with ``H_kv`` dividing ``H`` (grouped-query attention: K/V head ``j``
+    serves Q heads ``j * H/H_kv`` on): the kernels read K and V as they
+    are and return dK/dV summed over each group, nothing is repeated in
+    HBM; the heads must tile the 128 lanes (`grouped_heads_tile`).
+
     ``kv_mask``: optional key-validity mask ``[B, S_k]`` (True = attend).
     Differentiable (flash backward). ``causal`` needs ``S_q == S_k``.
     ``dropout_rng`` with a static ``dropout_rate`` > 0: dropout of the
@@ -953,16 +1069,32 @@ def flash_attention(
     TPU that block would mis-tile; pad the sequence to a multiple of 128.
     """
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Hkv = k.shape[1], k.shape[2]
     scale = float(D ** -0.5 if scale is None else scale)
     rate = float(dropout_rate) if dropout_rng is not None else 0.0
     if not 0.0 <= rate < 1.0:
         raise ValueError(f"dropout_rate must lie in [0, 1), got {rate}")
+    if not grouped_heads_tile(H, Hkv, D):
+        raise ValueError(
+            f"{H} query heads over {Hkv} K/V heads of {D} do not tile the "
+            "kernels' 128-lane blocks (`grouped_heads_tile`)")
     seed = dropout_seed_words(dropout_rng) if rate else None
     # [B,S,H,D] -> [B,S,H·D] is free: no transpose surrounds the kernels
-    o = _flash(q.reshape(B, Sq, H * D), k.reshape(B, Sk, H * D),
-               v.reshape(B, Sk, H * D), kv_mask, seed, H, scale, causal, rate)
+    o = _flash(q.reshape(B, Sq, H * D), k.reshape(B, Sk, Hkv * D),
+               v.reshape(B, Sk, Hkv * D), kv_mask, seed, H, scale, causal,
+               rate)
     return o.reshape(B, Sq, H, D)
+
+
+def grouped_heads_tile(heads: int, kv_heads: int, d: int) -> bool:
+    """Whether ``heads`` query heads over ``kv_heads`` K/V heads of width
+    ``d`` fit the kernels. Equal counts always do; fewer K/V heads need
+    whole groups and a K/V block of whole 128-lane rows (one head of a
+    multiple of 128 lanes, or ``128/d`` heads that fill 128), so that a Q
+    head reaches its K/V head's lanes by a rotation inside one lane row."""
+    return heads == kv_heads or (heads % kv_heads == 0 and (
+        d % _LANES == 0
+        or (_LANES % d == 0 and (kv_heads * d) % _LANES == 0)))
 
 
 def key_validity(mask):

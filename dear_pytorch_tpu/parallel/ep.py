@@ -168,7 +168,8 @@ class RoutedExperts(nn.Module):
     holds ``experts_held`` of them, from ``expert_offset`` on. Scores are
     ``sigmoid`` (DeepSeek-V3's ``noaux_tc``: the top-k is taken on
     ``score + router_bias``, the weights on the score alone) or ``softmax``;
-    ``norm_topk_prob`` divides the k weights by their sum, then
+    ``norm_topk_prob`` divides the k weights by their sum (plus
+    ``norm_topk_eps``, a constant of the source model's code), then
     ``routed_scaling_factor`` scales them. ``router_bias`` (the
     ``e_score_correction_bias``) is a parameter leaf behind `stop_gradient`:
     it moves the selection and receives no gradient.
@@ -194,6 +195,7 @@ class RoutedExperts(nn.Module):
     expert_offset: int = 0
     scoring: str = "sigmoid"
     norm_topk_prob: bool = True
+    norm_topk_eps: float = 1e-20
     routed_scaling_factor: float = 1.0
     dtype: Any = jnp.float32
     kernel_init: Callable = nn.initializers.lecun_normal()
@@ -212,7 +214,8 @@ class RoutedExperts(nn.Module):
         _, idx = lax.top_k(scores + lax.stop_gradient(router_bias), self.top_k)
         weights = jnp.take_along_axis(scores, idx, axis=-1)
         if self.norm_topk_prob:
-            weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+            weights = weights / (jnp.sum(weights, -1, keepdims=True)
+                                 + self.norm_topk_eps)
         return idx, weights * self.routed_scaling_factor
 
     @nn.compact

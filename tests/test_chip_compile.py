@@ -83,20 +83,30 @@ def test_perf_model_knows_the_described_device(topo):
 # GPT-2 124M attention at S=1024 (causal; the benchmark cell's batch),
 # BERT-Base at S=128, and GLM-4.7-Flash's latent attention at S=4096: 20
 # heads of 256, the one-head-a-block branch (the fused backward then holds
-# dK/dV of 4096 rows at 256 lanes in VMEM, 8.4 MB f32 of the 64 MB limit)
-@pytest.mark.parametrize("shape,causal", [((16, 1024, 12, 64), True),
-                                          ((32, 128, 12, 64), False),
-                                          ((1, 4096, 20, 256), True)])
+# dK/dV of 4096 rows at 256 lanes in VMEM, 8.4 MB f32 of the 64 MB limit);
+# and LFM2-8B-A1B's attention layer at S=8192, 32 Q heads over 8 K/V heads of
+# 64: the grouped form (a lane rotation by a traced amount brings a Q head
+# to its K/V head's lanes; dK/dV of 8192 rows accumulate over the q
+# sub-blocks too), its gradients back at K/V width
+@pytest.mark.parametrize("shape,causal,kv_heads", [
+    ((16, 1024, 12, 64), True, None), ((32, 128, 12, 64), False, None),
+    ((1, 4096, 20, 256), True, None), ((1, 8192, 32, 64), True, 8)])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_kernel_compiles_for_v5e(compiled_kernels, one_chip, shape,
-                                       causal, direction):
+                                       causal, kv_heads, direction):
     attend = functools.partial(flash_attention, causal=causal)
     fn = attend if direction == "fwd" else jax.grad(
         lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
         argnums=(0, 1, 2))
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
-    text = jax.jit(fn).lower(x, x, x).compile().as_text()
-    assert text.count(KERNEL) == (1 if direction == "fwd" else 2)
+    kv = jax.ShapeDtypeStruct(shape[:2] + (kv_heads or shape[2], shape[3]),
+                              jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(fn).lower(x, kv, kv).compile()
+    assert compiled.as_text().count(KERNEL) == (1 if direction == "fwd"
+                                                else 2)
+    if direction == "bwd":
+        assert [tuple(g.shape) for g in jax.tree.leaves(
+            compiled.out_info)] == [x.shape, kv.shape, kv.shape]
 
 
 #: a Pallas kernel in optimized HLO text

@@ -57,8 +57,13 @@ def test_reference_phase_dear_equals_plain_sgd():
 def test_flash_phase_agrees_with_dense_and_reports_the_kernel(dense):
     res = chip_smoke.phase_flash(_mesh(1), _tiny(jnp.bfloat16),
                                  batch_size=4, seq_len=64, steps=4, seed=0,
-                                 dense=dense)
+                                 dense=dense, grouped=((1, 256, 8, 64), 2))
     assert max(res["kernel_errors"].values()) < chip_smoke.FLASH_TOL
+    # the grouped kernels (8 Q heads over 2 K/V heads), against the dense
+    # program one K/V head at a time
+    assert set(res["grouped_kernel_errors"]) == {"out", "dq", "dk", "dv"}
+    assert max(res["grouped_kernel_errors"].values()) < chip_smoke.FLASH_TOL
+    assert chip_smoke.GROUPED_SHAPE == ((1, 8192, 32, 64), 8)
     assert abs(res["losses"][0] - dense["losses"][0]) < 1e-2
     # interpret mode here: the fact main() insists on is reported, as False
     assert res["kernel_in_program"] is False

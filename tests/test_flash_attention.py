@@ -256,15 +256,19 @@ def _dense(q, k, v, causal, kv_mask, keep=None, rate=0.0):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _check_against_dense(shape, mode, dtype, key=0, rate=0.0):
+def _check_against_dense(shape, mode, dtype, key=0, rate=0.0, kv_heads=None):
     """``rate`` > 0: the kernel drops probabilities with a key, the dense
-    program with `dropout_keep_mask`'s mask for that key."""
+    program with `dropout_keep_mask`'s mask for that key. ``kv_heads``
+    fewer than the shape's heads: k and v hold that many, and the dense
+    program sees them repeated for their groups."""
     b, s, h, d = shape
     ks = jax.random.split(jax.random.PRNGKey(key), 4)
     rng = jax.random.PRNGKey(key + 100) if rate else None
     keep = FA.dropout_keep_mask(rng, b, h, s, s, rate) if rate else None
-    q, k, v = (jax.random.normal(kk, shape, jnp.float32).astype(dtype)
-               for kk in ks[:3])
+    group = h // (kv_heads or h)
+    q, k, v = (jax.random.normal(kk, shp, jnp.float32).astype(dtype)
+               for kk, shp in zip(ks[:3], (shape, (b, s, h // group, d),
+                                           (b, s, h // group, d))))
     w = jax.random.normal(ks[3], shape, jnp.float32)   # a generic cotangent
     causal = "causal" in mode
     kv_mask = None
@@ -281,7 +285,8 @@ def _check_against_dense(shape, mode, dtype, key=0, rate=0.0):
         dropout_rate=rate)
     f32 = [x.astype(jnp.float32) for x in (q, k, v)]
     dense = lambda q, k, v: _dense(  # noqa: E731
-        q, k, v, causal, kv_mask, keep, rate)
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+        causal, kv_mask, keep, rate)
     got = flash(q, k, v)
     assert got.dtype == dtype
     tol = TOL[dtype]
@@ -290,7 +295,7 @@ def _check_against_dense(shape, mode, dtype, key=0, rate=0.0):
     got_g = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
     want_g = jax.grad(loss(dense), argnums=(0, 1, 2))(*f32)
     for g, wnt, name in zip(got_g, want_g, "qkv"):
-        assert g.dtype == dtype
+        assert g.dtype == dtype and g.shape == wnt.shape
         np.testing.assert_allclose(np.asarray(g, np.float32),
                                    np.asarray(wnt), err_msg=f"d{name}",
                                    **tol["grad"])
@@ -404,6 +409,128 @@ def test_pair_kernels_agree_with_the_fused_backward():
     for got, w, name in zip((dq, dk, dv), want, "qkv"):
         np.testing.assert_allclose(np.asarray(got), np.asarray(w),
                                    rtol=5e-4, atol=5e-5, err_msg=f"d{name}")
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention: K/V heads fewer than Q heads
+# ---------------------------------------------------------------------------
+
+# (H, H_kv, D): LFM2's ratio, two K/V heads a 128-lane block serving eight Q
+# heads (half of them reach their K/V head's lanes by a rotation); one K/V
+# head of 128 lanes a block serving four; equal heads (the old kernels)
+GROUPED = [(8, 2, 64), (4, 1, 128), (4, 4, 64)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("mode", ["causal", "full", "causal+kv_mask"])
+@pytest.mark.parametrize("heads,kv_heads,d", GROUPED)
+def test_grouped_heads_match_dense_with_repeated_kv(blocks_of_128, heads,
+                                                    kv_heads, d, mode, dtype):
+    """Forward and all three gradients over a 2 x 2 grid of blocks (dK/dV
+    accumulate over the query blocks and over a group's Q heads inside the
+    kernel) against the dense program with K and V repeated."""
+    _check_against_dense((2, 256, heads, d), mode, dtype, key=21,
+                         kv_heads=kv_heads)
+
+
+@pytest.mark.parametrize("heads,kv_heads,d", GROUPED[:2])
+def test_grouped_heads_drop_probabilities_by_the_q_heads_mask(
+        blocks_of_128, heads, kv_heads, d):
+    """Dropout with grouped heads: the mask of a score is its Q head's
+    (`dropout_keep_mask` over all H heads)."""
+    _check_against_dense((2, 256, heads, d), "causal+kv_mask", jnp.float32,
+                         key=23, rate=0.1, kv_heads=kv_heads)
+
+
+def test_grouped_heads_on_a_diagonal_tile_in_strips():
+    _check_against_dense((1, 512, 8, 64), "causal", jnp.float32, key=22,
+                         kv_heads=2)
+
+
+def test_grouped_kernels_take_kv_as_it_is_and_return_it_summed(monkeypatch):
+    """No repeat of K and V stands before the kernels: the K/V operands of
+    both `pallas_call`s are ``[B, S, H_kv * D]``, and dK/dV leave the
+    backward kernel at that width."""
+    seen = []
+    real = FA.pl.pallas_call
+
+    def recording(kernel, **kw):
+        call = real(kernel, **kw)
+
+        def run(*operands):
+            seen.append(([o.shape for o in operands],
+                         [o.shape for o in kw["out_shape"]]))
+            return call(*operands)
+        return run
+
+    monkeypatch.setattr(FA.pl, "pallas_call", recording)
+    jax.clear_caches()
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (1, 128, 8, 64))
+    k, v = (jax.random.normal(kk, (1, 128, 2, 64)) for kk in ks[1:])
+    grads = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True).sum(), argnums=(0, 1, 2))(q, k, v)
+    jax.clear_caches()
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+    (fwd_in, fwd_out), (bwd_in, bwd_out) = seen
+    assert fwd_in == [(1, 128, 512), (1, 128, 128), (1, 128, 128)]
+    # lse by Q head: [B, 128-lane blocks of q, Q heads a block, S]
+    assert fwd_out == [(1, 128, 512), (1, 4, 2, 128)]
+    assert bwd_in[:3] == fwd_in
+    assert bwd_out == [(1, 128, 512), (1, 128, 128), (1, 128, 128)]
+
+
+def test_what_grouped_heads_do_not_fit():
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (2, 128, 8, 64))
+    k, v = (jax.random.normal(kk, (2, 128, 2, 64)) for kk in ks[1:])
+    # 2 K/V heads of 16 are a quarter of a lane row: no block of whole rows
+    with pytest.raises(ValueError, match="do not tile"):
+        flash_attention(q[..., :16], k[..., :16], v[..., :16])
+    assert FA.grouped_heads_tile(32, 8, 64) and FA.grouped_heads_tile(4, 1, 128)
+    assert FA.grouped_heads_tile(16, 4, 32) and FA.grouped_heads_tile(8, 8, 256)
+    assert FA.grouped_heads_tile(3, 3, 20)           # equal heads always
+    assert not FA.grouped_heads_tile(8, 3, 64)       # no whole groups
+    assert not FA.grouped_heads_tile(6, 3, 64)       # 192 lanes of K/V
+    assert not FA.grouped_heads_tile(4, 2, 96)
+    # the ring's pair kernels (folded [BH, S, D]) need equal heads
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, 128, 64)  # noqa: E731
+    fq, fk, fv = fold(q), fold(k), fold(v)
+    lse = jnp.zeros(fq.shape[:2])
+    for call in (lambda: FA.flash_pair_fwd(fq, fk, fv, None, 0.125, True),
+                 lambda: FA.flash_pair_dq(fq, fk, fv, None, fq, lse, lse,
+                                          0.125, True),
+                 lambda: FA.flash_pair_dkv(fq, fk, fv, None, fq, lse, lse,
+                                           0.125, True)):
+        with pytest.raises(ValueError, match="H_kv == H"):
+            call()
+
+
+def test_the_default_core_takes_grouped_heads_where_the_kernels_do(
+        monkeypatch):
+    from dear_pytorch_tpu.models import gpt
+
+    monkeypatch.setattr(FA, "_interpret", lambda: False)   # "on a TPU"
+    q = jax.ShapeDtypeStruct((1, 1024, 8, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16)
+    assert gpt.flash_core_applies(q, k, None, None, 0.0)
+    assert gpt.flash_core_applies(q, q, None, None, 0.0)
+    mask = jnp.zeros((1, 1, 1, 1024))
+    assert gpt.flash_core_applies(q, k, mask, jax.random.PRNGKey(0), 0.1)
+    small = jax.ShapeDtypeStruct((1, 1024, 2, 16), jnp.bfloat16)
+    assert not gpt.flash_core_applies(
+        jax.ShapeDtypeStruct((1, 1024, 8, 16), jnp.bfloat16), small, None,
+        None, 0.0)
+    # off the TPU the dense program repeats K and V
+    monkeypatch.setattr(FA, "_interpret", lambda: True)
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    qa = jax.random.normal(ks[0], (1, 128, 8, 64))
+    ka, va = (jax.random.normal(kk, (1, 128, 2, 64)) for kk in ks[1:])
+    got = gpt.causal_attention(qa, ka, va, None)
+    want = flash_attention(qa, ka, va, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
 
 
 # ---------------------------------------------------------------------------
