@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dear_pytorch_tpu.ops import moe_rows
+
 EP_AXIS = "ep"
 
 #: partition rules for `tp.make_tp_train_step(rules=EP_RULES, tp_axis='ep')`
@@ -180,7 +182,13 @@ class RoutedExperts(nn.Module):
     over the held experts' rows, so the buffers are ``T*k`` rows whatever the
     routing: the worst case, every token choosing k held experts, fits.
     Rows in no group are masked out explicitly wherever they could reach a
-    result or a gradient (the TPU kernel leaves them unwritten).
+    result or a gradient (the TPU kernel leaves them unwritten). On a TPU,
+    with rows of whole 128-lane tiles, the rows are moved between token
+    order and sorted order by the kernels of `ops.moe_rows`, which read the
+    count of the held experts' rows on the device and neither read nor
+    write the others (same mathematics, still dropless: with every
+    assignment held they move all ``T*k`` rows); elsewhere by the gathers
+    `_spread` / `_unpermute`, the kernels' reference.
 
     Input ``[T, H]``; returns the routed part ``[T, H]`` (add the shared
     expert outside: every chip computes that alike). Sows the assignments per
@@ -248,13 +256,26 @@ class RoutedExperts(nn.Module):
             # the grouped matmul neither reads nor writes them (on the TPU
             # they hold whatever the buffer held), forward and backward
             valid = jnp.arange(T * k) < jnp.sum(sizes)
-            xs = _spread(x.astype(self.dtype), order, inverse, valid)
+            # on a TPU, rows of whole lane tiles: kernels that move the held
+            # experts' rows only (`ops.moe_rows`); else the gathers below,
+            # which stay as their reference (and serve `init`, whose program
+            # would pay the kernels' tracing for values it throws away)
+            kernels = (moe_rows.applies(T, k, H)
+                       and not self.is_initializing())
+            if kernels:
+                moved = moe_rows.dispatch(group.reshape(T, k), order, inverse,
+                                          sizes)
+                xs = moe_rows.spread(x.astype(self.dtype), moved)
+            else:
+                xs = _spread(x.astype(self.dtype), order, inverse, valid)
         with jax.named_scope("experts"):
             gate_up = lax.ragged_dot(xs, wi.astype(self.dtype), sizes)
             gate_up = jnp.where(valid[:, None], gate_up, 0)
             act = jax.nn.silu(gate_up[:, :F]) * gate_up[:, F:]
             ys = lax.ragged_dot(act, wo.astype(self.dtype), sizes)
         with jax.named_scope("combine"):
+            if kernels:
+                return moe_rows.combine(ys, weights, moved, x.dtype)
             back = _unpermute(ys, order, inverse).reshape(T, k, H)
             # a row outside every group is no expert's output: leave it out
             back = jnp.where(held[..., None], back.astype(jnp.float32), 0.0)

@@ -28,8 +28,10 @@ from dear_pytorch_tpu.ops.collective_matmul import (
     ring_all_gather,
 )
 from dear_pytorch_tpu.ops.flash_attention import flash_attention
+from dear_pytorch_tpu.ops import moe_rows
 from dear_pytorch_tpu.ops.fused_sgd import fused_sgd
 from dear_pytorch_tpu.parallel import DearState, build_train_step
+from dear_pytorch_tpu.parallel.ep import RoutedExperts
 
 P = jax.P
 #: a 25 MB f32 fusion bucket (THRESHOLD_MB of the smoke), in elements
@@ -63,7 +65,7 @@ def compiled_kernels(monkeypatch):
     is the CPU here; steer them to the Mosaic path for these compiles.
     (By module name through sys.modules: `dear_pytorch_tpu.ops` re-exports
     a `flash_attention` FUNCTION that shadows the module attribute.)"""
-    for name in ("flash_attention", "collective_matmul"):
+    for name in ("flash_attention", "collective_matmul", "moe_rows"):
         monkeypatch.setattr(sys.modules[f"dear_pytorch_tpu.ops.{name}"],
                             "_interpret", lambda: False)
 
@@ -221,6 +223,83 @@ def test_default_gpt2_step_selects_the_kernel(compiled_kernels, one_chip,
     assert sum("transpose(jvp(" in line for line in calls) == kernels // 2
     assert all(bool(DROPOUT_KERNEL.search(line)) == (attn_dropout > 0)
                for line in calls)
+
+
+# The routed experts' row kernels at the sparse cells' shape, T = 8192
+# tokens choosing 4 experts, H = 2048, bf16: a row DMA out of a tiled buffer
+# is what Mosaic refused in PR 24 (`Slice shape along dimension 0 must be
+# aligned to tiling`), so the spread reads rows as whole tiles behind a
+# leading index and the combine reads aligned 16-row chunks
+@pytest.mark.parametrize("op", ["spread", "combine"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_moe_row_kernels_compile_for_v5e(compiled_kernels, one_chip, op,
+                                         direction):
+    T, k, H, E = 8192, 4, 2048, 8
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    routing = (on((T, k), jnp.int32), on((T * k,), jnp.int32),
+               on((T * k,), jnp.int32), on((E,), jnp.int32))
+    x, ys = on((T, H), jnp.bfloat16), on((T * k, H), jnp.bfloat16)
+
+    def spread(x, *routing):
+        return moe_rows.spread(x, moe_rows.dispatch(*routing))
+
+    def combine(ys, w, *routing):
+        return moe_rows.combine(ys, w, moe_rows.dispatch(*routing),
+                                jnp.bfloat16)
+
+    fn, operands = {"spread": (spread, (x,)),
+                    "combine": (combine, (ys, on((T, k), jnp.float32)))}[op]
+    if direction == "bwd":
+        fn = jax.grad(lambda *a, f=fn: f(*a).astype(jnp.float32).sum(),
+                      argnums=tuple(range(len(operands))))
+    text = jax.jit(fn).lower(*operands, *routing).compile().as_text()
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    # each body is the other's gradient (a sum's gradient needs no forward)
+    body = op if direction == "fwd" else {"spread": "combine",
+                                          "combine": "spread"}[op]
+    assert len(calls) == 1 and f"moe_{body}_rows" in calls[0]
+
+
+@pytest.mark.parametrize("width,mlp_dim,hidden,kernels", [
+    (64, 1536, 2048, 4), (32, 1792, 2048, 4), (16, 48, 64, 0)],
+    ids=["glm-4.7-flash", "lfm2-8b-a1b", "tiny"])
+def test_routed_experts_layer_selects_the_row_kernels(
+        compiled_kernels, one_chip, width, mlp_dim, hidden, kernels):
+    """One `RoutedExperts` layer at the sparse cells' widths (8192 tokens,
+    H 2048, 8 of 64 and 8 of 32 experts held), value and gradient: the
+    spread and the combine and each one's backward are kernels, under the
+    ``dispatch`` / ``combine`` scopes `moe_dispatch_ms` and
+    `moe_row_kernel_calls_per_step` read; the tiny presets' 64 lanes keep
+    the gathers."""
+    T = 8192
+    layer = RoutedExperts(router_width=width, experts_held=8, top_k=4,
+                          mlp_dim=mlp_dim, dtype=jnp.bfloat16)
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    x = on((T, hidden), jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: on(a.shape, a.dtype),
+        jax.eval_shape(lambda key: layer.init(key, jnp.zeros(
+            (T, hidden), jnp.bfloat16))["params"], jax.random.PRNGKey(0)))
+
+    def loss(p, x):
+        with jax.named_scope("moe"):
+            y = layer.apply({"params": p}, x)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if KERNEL in line and "/moe_" in line]
+    assert len(calls) == kernels
+    scopes = sorted(re.search(r"(transpose\(jvp\(moe\)\)|jvp\(moe\))/"
+                              r"RoutedExperts/(\w+)/(?:jit\(\w+\)/)?"
+                              r"moe_(\w+)_rows", line).groups()
+                    for line in calls)
+    assert scopes == sorted([
+        ("jvp(moe)", "dispatch", "spread"),
+        ("jvp(moe)", "combine", "combine"),
+        ("transpose(jvp(moe))", "combine", "spread"),
+        ("transpose(jvp(moe))", "dispatch", "combine")][:kernels])
 
 
 def test_gpt2_head_and_loss_keep_one_bf16_logits_buffer(one_chip):

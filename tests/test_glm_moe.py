@@ -234,11 +234,16 @@ def test_the_layers_gradients_are_gathers_of_the_plain_ones():
     jax.tree.map(_close, got, want)
 
 
-def test_rows_of_no_group_reach_neither_result_nor_gradient(monkeypatch):
+@pytest.mark.parametrize("rows", ["gathers", "kernels"])
+def test_rows_of_no_group_reach_neither_result_nor_gradient(monkeypatch,
+                                                            rows):
     """On the TPU the grouped matmul leaves the rows past the held experts'
     groups unwritten, forward and backward (PR 31's first chip run: NaN
     from the second step on). A `ragged_dot` that poisons those rows the
-    same way must change nothing."""
+    same way must change nothing. Nor must the row kernels the TPU path
+    takes (`ops.moe_rows`, here in interpret mode at 128 lanes), whose own
+    outputs hold anything past the held experts' rows: ``xs``, the
+    cotangent of ``ys`` and the row-wise dots are poisoned there too."""
     from dear_pytorch_tpu.parallel import ep
 
     real = jax.lax.ragged_dot
@@ -263,12 +268,30 @@ def test_rows_of_no_group_reach_neither_result_nor_gradient(monkeypatch):
     dirty.defvjp(fwd, bwd)
     x, params = _whole()
     p = _share(params, 4, 4)
+    if rows == "kernels":   # rows of whole lane tiles: 128 lanes, not 32
+        n = 128 // H
+        x = jnp.tile(x, (1, n))
+        p = {**p, "router": jnp.tile(p["router"], (n, 1)) / n,
+             "wi": jnp.tile(p["wi"], (1, n, 1)),
+             "wo": jnp.tile(p["wo"], (1, 1, n))}
 
     def loss(p, x):
         return jnp.sum(jnp.sin(_layer(4, 4).apply({"params": p}, x)))
 
     want = jax.value_and_grad(loss, (0, 1))(p, x)
     monkeypatch.setattr(ep.lax, "ragged_dot", dirty)
+    if rows == "kernels":
+        spread_rows = ep.moe_rows.spread_rows
+
+        def dirty_rows(src, row, count, **kw):
+            live = jnp.arange(row.shape[0]) < count
+            out = spread_rows(src, row, count, **kw)
+            return jax.tree.map(
+                lambda o: jnp.where(live.reshape((-1,) + (1,) * (o.ndim - 1)),
+                                    o, jnp.nan), out)
+
+        monkeypatch.setattr(ep.moe_rows, "spread_rows", dirty_rows)
+        monkeypatch.setattr(ep.moe_rows, "applies", lambda *a: True)
     got = jax.value_and_grad(loss, (0, 1))(p, x)
     assert np.isfinite(float(got[0]))
     jax.tree.map(_close, got, want)
