@@ -1,15 +1,16 @@
-"""Synthetic causal-LM pre-training benchmark for the sparse decoders: the
-latent-attention one (`models/glm_moe.py`: GLM-4.x / DeepSeek-V3 family) and
-the short-convolution hybrid (`models/lfm2_moe.py`: LFM2-MoE family), the
-family picked by ``--model``; measured with the same harness and output
-format as `benchmarks/gpt.py`.
+"""Synthetic causal-LM pre-training benchmark for the decoders beyond GPT:
+the sparse latent-attention one (`models/glm_moe.py`: GLM-4.x / DeepSeek-V3
+family), the sparse short-convolution hybrid (`models/lfm2_moe.py`: LFM2-MoE
+family) and the dense Mamba-2 hybrid (`models/granite_hybrid.py`:
+Granite-4.0-H family), the family picked by ``--model``; measured with the
+same harness and output format as `benchmarks/gpt.py`.
 
-One chip runs its share of an expert-parallel deployment: ``--num-layers``
-of the published depth (LFM2: from ``--first-layer`` on), ``--experts-held``
-of the routed experts from ``--expert-offset`` on (the router keeps its
-width), ``--vocab-size`` ids of the vocabulary. Examples, the benchmark
-cells' shares (BENCHMARK.json, ``glm-4.7-flash-ep8.s4096`` and
-``lfm2-8b-a1b-ep4.s8192``):
+One chip runs its share of a deployment: ``--num-layers`` of the published
+depth (the hybrids: from ``--first-layer`` on), ``--experts-held`` of the
+routed experts from ``--expert-offset`` on (the router keeps its width),
+``--vocab-size`` ids of the vocabulary. Examples, the benchmark cells' shares
+(BENCHMARK.json, ``glm-4.7-flash-ep8.s4096``, ``lfm2-8b-a1b-ep4.s8192`` and
+``granite-4.0-h-micro-vp4.s4096x1``):
 
   python -m dear_pytorch_tpu.benchmarks.glm --model glm47_flash \\
       --num-layers 5 --experts-held 8 --vocab-size 19360 \\
@@ -17,6 +18,9 @@ cells' shares (BENCHMARK.json, ``glm-4.7-flash-ep8.s4096`` and
   python -m dear_pytorch_tpu.benchmarks.glm --model lfm2_8b_a1b \\
       --first-layer 1 --num-layers 5 --experts-held 8 --vocab-size 16384 \\
       --sequence-len 8192 --batch-size 1 --fp16 --momentum 0.9
+  python -m dear_pytorch_tpu.benchmarks.glm --model granite_4_0_h_micro \\
+      --num-layers 10 --vocab-size 25088 --remat \\
+      --sequence-len 4096 --batch-size 1 --fp16 --momentum 0.9
 """
 
 from __future__ import annotations
@@ -37,18 +41,20 @@ from dear_pytorch_tpu.observability import tracer as T
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
-        description="TPU Synthetic sparse-decoder (GLM-MoE, LFM2-MoE) "
-                    "Benchmark",
+        description="TPU Synthetic decoder (GLM-MoE, LFM2-MoE, "
+                    "Granite-4.0-H) Benchmark",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter,
     )
     p.add_argument("--model", type=str, default="glm47_flash",
-                   help=f"one of {models.glm_names() + models.lfm2_names()}")
+                   help="one of " + str(
+                       models.glm_names() + models.lfm2_names()
+                       + models.granite_names()))
     p.add_argument("--sequence-len", type=int, default=4096)
     p.add_argument("--num-layers", type=int, default=None,
                    help="blocks run here (the leading dense layer first)")
     p.add_argument("--first-layer", type=int, default=0,
-                   help="LFM2: the published layer the blocks start at (its "
-                        "layer_types and dense layers follow)")
+                   help="the hybrids: the published layer the blocks start "
+                        "at (its layer_types, LFM2's dense layers, follow)")
     p.add_argument("--experts-held", type=int, default=None,
                    help="routed experts this chip holds (default: all); the "
                         "router scores all of the model's either way")
@@ -66,9 +72,16 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def config_from_args(args, dtype):
-    """The model's config (`GlmMoeConfig` or `Lfm2MoeConfig`) cut to this
-    chip's share."""
+    """The model's config (`GlmMoeConfig`, `Lfm2MoeConfig` or
+    `GraniteHybridConfig`) cut to this chip's share."""
     cfg = models.get_model(args.model, dtype=dtype).config
+    if isinstance(cfg, models.GraniteHybridConfig):
+        if args.experts_held is not None or args.expert_offset:
+            raise ValueError("the Granite hybrid has no routed experts")
+        return dataclasses.replace(
+            cfg, remat=args.remat, vocab_size=args.vocab_size
+            or cfg.vocab_size,
+            layer_types=cfg.layer_types[args.first_layer:][:args.num_layers])
     given = {"experts_held": args.experts_held,
              "vocab_size": args.vocab_size}
     if isinstance(cfg, models.Lfm2MoeConfig):
@@ -77,7 +90,7 @@ def config_from_args(args, dtype):
         given.update(layer_types=kinds, num_hidden_layers=len(kinds),
                      num_dense_layers=max(cfg.num_dense_layers - first, 0))
     elif args.first_layer:
-        raise ValueError("--first-layer is the LFM2 family's")
+        raise ValueError("--first-layer is the hybrid families'")
     else:
         given["num_layers"] = args.num_layers
         if args.no_mtp:
@@ -91,6 +104,8 @@ def loss_of(cfg, outputs, input_ids):
     """The family's training loss of its model's outputs."""
     if isinstance(cfg, models.Lfm2MoeConfig):
         return models.lfm2_moe_lm_loss(outputs, input_ids)
+    if isinstance(cfg, models.GraniteHybridConfig):
+        return models.granite_hybrid_lm_loss(outputs, input_ids)
     return models.glm_moe_lm_loss(outputs, input_ids,
                                   mtp_loss_weight=cfg.mtp_loss_weight)
 
@@ -104,8 +119,8 @@ def main(argv=None) -> runner.BenchResult:
 
     cfg = config_from_args(args, jnp.bfloat16 if args.fp16 else jnp.float32)
     lfm2 = isinstance(cfg, models.Lfm2MoeConfig)
-    model = (models.Lfm2MoeLmHeadModel if lfm2
-             else models.GlmMoeLmHeadModel)(cfg)
+    dense = isinstance(cfg, models.GraniteHybridConfig)
+    model = type(models.get_model(args.model))(cfg)
     global_bs = args.batch_size * world
     batch = data.synthetic_gpt_batch(
         jax.random.PRNGKey(0), global_bs, seq_len=args.sequence_len,
@@ -118,9 +133,12 @@ def main(argv=None) -> runner.BenchResult:
             jax.random.PRNGKey(0))
 
     def loss_fn(p, b, rng):
-        # aux: the routing counter, assignments per (expert layer, held
-        # expert), averaged over the workers by the step
+        # aux (the sparse decoders): the routing counter, assignments per
+        # (expert layer, held expert), averaged over the workers by the step
         del rng
+        if dense:
+            return loss_of(cfg, model.apply({"params": p}, b["input_ids"]),
+                           b["input_ids"])
         outputs, collections = model.apply(
             {"params": p}, b["input_ids"], mutable=["intermediates"])
         return (loss_of(cfg, outputs, b["input_ids"]),
@@ -128,19 +146,22 @@ def main(argv=None) -> runner.BenchResult:
 
     dear_cfg = runner.config_from_args(args, world=world)
     ts, stepper = runner.build_stepper(dear_cfg, loss_fn, params, mesh,
-                                       mgwfbp=args.mgwfbp, has_aux=True)
+                                       mgwfbp=args.mgwfbp, has_aux=not dense)
     state = ts.init(params)
     del params
 
-    scored = cfg.num_experts if lfm2 else cfg.n_routed_experts
-    held = cfg.experts_held or scored
-    depth = (f"layers {', '.join(cfg.layer_types)}" if lfm2 else
+    depth = (f"layers {', '.join(cfg.layer_types)}" if lfm2 or dense else
              f"{cfg.num_layers} layer(s), {cfg.num_nextn_predict_layers} "
              "prediction module(s)")
+    experts = "no routed experts"
+    if not dense:
+        scored = cfg.num_experts if lfm2 else cfg.n_routed_experts
+        held = cfg.experts_held or scored
+        experts = (f"experts [{cfg.expert_offset}, "
+                   f"{cfg.expert_offset + held}) of {scored}")
     runner.log(f"{args.model} causal-LM pretraining, sequence len: "
-               f"{args.sequence_len}; {depth}, experts "
-               f"[{cfg.expert_offset}, {cfg.expert_offset + held}) of "
-               f"{scored}, {cfg.vocab_size} ids")
+               f"{args.sequence_len}; {depth}, {experts}, "
+               f"{cfg.vocab_size} ids")
     runner.log(f"Batch size: {args.batch_size} (per dp rank), "
                f"{global_bs} global "
                f"({global_bs * args.sequence_len} tokens/step)")

@@ -40,6 +40,13 @@ from dear_pytorch_tpu.models.glm_moe import (  # noqa: F401
     GlmMoeLmHeadModel,
     glm_moe_lm_loss,
 )
+from dear_pytorch_tpu.models.granite_hybrid import (  # noqa: F401
+    GRANITE_4_0_H_MICRO,
+    GRANITE_HYBRID_TINY,
+    GraniteHybridConfig,
+    GraniteHybridLmHeadModel,
+    granite_hybrid_lm_loss,
+)
 from dear_pytorch_tpu.models.lfm2_moe import (  # noqa: F401
     LFM2_8B_A1B,
     LFM2_MOE_TINY,
@@ -112,6 +119,21 @@ _LFM2_REGISTRY: dict[str, Any] = {
 }
 
 
+# Dense Mamba-2 / attention hybrids with the Granite multipliers
+# (models/granite_hybrid.py).
+_GRANITE_REGISTRY: dict[str, Any] = {
+    "granite_4_0_h_micro": GRANITE_4_0_H_MICRO,
+    "granite_hybrid_tiny": GRANITE_HYBRID_TINY,   # CPU tests and smoke runs
+}
+
+#: decoders `benchmarks/glm.py` runs: (registry, model class)
+_DECODERS = (
+    (_GLM_REGISTRY, GlmMoeLmHeadModel),
+    (_LFM2_REGISTRY, Lfm2MoeLmHeadModel),
+    (_GRANITE_REGISTRY, GraniteHybridLmHeadModel),
+)
+
+
 def cnn_names() -> list[str]:
     return sorted(_CNN_REGISTRY)
 
@@ -130,6 +152,10 @@ def glm_names() -> list[str]:
 
 def lfm2_names() -> list[str]:
     return sorted(_LFM2_REGISTRY)
+
+
+def granite_names() -> list[str]:
+    return sorted(_GRANITE_REGISTRY)
 
 
 def expert_assignments(cfg, intermediates):
@@ -158,15 +184,16 @@ def get_model(name: str, *, dtype=jnp.float32, **kwargs):
             cfg = dataclasses.replace(cfg, dtype=dtype)
         cls = BertForPreTraining if key in _BERT_REGISTRY else GptLmHeadModel
         return cls(cfg, **kwargs)
-    if key in _GLM_REGISTRY or key in _LFM2_REGISTRY:
-        import dataclasses
+    for registry, cls in _DECODERS:
+        if key in registry:
+            import dataclasses
 
-        cfg = _GLM_REGISTRY.get(key) or _LFM2_REGISTRY[key]
-        cls = GlmMoeLmHeadModel if key in _GLM_REGISTRY else Lfm2MoeLmHeadModel
-        return cls(dataclasses.replace(cfg, dtype=dtype), **kwargs)
+            return cls(dataclasses.replace(registry[key], dtype=dtype),
+                       **kwargs)
     raise KeyError(
         f"unknown model {name!r}; CNNs: {cnn_names()}, BERT: {bert_names()}, "
-        f"GPT: {gpt_names()}, GLM: {glm_names()}, LFM2: {lfm2_names()}"
+        f"GPT: {gpt_names()}, GLM: {glm_names()}, LFM2: {lfm2_names()}, "
+        f"Granite: {granite_names()}"
     )
 
 

@@ -115,18 +115,25 @@ LFM2_MOE_TINY = Lfm2MoeConfig(
     intermediate_size=128, moe_intermediate_size=48, num_experts=16)
 
 
+def causal_depthwise_conv(u, taps):
+    """``c_t = sum_j taps[j] * u_{t-(L-1)+j}`` of ``u`` ``[B, S, C]`` with
+    ``taps`` ``[L, C]``, zeros before the sequence: ``L`` shifted
+    multiply-adds (on the chip they beat `lax.conv_general_dilated`
+    depthwise, PERF.md PR 35). Shared with `models.granite_hybrid`'s Mamba-2
+    mixer (four taps, a bias and a silu after it)."""
+    seq, lag = u.shape[1], taps.shape[0] - 1
+    padded = jnp.pad(u, ((0, 0), (lag, 0), (0, 0)))
+    return sum(taps[j] * padded[:, j:j + seq] for j in range(lag + 1))
+
+
 def short_conv_filter(gates, taps):
     """``C * conv(B * x~)`` of ``gates`` ``[B, S, 3H]`` (``B``, ``C``,
     ``x~`` side by side, the source's order) with ``taps`` ``[L, H]``: the
-    depthwise causal convolution ``c_t = sum_j taps[j] * u_{t-(L-1)+j}``
-    (zeros before the sequence) between its two multiplicative gates.
-    Elementwise throughout, f32 arithmetic, result in ``gates``' dtype."""
+    depthwise causal convolution (`causal_depthwise_conv`) between its two
+    multiplicative gates. Elementwise throughout, f32 arithmetic, result in
+    ``gates``' dtype."""
     b, c, x = jnp.split(gates.astype(jnp.float32), 3, axis=-1)
-    u = b * x
-    seq, lag = u.shape[1], taps.shape[0] - 1
-    padded = jnp.pad(u, ((0, 0), (lag, 0), (0, 0)))
-    conv = sum(taps[j] * padded[:, j:j + seq] for j in range(lag + 1))
-    return (c * conv).astype(gates.dtype)
+    return (c * causal_depthwise_conv(b * x, taps)).astype(gates.dtype)
 
 
 class ShortConv(nn.Module):

@@ -302,6 +302,85 @@ def test_routed_experts_layer_selects_the_row_kernels(
         ("transpose(jvp(moe))", "dispatch", "combine")][:kernels])
 
 
+def _granite_block_text(one_chip, mixer, remat):
+    """Optimized HLO of one Granite-4.0-H-Micro block at the cell's widths
+    (hidden 2048, bf16, one sequence of 4096), value and gradient."""
+    import dataclasses
+
+    from dear_pytorch_tpu import models
+    from dear_pytorch_tpu.models import granite_hybrid
+
+    cfg = dataclasses.replace(models.GRANITE_4_0_H_MICRO,
+                              layer_types=(mixer,), dtype=jnp.bfloat16)
+    block = granite_hybrid.GraniteHybridBlock
+    if remat:
+        block = granite_hybrid.nn.remat(
+            block, policy=granite_hybrid._BLOCK_POLICY)
+    layer = block(cfg, mixer)
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    x = on((1, 4096, 2048), jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: on(a.shape, a.dtype),
+        jax.eval_shape(lambda key: layer.init(key, jnp.zeros(
+            (1, 8, 2048), jnp.bfloat16))["params"], jax.random.PRNGKey(0)))
+
+    def loss(p, x):
+        with jax.named_scope("block"):
+            return jnp.sum(jnp.square(
+                layer.apply({"params": p}, x).astype(jnp.float32)))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["stored", "remat"])
+def test_granite_mamba_block_compiles_for_v5e_with_its_scopes(
+        compiled_kernels, one_chip, remat):
+    """One Mamba-2 block at the cell's widths (`x [1, 4096, 64, 64]`, one
+    B/C group, state 128, chunk 256): XLA, no kernel; every inner scope of
+    ``mamba`` is on instructions of the forward pass and, under
+    ``transpose(jvp(...))``, of the backward pass, which is what
+    `mamba_mixer_ms`, `ssd_scan_ms` and `ssd_scan_roofline_pct` join on; the
+    scan's recomputation sits under its own ``checkpoint``; the temporaries
+    (the f32 `[16, 64, 256, 256]` decay matrices among them) stay under 3
+    GB."""
+    text, memory = _granite_block_text(one_chip, "mamba", remat)
+    assert KERNEL not in text and text.count(" while(") >= 2
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for inner in ("in_proj", "conv1d", "ssd", "gate_norm", "out_proj"):
+        scope = f"mamba/{inner}"
+        assert any("jvp(block)" in n and "transpose(" not in n
+                   and scope in n for n in names), scope
+        assert any("transpose(jvp(block))" in n and scope in n
+                   for n in names), scope
+    assert any("mamba/ssd/checkpoint/rematted_computation" in n
+               for n in names)
+    assert memory.temp_size_in_bytes < 3e9
+
+
+@pytest.mark.parametrize("remat,kernels", [(False, 2), (True, 3)],
+                         ids=["stored", "remat"])
+def test_granite_attention_block_holds_the_grouped_flash_kernels(
+        compiled_kernels, one_chip, remat, kernels):
+    """The position-free attention block at 32 Q / 8 K/V heads of 64,
+    S=4096: the two grouped flash kernels under the bare ``attention``
+    scope (`attention_kernel_calls_per_step`, `gqa_attention_flops_util_pct`
+    read it); a recomputed block runs the forward kernel a second time in
+    its backward pass (three calls: what the benchmark cell's step holds).
+    No rotary table, no dense ``[S, S]`` scores."""
+    text, _ = _granite_block_text(one_chip, "attention", remat)
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    assert len(calls) == kernels
+    for line in calls:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert re.search(r"(?:^|[/(])attention(?:[/)]|$)", op_name), op_name
+    assert sum("transpose(jvp(block))" in line for line in calls) \
+        == kernels - 1
+    assert "f32[1,32,4096,4096]" not in text
+    assert not re.search(r"\b(cosine|sine)\(", text)
+
+
 def test_gpt2_head_and_loss_keep_one_bf16_logits_buffer(one_chip):
     """GPT-2 124M's tied head and `gpt_lm_loss` at the benchmark cell's
     shapes, value and both gradients. XLA:TPU fuses the row maximum into

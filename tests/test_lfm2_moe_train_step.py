@@ -113,6 +113,6 @@ def test_the_cli_flags_cut_the_benchmark_cells_share():
          "3"]), jnp.float32)
     assert late.layer_types == ("full_attention", "conv", "conv")
     assert late.num_dense_layers == 0 and late.num_hidden_layers == 3
-    with pytest.raises(ValueError, match="LFM2 family's"):
+    with pytest.raises(ValueError, match="hybrid families'"):
         glm_cli.config_from_args(glm_cli.build_parser().parse_args(
             ["--first-layer", "1"]), jnp.float32)
