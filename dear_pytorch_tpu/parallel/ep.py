@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dear_pytorch_tpu.ops import moe_rows
+from dear_pytorch_tpu.ops import grouped_matmul, moe_rows
 
 EP_AXIS = "ep"
 
@@ -177,18 +177,28 @@ class RoutedExperts(nn.Module):
     it moves the selection and receives no gradient.
 
     Dropless under any imbalance: the ``T*k`` assignments are sorted by
-    held expert (absent ones last, in no group) and two grouped matmuls
-    (`jax.lax.ragged_dot`, a Mosaic grouped-matmul kernel on the TPU) run
-    over the held experts' rows, so the buffers are ``T*k`` rows whatever the
-    routing: the worst case, every token choosing k held experts, fits.
-    Rows in no group are masked out explicitly wherever they could reach a
-    result or a gradient (the TPU kernel leaves them unwritten). On a TPU,
-    with rows of whole 128-lane tiles, the rows are moved between token
-    order and sorted order by the kernels of `ops.moe_rows`, which read the
+    held expert (absent ones last, in no group) and the feed-forward runs
+    as grouped matmuls over the held experts' rows, so the buffers are
+    ``T*k`` rows whatever the routing: the worst case, every token choosing
+    k held experts, fits. Rows in no group are kept out of every result
+    and every gradient. Two pairs of paths, each chosen from the backend,
+    the shapes and the dtype alone (same mathematics, still dropless: with
+    every assignment held the kernels walk all ``T*k`` rows):
+
+    the rows between token order and sorted order: on a TPU, with rows of
+    whole 128-lane tiles, the kernels of `ops.moe_rows`, which read the
     count of the held experts' rows on the device and neither read nor
-    write the others (same mathematics, still dropless: with every
-    assignment held they move all ``T*k`` rows); elsewhere by the gathers
-    `_spread` / `_unpermute`, the kernels' reference.
+    write the others; elsewhere the gathers `_spread` / `_unpermute`, the
+    kernels' reference;
+
+    the feed-forward between them: on a TPU, in bfloat16, with ``H`` and
+    ``F`` whole lane tiles, the six kernels of `ops.grouped_matmul`, whose
+    row tiles follow the groups (none past the count is visited), with the
+    SwiGLU and its derivative in the matmuls' epilogues and f32
+    accumulation; elsewhere (f32, the CPU, `init`) two `jax.lax.ragged_dot`s with the
+    activation and an explicit mask on the rows of no group between them
+    (XLA:TPU's grouped-matmul kernel leaves those unwritten), the kernels'
+    reference.
 
     Input ``[T, H]``; returns the routed part ``[T, H]`` (add the shared
     expert outside: every chip computes that alike). Sows the assignments per
@@ -269,10 +279,19 @@ class RoutedExperts(nn.Module):
             else:
                 xs = _spread(x.astype(self.dtype), order, inverse, valid)
         with jax.named_scope("experts"):
-            gate_up = lax.ragged_dot(xs, wi.astype(self.dtype), sizes)
-            gate_up = jnp.where(valid[:, None], gate_up, 0)
-            act = jax.nn.silu(gate_up[:, :F]) * gate_up[:, F:]
-            ys = lax.ragged_dot(act, wo.astype(self.dtype), sizes)
+            # on a TPU, bf16 and whole lane tiles: grouped-matmul kernels
+            # that walk the held experts' rows only, the SwiGLU in their
+            # epilogues (`ops.grouped_matmul`); else XLA's grouped matmul
+            # and the mask, their reference
+            if (grouped_matmul.applies(T * k, H, F, self.dtype)
+                    and not self.is_initializing()):
+                ys = grouped_matmul.feed_forward(
+                    xs, wi.astype(self.dtype), wo.astype(self.dtype), sizes)
+            else:
+                gate_up = lax.ragged_dot(xs, wi.astype(self.dtype), sizes)
+                gate_up = jnp.where(valid[:, None], gate_up, 0)
+                act = jax.nn.silu(gate_up[:, :F]) * gate_up[:, F:]
+                ys = lax.ragged_dot(act, wo.astype(self.dtype), sizes)
         with jax.named_scope("combine"):
             if kernels:
                 return moe_rows.combine(ys, weights, moved, x.dtype)

@@ -28,7 +28,7 @@ from dear_pytorch_tpu.ops.collective_matmul import (
     ring_all_gather,
 )
 from dear_pytorch_tpu.ops.flash_attention import flash_attention
-from dear_pytorch_tpu.ops import moe_rows
+from dear_pytorch_tpu.ops import grouped_matmul, moe_rows
 from dear_pytorch_tpu.ops.fused_sgd import fused_sgd
 from dear_pytorch_tpu.parallel import DearState, build_train_step
 from dear_pytorch_tpu.parallel.ep import RoutedExperts
@@ -65,7 +65,8 @@ def compiled_kernels(monkeypatch):
     is the CPU here; steer them to the Mosaic path for these compiles.
     (By module name through sys.modules: `dear_pytorch_tpu.ops` re-exports
     a `flash_attention` FUNCTION that shadows the module attribute.)"""
-    for name in ("flash_attention", "collective_matmul", "moe_rows"):
+    for name in ("flash_attention", "collective_matmul", "moe_rows",
+                 "grouped_matmul"):
         monkeypatch.setattr(sys.modules[f"dear_pytorch_tpu.ops.{name}"],
                             "_interpret", lambda: False)
 
@@ -260,6 +261,39 @@ def test_moe_row_kernels_compile_for_v5e(compiled_kernels, one_chip, op,
     assert len(calls) == 1 and f"moe_{body}_rows" in calls[0]
 
 
+# The routed experts' feed-forward at the sparse cells' shapes: 32,768
+# sorted rows (8192 tokens x 4 experts) of H = 2048 against 8 held experts
+# of 1536 (GLM-4.7-Flash) and 1792 (LFM2-8B-A1B; 14 lane tiles: its column
+# tiles are 896 or 1792 wide), bf16 rows, f32 parameter leaves: two kernels
+# forward, four more backward, whole-contraction blocks within the 64 MB of
+# VMEM the kernels ask for
+@pytest.mark.parametrize("mlp_dim", [1536, 1792],
+                         ids=["glm-4.7-flash", "lfm2-8b-a1b"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_grouped_matmul_kernels_compile_for_v5e(compiled_kernels, one_chip,
+                                                mlp_dim, direction):
+    N, H, E = 32768, 2048, 8
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    operands = (on((N, H), jnp.bfloat16), on((E, H, 2 * mlp_dim), jnp.float32),
+                on((E, mlp_dim, H), jnp.float32))
+
+    def fn(xs, wi, wo, sizes):
+        return grouped_matmul.feed_forward(
+            xs, wi.astype(jnp.bfloat16), wo.astype(jnp.bfloat16), sizes)
+
+    if direction == "bwd":
+        fn = jax.grad(lambda *a, f=fn: jnp.square(
+            f(*a).astype(jnp.float32)).sum(), argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*operands, on((E,), jnp.int32)).compile(
+        ).as_text()
+    names = sorted(re.search(r"/(grouped_\w+)", line).group(1)
+                   for line in text.splitlines() if KERNEL in line)
+    forward = ["grouped_gate_up", "grouped_matmul"]
+    assert names == (forward if direction == "fwd" else sorted(
+        forward + ["grouped_act_grad", "grouped_matmul",
+                   "grouped_weight_grad", "grouped_weight_grad"]))
+
+
 @pytest.mark.parametrize("width,mlp_dim,hidden,kernels", [
     (64, 1536, 2048, 4), (32, 1792, 2048, 4), (16, 48, 64, 0)],
     ids=["glm-4.7-flash", "lfm2-8b-a1b", "tiny"])
@@ -300,6 +334,19 @@ def test_routed_experts_layer_selects_the_row_kernels(
         ("jvp(moe)", "combine", "combine"),
         ("transpose(jvp(moe))", "combine", "spread"),
         ("transpose(jvp(moe))", "dispatch", "combine")][:kernels])
+    # and between them the feed-forward's six, under the ``experts`` scope
+    # `expert_matmul_kernel_calls_per_step` counts and `moe_routed_ms` books
+    found = (re.search(
+        r"(transpose\(jvp\(moe\)\)|jvp\(moe\))/RoutedExperts/experts/"
+        r"(?:jit\(\w+\)/)?(grouped_\w+)", line)
+        for line in text.splitlines() if KERNEL in line)
+    experts = sorted(m.groups() for m in found if m)
+    assert experts == sorted([
+        ("jvp(moe)", "grouped_gate_up"), ("jvp(moe)", "grouped_matmul"),
+        ("transpose(jvp(moe))", "grouped_act_grad"),
+        ("transpose(jvp(moe))", "grouped_matmul"),
+        ("transpose(jvp(moe))", "grouped_weight_grad"),
+        ("transpose(jvp(moe))", "grouped_weight_grad")][:6 * kernels // 4])
 
 
 def _granite_block_text(one_chip, mixer, remat):
