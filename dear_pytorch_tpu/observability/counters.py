@@ -23,6 +23,7 @@ times and this module's byte counts can never drift apart.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import numpy as np
@@ -98,6 +99,13 @@ class CommAccounting:
     def leg_bytes_per_step(self, leg: str) -> int:
         return sum(r.payload_bytes for r in self.rows if r.leg == leg)
 
+    @functools.cached_property
+    def payload_bytes_by_leg(self) -> dict:
+        """``{leg: payload bytes a step}``, legs in name order: what
+        `parallel/dear.py` adds to ``dear.<leg>_bytes`` every step."""
+        return {leg: self.leg_bytes_per_step(leg)
+                for leg in sorted({r.leg for r in self.rows})}
+
     def totals(self, steps: Optional[int] = None,
                runtime_counters: Optional[dict] = None) -> dict:
         """JSON-safe cumulative accounting.
@@ -154,7 +162,9 @@ def plan_comm_accounting(
     ``comm_itemsize`` is the gradient-leg dtype size in bytes
     (``comm_dtype`` — 2 for bf16); ``gather_itemsize`` the parameter
     all-gather leg's (``gather_dtype``, 'dear'/'fsdp' only; defaults to
-    ``comm_itemsize``). ``compressor``/``density`` scale the GRADIENT
+    the BUFFER's itemsize: with no ``gather_dtype`` the step gathers each
+    master shard as it is stored, whatever ``comm_dtype`` the gradients
+    travel in). ``compressor``/``density`` scale the GRADIENT
     leg's bytes by `ops.compression.wire_ratio` (the parameter all-gather
     stays dense): the payload shrinks to the compressed wire format, and
     the wire estimate becomes gather-shaped — compressed reductions
@@ -178,8 +188,10 @@ def plan_comm_accounting(
     if mode not in MODE_LEGS:
         raise ValueError(f"mode must be one of {sorted(MODE_LEGS)}, "
                          f"got {mode!r}")
-    gather_itemsize = (comm_itemsize if gather_itemsize is None
-                      else gather_itemsize)
+    buffer_itemsize = (np.dtype(plan.leaves[0].dtype).itemsize
+                       if plan.leaves else 4)
+    if gather_itemsize is None:
+        gather_itemsize = buffer_itemsize
     compressed = compressor not in (None, "none")
     if compressed:
         from dear_pytorch_tpu.ops import compression as Z
@@ -189,8 +201,7 @@ def plan_comm_accounting(
         # — its payload values never travel in comm_dtype, so price them
         # at the buffer itemsize or the wire bytes under-count whenever a
         # caller combines compressor with a narrower comm_dtype
-        comp_itemsize = (np.dtype(plan.leaves[0].dtype).itemsize
-                         if plan.leaves else 4)
+        comp_itemsize = buffer_itemsize
 
     rows = []
     for b in plan.buckets:
@@ -219,8 +230,7 @@ def plan_comm_accounting(
             # dtype (the host leg averages reduced f32 partials; see
             # comm/dcn.py) — price it at the leaf itemsize, not the
             # intra-slice comm_dtype
-            dcn_itemsize = (np.dtype(plan.leaves[0].dtype).itemsize
-                            if plan.leaves else 4)
+            dcn_itemsize = buffer_itemsize
             payload = b.padded_size * dcn_itemsize
             chunks = len(F.chunk_bounds(
                 b.padded_size, dcn_itemsize, dcn_partition_mb))

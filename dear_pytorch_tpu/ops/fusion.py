@@ -427,27 +427,35 @@ def unpack_bucket(
 
 
 def pack_all(tree, plan: FusionPlan, dtype=None) -> list[jax.Array]:
-    """Pack every bucket from a pytree with the plan's structure."""
+    """Pack every bucket from a pytree with the plan's structure. Each
+    bucket's copy is named ``bucket<g>`` (under the step's ``dear/pack``: a
+    profile shows which bucket a copy belongs to)."""
     leaves = jax.tree_util.tree_leaves(tree)
     if len(leaves) != len(plan.leaves):
         raise ValueError(
             f"tree has {len(leaves)} leaves, plan expects {len(plan.leaves)}"
         )
-    return [pack_bucket(leaves, plan, b.index, dtype) for b in plan.buckets]
+    out = []
+    for b in plan.buckets:
+        with jax.named_scope(f"bucket{b.index}"):
+            out.append(pack_bucket(leaves, plan, b.index, dtype))
+    return out
 
 
 def unpack_all(buffers: Sequence[jax.Array], plan: FusionPlan, *, wrap=None,
                cast=True):
     """Rebuild the original pytree from per-bucket flat buffers, restoring
     each leaf's shape and (with ``cast=True``, the default) dtype. ``wrap``
-    and ``cast=False`` serve the fsdp schedule — see `unpack_bucket`."""
+    and ``cast=False`` serve the fsdp schedule — see `unpack_bucket`. Each
+    bucket's slices are named ``bucket<g>``, as `pack_all`'s copies are."""
     if len(buffers) != plan.num_buckets:
         raise ValueError(
             f"{len(buffers)} buffers for {plan.num_buckets} buckets"
         )
     flat: list[Optional[jax.Array]] = [None] * len(plan.leaves)
     for b, buf in zip(plan.buckets, buffers):
-        pieces = unpack_bucket(buf, plan, b.index, wrap=wrap, cast=cast)
+        with jax.named_scope(f"bucket{b.index}"):
+            pieces = unpack_bucket(buf, plan, b.index, wrap=wrap, cast=cast)
         for leaf_id, x in pieces.items():
             flat[leaf_id] = x
     return jax.tree_util.tree_unflatten(plan.treedef, flat)

@@ -43,7 +43,9 @@ The map. `build_train_step` is four boxes, in this order:
 To add or change a schedule, edit its class in `schedules.py`: the legs are
 all it has to say, and `_fwd_bwd` / `_apply` read nothing of a schedule but
 `sharded`. Every `jax.named_scope` of the step is set here, around the leg it
-names (the hierarchical programs' ``dear/metrics`` in `hier.py`).
+names (the hierarchical programs' ``dear/metrics`` in `hier.py`; the
+``bucket<g>`` under ``dear/pack`` and ``dear/unpack`` where the loop over
+buckets runs, `ops/fusion.py:pack_all` / `unpack_all`).
 """
 
 from __future__ import annotations
@@ -122,8 +124,12 @@ class TrainStep(NamedTuple):
     #: None on single-level schedules. Elastic transitions renormalize the
     #: cross-slice leg through it (``dcn.set_slices``).
     dcn: Any = None
-
-
+    #: the schedule's static account of itself, built once:
+    #: `observability.counters.CommAccounting`, one row per bucket and leg
+    #: with the payload and ring-estimate wire bytes the step asks the
+    #: interconnect to move (the ``dear.<leg>_bytes`` counters add its
+    #: per-leg sums every step under ``DEAR_TELEMETRY``)
+    comm: Any = None
 
 
 def _opt_bucket_specs(axis_name: str, bucket_padded: int, opt_state_leaf):
@@ -606,18 +612,19 @@ def build_train_step(
             lambda x, s: jax.device_put(x, jax.sharding.NamedSharding(mesh, s)),
             state, specs)
 
-    # ---- telemetry ---------------------------------------------------------
-    # Static per-step communication accounting for this (plan, mode). The
-    # hot path pays two dict adds + one span per step when telemetry is ON
-    # and a single attribute check when it is off (the contract
-    # scripts/check_telemetry_overhead.py measures).
+    # ---- the schedule's account of itself, and telemetry -------------------
+    # Static per-step communication accounting for this (plan, mode),
+    # returned as `TrainStep.comm`. The hot path pays two dict adds + one
+    # span per step when telemetry is ON and a single attribute check when
+    # it is off (the contract scripts/check_telemetry_overhead.py measures).
     _leaf_itemsize = (
         jnp.dtype(plan.leaves[0].dtype).itemsize if plan.leaves else 4
     )
-    _acct = _tel_counters.plan_comm_accounting(
+    comm = _tel_counters.plan_comm_accounting(
         plan, mode=mode,
         comm_itemsize=(jnp.dtype(comm_dtype).itemsize
                        if comm_dtype is not None else _leaf_itemsize),
+        # None: the shard is gathered as it is stored (`gather_unpack`)
         gather_itemsize=(jnp.dtype(gather_dtype).itemsize
                          if gather_dtype is not None else None),
         compressor=comp.name if compressed else None,
@@ -628,24 +635,20 @@ def build_train_step(
         num_slices=(dcn.num_slices if dcn is not None else 1),
         dcn_partition_mb=(partition_mb if dcn is not None else None),
     )
-    _leg_bytes = {
-        leg: _acct.leg_bytes_per_step(leg)
-        for leg in sorted({r.leg for r in _acct.rows})
-    }
     _tr = _telemetry.get_tracer()
     if _tr.enabled:
         _tr.count("dear.plan_builds")
         _tr.event(
             "dear.plan_built", mode=mode, world=world,
             buckets=plan.num_buckets, total_elements=plan.total_size,
-            payload_bytes_per_step=_acct.payload_bytes_per_step,
+            payload_bytes_per_step=comm.payload_bytes_per_step,
         )
 
     def _count_step(tr):
         if not tr.enabled:
             return
         tr.count("dear.steps")
-        for leg, nbytes in _leg_bytes.items():
+        for leg, nbytes in comm.payload_bytes_by_leg.items():
             tr.count(f"dear.{leg}_bytes", nbytes)
         schedule.count_launches(tr)
 
@@ -724,7 +727,6 @@ def build_train_step(
             return cached
         tr = _telemetry.get_tracer()
         if tr.enabled:
-            tr.count("dear.multi_step_compiles")
             tr.event("dear.multi_step_compile", mode=mode, n=n)
 
         def fn(state: DearState, batch):
@@ -758,4 +760,4 @@ def build_train_step(
             donate=donate, count_step=_count_step)
     return TrainStep(init=init, step=step, gather_params=gather_params,
                      plan=plan, mesh=mesh, lower=lower,
-                     multi_step=multi_step, dcn=dcn)
+                     multi_step=multi_step, dcn=dcn, comm=comm)

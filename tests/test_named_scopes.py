@@ -156,6 +156,73 @@ def test_other_schedules_carry_their_scopes(mode, kw, want, monkeypatch):
     assert not want - found, sorted(want - found)
 
 
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_pack_and_unpack_name_every_bucket(family):
+    """``dear/pack`` and ``dear/unpack`` hold one ``bucket<g>`` a bucket and
+    nothing directly: a profile shows pack -> ``dear/bucket<g>/reduce`` ->
+    update -> gather -> unpack bucket by bucket."""
+    ts, state, batch = _build(family, 4)
+    text = ts.lower(state, batch).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*)"', text))
+    for g in range(ts.plan.num_buckets):
+        for copy in ("pack", "unpack"):
+            assert any(f"dear/{copy}/bucket{g}/" in n for n in names), (
+                copy, g)
+    bare = [n for n in names
+            if re.search(r"dear/(?:pack|unpack)/(?!bucket\d+/)", n)]
+    assert not bare, bare
+
+
+@pytest.mark.parametrize("mode", ["dear", "allreduce"])
+def test_comm_is_the_schedules_account(mode, monkeypatch):
+    """`TrainStep.comm` is `plan_comm_accounting` of the plan and mode, and
+    what ``dear.<leg>_bytes`` count a step under ``DEAR_TELEMETRY=1``."""
+    from dear_pytorch_tpu.observability import counters, tracer
+
+    monkeypatch.setenv("DEAR_TELEMETRY", "1")
+    before = tracer.get_tracer()
+    tracer.set_tracer(None)            # the next use reads the environment
+    try:
+        ts, state, batch = _build("gpt", 4, mode=mode)
+        assert ts.comm == counters.plan_comm_accounting(
+            ts.plan, mode=mode, comm_itemsize=4)
+        legs = {r.leg for r in ts.comm.rows}
+        assert legs == set(counters.MODE_LEGS[mode])
+        assert len(ts.comm.rows) == ts.plan.num_buckets * len(legs)
+        for _ in range(2):
+            state, _ = ts.step(state, batch)
+        counted = tracer.get_tracer().counters()
+        assert counted["dear.steps"] == 2
+        for leg in legs:
+            assert counted[f"dear.{leg}_bytes"] == (
+                2 * ts.comm.leg_bytes_per_step(leg)) > 0
+    finally:
+        tracer.set_tracer(before)
+
+
+@pytest.mark.parametrize("gather_dtype,gather_itemsize",
+                         [(None, 4), (jnp.bfloat16, 2)],
+                         ids=["as-stored", "bf16"])
+def test_gather_leg_is_priced_at_what_the_step_gathers(gather_dtype,
+                                                       gather_itemsize):
+    """bf16 gradients over f32 masters: with no ``gather_dtype`` the step
+    gathers each shard as it is stored (f32), not in ``comm_dtype``; the
+    compiled all-gathers move the dtype the account prices."""
+    ts, state, batch = _build("gpt", 4, comm_dtype=jnp.bfloat16,
+                              gather_dtype=gather_dtype)
+    by = {(r.bucket, r.leg): r for r in ts.comm.rows}
+    for b in ts.plan.buckets:
+        gather, reduce = by[b.index, "all_gather"], by[b.index,
+                                                       "reduce_scatter"]
+        assert gather.payload_bytes == b.padded_size * gather_itemsize
+        assert gather.wire_bytes == gather.payload_bytes * 3 / 4
+        assert reduce.payload_bytes == b.padded_size * 2
+    text = ts.lower(state, batch).as_text()
+    gathered = set(re.findall(
+        r"stablehlo.all_gather.*-> tensor<\d+x(\w+)>", text))
+    assert gathered == {"f32" if gather_itemsize == 4 else "bf16"}
+
+
 def test_checkpointed_and_flash_impls_sit_under_attention():
     from dear_pytorch_tpu.models.gpt import (
         checkpointed_causal_attention_impl, flash_causal_attention_impl)
