@@ -66,6 +66,17 @@ def pad_to_multiple(x: jax.Array, world: int) -> jax.Array:
     return jnp.concatenate([x, jnp.zeros((target - n,), dtype=x.dtype)])
 
 
+#: lanes of a TPU tile
+LANES = 128
+
+
+def lanes(x: jax.Array) -> jax.Array:
+    """A flat buffer as ``[len / 128, 128]``: the layout in which XLA:TPU
+    tiles a bucket, where a flat operand is tiled 1-D and its
+    reduce-scatter padded into an all-reduce (`ops.fusion.bucket_length`)."""
+    return x.reshape(-1, LANES)
+
+
 # ---------------------------------------------------------------------------
 # Per-shard collectives (use inside shard_map)
 # ---------------------------------------------------------------------------

@@ -72,7 +72,7 @@ class Bucket:
     leaf_ids: tuple[int, ...]
     offsets: tuple[int, ...]
     size: int          # total elements (unpadded)
-    padded_size: int   # rounded up to a multiple of world
+    padded_size: int   # `bucket_length`: a multiple of world, or of spans
     shard_size: int    # padded_size // world
 
     @property
@@ -82,7 +82,16 @@ class Bucket:
 
 @dataclasses.dataclass(frozen=True)
 class FusionPlan:
-    """Complete static bucketing of a parameter pytree."""
+    """Complete static bucketing of a parameter pytree.
+
+    Each bucket is zero-padded to `bucket_length`: a multiple of ``world``,
+    so that it shards evenly, and, where `build_train_step` lays the plan
+    out for a schedule whose legs travel lane-dense on a TPU mesh of four
+    (`parallel.schedules.Dear.lane_dense`), to whole spans of XLA:TPU's
+    reduce-scatter, so that the compiler keeps each bucket's reduce-scatter
+    rather than padding it into an all-reduce of twice the traffic. Every
+    leg carries the same zeros; `utils.checkpoint.plan_desc` records the
+    padded lengths, so a plan rebuilt from a checkpoint keeps them."""
 
     leaves: tuple[LeafSpec, ...]
     buckets: tuple[Bucket, ...]
@@ -326,27 +335,74 @@ def make_plan(
 
 
 def rescale_plan(plan: FusionPlan, world: int,
-                 *, epoch: Optional[int] = None) -> FusionPlan:
+                 *, epoch: Optional[int] = None,
+                 platform: Optional[str] = None) -> FusionPlan:
     """Rebuild ``plan`` for a NEW replica count (elastic membership change:
     a host is lost or readmitted and the data-parallel world shrinks or
-    grows). The leaf specs and bucket grouping are preserved exactly — only
-    the per-bucket padding and shard sizes are recomputed for the new
-    ``world`` — so `tuning.autotune.repack_state` can carry a live
-    `DearState` across the resize. ``epoch`` stamps the membership epoch
-    into the plan (and therefore into `utils.checkpoint.plan_fingerprint`),
-    keeping plan-fingerprinted restores coherent across reconfigurations.
+    grows), or for the collectives of ``platform`` (`bucket_length`;
+    `build_train_step` passes its mesh's where its schedule's legs are
+    lane-dense, None elsewhere, so a plan made anywhere — a tuner's
+    groups, an elastic resize — is padded for the program that runs it).
+    The leaf specs and bucket grouping are preserved exactly — only
+    the per-bucket padding and shard sizes are recomputed — so
+    `tuning.autotune.repack_state` can carry a live `DearState` across the
+    resize. ``epoch`` stamps the membership epoch into the plan (and
+    therefore into `utils.checkpoint.plan_fingerprint`), keeping
+    plan-fingerprinted restores coherent across reconfigurations.
     """
-    if world == plan.world and (epoch is None or epoch == plan.epoch):
+    lengths = [bucket_length(b.size, world, platform) for b in plan.buckets]
+    if (world == plan.world and (epoch is None or epoch == plan.epoch)
+            and lengths == [b.padded_size for b in plan.buckets]):
         return plan
     rebuilt = _build_plan(
         plan.leaves, [list(b.leaf_ids) for b in plan.buckets], world,
-        plan.treedef,
+        plan.treedef, lengths,
     )
     return dataclasses.replace(
         rebuilt, epoch=plan.epoch if epoch is None else int(epoch))
 
 
-def _build_plan(specs, groups, world, treedef) -> FusionPlan:
+#: XLA:TPU compiles a bucket's reduce-scatter over a v5e 2x2 (four chips)
+#: as one "all-reduce-scatter fusion" that cuts the operand into equal
+#: spans of whole quanta, 128 x 128 elements each, at most `_SPAN_QUANTA`
+#: of them a span (3.7 MB of a bf16 wire). Where the bucket is no whole
+#: number of such spans the compiler pads it itself and the reduce-scatter
+#: becomes an all-reduce of the padded operand and a slice: twice the
+#: wire, and XLA then combines neighbouring buckets' all-reduces into one
+#: that waits for the last of them (PERF.md, PR 41: the span counts and
+#: sizes the compiler reports, bisected for each bucket size).
+_SPAN_QUANTUM = 128 * 128
+_SPAN_QUANTA = 114
+
+
+def spans_apply(platform: Optional[str], world: int) -> bool:
+    """Whether ``bucket_length`` pads to XLA:TPU's reduce-scatter spans: a
+    TPU mesh of four, the one reduction world the span rule was read on (a
+    v5e 2x2; `parallel.schedules.Dear.lane_dense` adds the rest of what it
+    was read on: the dense 'dear' / 'fsdp' legs over a bf16 wire)."""
+    return platform == "tpu" and world == 4
+
+
+def bucket_length(n: int, world: int, platform: Optional[str] = None) -> int:
+    """Padded length of a bucket of ``n`` elements over ``world`` devices
+    of ``platform`` (a mesh's ``devices.flat[0].platform``). Everywhere but
+    `spans_apply`: the smallest multiple of ``world``. There: the fewest
+    spans of at most `_SPAN_QUANTA` quanta that hold ``n``, each of the
+    same whole number of quanta, so the compiler adds no padding of its own
+    and keeps the reduce-scatter (a bf16 wire; an f32 one is cut
+    differently and may still be padded). The zeros cost at most one
+    quantum a span: 0.9% of a 25 MB bucket."""
+    if n == 0 or not spans_apply(platform, world):
+        return padded_length(n, world)
+    quanta = -(-n // _SPAN_QUANTUM)
+    spans = -(-quanta // _SPAN_QUANTA)
+    return spans * -(-quanta // spans) * _SPAN_QUANTUM
+
+
+def _build_plan(specs, groups, world, treedef, lengths=None) -> FusionPlan:
+    """The plan of ``groups`` (leaf ids a bucket). ``lengths``: each
+    bucket's padded length (`rescale_plan`, a checkpoint's `plan_desc`);
+    None pads to the smallest multiple of ``world``."""
     if world < 1:
         raise ValueError(f"world must be >= 1, got {world}")
     buckets = []
@@ -360,7 +416,12 @@ def _build_plan(specs, groups, world, treedef) -> FusionPlan:
             seen.add(i)
             offsets.append(off)
             off += specs[i].size
-        padded = padded_length(off, world)
+        padded = (padded_length(off, world) if lengths is None
+                  else int(lengths[idx]))
+        if padded < off or padded % world:
+            raise ValueError(
+                f"bucket {idx}: padded length {padded} does not hold "
+                f"{off} elements in a multiple of world={world}")
         buckets.append(
             Bucket(
                 index=idx,

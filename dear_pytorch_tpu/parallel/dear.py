@@ -318,6 +318,11 @@ def build_train_step(
         clip_norm=clip_norm, remat=remat, dcn=dcn)
     # ---- validate, continued: this schedule's limits, then option values ---
     schedule.check()
+    # buckets padded for this program's collectives (`F.bucket_length`),
+    # wherever the plan was made
+    plan = schedule.plan = F.rescale_plan(
+        plan, world, platform=(mesh.devices.flat[0].platform
+                               if schedule.lane_dense else None))
     # SDC sentinel: the per-bucket fingerprint is baked into the program
     # only when armed — resolved once here at build time, so the disabled
     # path carries zero extra ops and no per-step branch

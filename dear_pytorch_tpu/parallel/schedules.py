@@ -33,6 +33,9 @@ class Schedule:
     sharded = False
     #: the gradient leg can run compressed (`compressed_reduce`)
     compressible = False
+    #: the legs travel as ``[n / 128, 128]`` over span-padded buckets
+    #: (`Dear.lane_dense`)
+    lane_dense = False
 
     def __init__(self, **build):
         # the build's resolved options, all a leg may read: mesh, axes,
@@ -248,11 +251,34 @@ class Dear(Schedule):
     sharded = True
     compressible = True
 
+    @property
+    def wire_dtype(self):
+        """The dtype the reduce-scatter carries."""
+        return self.comm_dtype
+
+    @property
+    def lane_dense(self) -> bool:
+        """Both legs travel as ``[n / 128, 128]`` (`C.lanes`) and
+        `build_train_step` pads the plan's buckets to XLA:TPU's spans
+        (`F.spans_apply`), so the compiler keeps one reduce-scatter and one
+        all-gather a bucket, as asked. Only where the rule was read: the
+        dense legs over a bf16 wire, one level (no ``dcn``); compressed
+        payloads and an f32 wire keep the flat form."""
+        return (self.dcn is None and not self.compressed
+                and self.wire_dtype is not None
+                and jnp.dtype(self.wire_dtype) == jnp.bfloat16
+                and F.spans_apply(self.mesh.devices.flat[0].platform,
+                                  self.world))
+
     def gather(self, g, bucket, shard):
         """Bucket ``g``'s (cast) shard -> its full buffer."""
+        if self.lane_dense:
+            return C.all_gather(C.lanes(shard), self.axis_name).reshape(-1)
         return C.all_gather(shard, self.axis_name)
 
     def transport(self, bucket, gbuf):
+        if self.lane_dense:
+            return C.reduce_scatter(C.lanes(gbuf), self.axis_name).reshape(-1)
         return C.reduce_scatter(gbuf, self.axis_name)
 
     def reduce(self, g, bucket, gbuf, state, idx):
@@ -275,6 +301,8 @@ class DearFused(Dear):
     the rings too (`ops.collective_matmul.make_ring_projection_impl`)."""
 
     name = "dear-fused"
+    #: the ring kernels carry the flat buffers
+    lane_dense = False
 
     def check(self) -> None:
         if self.dcn is not None:
@@ -389,6 +417,10 @@ class Fsdp(Dear):
             raise ValueError(
                 "'fsdp' owns its rematerialization policy (the re-gather-in-"
                 "backward checkpoint); remat applies to the other schedules")
+
+    @property
+    def wire_dtype(self):
+        return self.gather_dtype
 
     def gather(self, g, bucket, shard):
         return _named(super().gather(g, bucket, shard))

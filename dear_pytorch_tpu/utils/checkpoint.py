@@ -99,6 +99,9 @@ def plan_desc(plan: F.FusionPlan) -> dict:
             for s in plan.leaves
         ],
         "groups": [list(b.leaf_ids) for b in plan.buckets],
+        # the buffers' lengths: a TPU four's lane-dense schedules pad to
+        # XLA:TPU's spans (`F.bucket_length`), not to a multiple of world
+        "padded": [b.padded_size for b in plan.buckets],
     }
 
 
@@ -117,7 +120,7 @@ def plan_from_desc(desc: dict, treedef) -> F.FusionPlan:
         for d in desc["leaves"]
     )
     plan = F._build_plan(specs, [list(g) for g in desc["groups"]],
-                         desc["world"], treedef)
+                         desc["world"], treedef, desc.get("padded"))
     epoch = int(desc.get("epoch", 0) or 0)
     if epoch:
         import dataclasses as _dc

@@ -30,6 +30,7 @@ from dear_pytorch_tpu.ops.collective_matmul import (
 from dear_pytorch_tpu.ops.flash_attention import flash_attention
 from dear_pytorch_tpu.ops import grouped_matmul, moe_rows
 from dear_pytorch_tpu.ops.fused_sgd import fused_sgd
+from dear_pytorch_tpu.ops.fusion import bucket_length
 from dear_pytorch_tpu.parallel import DearState, build_train_step
 from dear_pytorch_tpu.parallel.ep import RoutedExperts
 
@@ -458,11 +459,16 @@ def test_gpt2_head_and_loss_keep_one_bf16_logits_buffer(one_chip):
 
 def test_dear_step_compiles_for_four_v5e_chips(compiled_kernels, mesh4):
     """A 2-layer full-width GPT-2 `dear` step, lowered from shapes alone on
-    the described mesh: the program as written asks for reduce-scatter and
-    all-gather. What XLA:TPU keeps is pinned as observed on this topology
-    (PERF.md, PR 24): the parameter all-gathers survive, every gradient
-    reduce-scatter is rewritten into a combined all-reduce + slice. The
-    default attention core's kernels compile inside the `shard_map`."""
+    the described mesh. Its buckets are padded to XLA:TPU's reduce-scatter
+    spans and both legs travel as ``[n / 128, 128]`` (PR 41), so what
+    XLA:TPU keeps is what the program asks for: one reduce-scatter and one
+    all-gather a bucket, and no all-reduce but the loss's. (A flat bucket
+    of any other length compiled to an all-reduce of a padded operand,
+    ``from-cross-replica-sharding``, combined with its neighbours'.) The
+    default attention core's kernels compile inside the `shard_map`. Not
+    asynchronous: a bucket's gathered buffer is sliced into its leaves,
+    and this libtpu overlaps an all-gather only where its consumer takes
+    the buffer whole; no reduce-scatter is made asynchronous (PERF.md)."""
     model, loss_fn = chip_smoke.make_loss(
         chip_smoke.gpt2_config(jnp.bfloat16, num_layers=2))
     params = jax.eval_shape(
@@ -475,6 +481,8 @@ def test_dear_step_compiles_for_four_v5e_chips(compiled_kernels, mesh4):
         optimizer=fused_sgd(lr=chip_smoke.LR, momentum=chip_smoke.MOMENTUM),
         comm_dtype=jnp.bfloat16)
     sizes = [b.padded_size for b in ts.plan.buckets]
+    assert sizes == [bucket_length(b.size, 4, "tpu")
+                     for b in ts.plan.buckets]
     state = DearState(
         buffers=tuple(_on(mesh4, (n,), jnp.float32, P("dp")) for n in sizes),
         opt_state=tuple((_on(mesh4, (n,), jnp.float32, P("dp")),
@@ -487,12 +495,62 @@ def test_dear_step_compiles_for_four_v5e_chips(compiled_kernels, mesh4):
     assert "stablehlo.reduce_scatter" in asked
     assert "stablehlo.all_gather" in asked
     compiled = lowered.compile()
-    assert compiled.as_text().count(KERNEL) == 4      # 2 layers x (fwd + bwd)
-    kept = chip_smoke.count_collectives(compiled.as_text())
-    assert kept.get("all-gather", 0) >= 1, kept
-    assert kept.get("reduce-scatter", 0) + kept.get("all-reduce", 0) >= 1, kept
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 4      # 2 layers x (fwd + bwd)
+    kept = chip_smoke.count_collectives(text)
+    nb = ts.plan.num_buckets
+    assert kept == {"all-gather": nb, "reduce-scatter": nb,
+                    "all-reduce": 1}, kept
+    assert "from-cross-replica-sharding" not in text
+    for g in range(nb):
+        reduce = [line for line in text.splitlines()
+                  if f"/dear/bucket{g}/reduce/" in line
+                  and " reduce-scatter(" in line]
+        assert len(reduce) == 1, (g, reduce)
+        assert f"[{sizes[g] // 4 // 128},128]" in reduce[0]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_one_chip_step_gets_no_spans(one_chip):
+    """On one described chip the plan keeps the multiple-of-world padding
+    (world 1: none) and the legs their flat form: the one-chip cells'
+    programs are the parent's (PR 41; no compiler option anywhere)."""
+    from dear_pytorch_tpu.parallel import schedules as S
+
+    params = {"w": jax.ShapeDtypeStruct((1000, 7), jnp.float32),
+              "b": jax.ShapeDtypeStruct((7,), jnp.float32)}
+    mesh1 = Mesh(np.array(list(one_chip.device_set)), ("dp",))
+    ts = build_train_step(lambda p, x: jnp.sum(x @ p["w"] + p["b"]), params,
+                          mesh=mesh1, mode="dear")
+    assert [b.padded_size for b in ts.plan.buckets] == [7007]
+    assert not S.SCHEDULES["dear"](
+        mesh=mesh1, world=1, dcn=None, compressor=None,
+        comm_dtype=jnp.bfloat16).lane_dense
+
+
+@pytest.mark.parametrize("mode,build,lane_dense", [
+    ("dear", dict(comm_dtype=jnp.bfloat16), True),
+    ("fsdp", dict(gather_dtype=jnp.bfloat16), True),
+    ("dear", dict(comm_dtype=None), False),              # an f32 wire
+    ("dear", dict(comm_dtype=jnp.bfloat16, compressor="eftopk"), False),
+    ("dear-fused", dict(comm_dtype=jnp.bfloat16), False),
+    ("allreduce", dict(comm_dtype=jnp.bfloat16), False),
+    ("rsag", dict(comm_dtype=jnp.bfloat16), False),
+])
+def test_lane_dense_only_where_the_span_rule_was_read(mesh4, mode, build,
+                                                      lane_dense):
+    """On the described 2x2 the span padding and the ``[n / 128, 128]``
+    legs reach only what the rule was read on (PR 41): the dense
+    'dear' / 'fsdp' legs over a bf16 wire. Compressed payloads (whose k is
+    a share of the padded length), an f32 wire, the ring kernels and the
+    replicated schedules keep the flat plan."""
+    from dear_pytorch_tpu.parallel import schedules as S
+
+    kw = dict(dcn=None, compressor=None, comm_dtype=None, gather_dtype=None)
+    kw.update(build)
+    assert S.SCHEDULES[mode](mesh=mesh4, world=4, **kw).lane_dense \
+        is lane_dense
 
 
 def _expect_refusal(compile_fn, words: str):

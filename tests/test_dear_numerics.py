@@ -139,6 +139,57 @@ def test_dear_state_is_sharded(mesh, world, problem):
     assert mom.addressable_shards[0].data.size == mom.size // world
 
 
+@pytest.mark.parametrize("mode,build,spans", [
+    ("dear", dict(comm_dtype=jnp.bfloat16), True),
+    ("fsdp", dict(gather_dtype=jnp.bfloat16), True),
+    ("allreduce", dict(comm_dtype=jnp.bfloat16), False),
+    ("dear", dict(comm_dtype=None), False),
+    ("dear", dict(comm_dtype=jnp.bfloat16, compressor="eftopk",
+                  density=0.25), False),
+    ("dear", dict(comm_dtype=jnp.bfloat16, compressor="topk", density=0.25,
+                  gtopk=True), False),
+])
+def test_span_layout_gives_the_same_step(problem, mode, build, spans,
+                                         monkeypatch):
+    """The layout a TPU four gets (buckets padded to XLA:TPU's spans, both
+    legs over ``[n / 128, 128]``; PR 41), with `F.spans_apply` forced on
+    four CPU devices: the same losses and parameters, bit for bit, as the
+    flat layout. It reaches only the dense 'dear' / 'fsdp' legs over a bf16
+    wire; the other schedules, an f32 wire and the compressed legs (whose k
+    would grow with the padding) keep the flat plan."""
+    params, batches, _, _ = problem
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:4]), ("dp",))
+
+    def run():
+        ts = build_train_step(
+            _loss_fn, params, optimizer=fused_sgd(lr=0.1, momentum=0.9),
+            mesh=mesh, mode=mode, threshold_mb=0.0008, donate=False,
+            **build)
+        state, losses = ts.init(params), []
+        for b in batches[:3]:
+            state, metrics = ts.step(state, b)
+            losses.append(float(metrics["loss"]))
+        return ts, losses, ts.gather_params(state)
+
+    flat_ts, flat_losses, flat_params = run()
+    # the CPU mesh stands in for a TPU four (platform None: no spans)
+    monkeypatch.setattr(F, "spans_apply",
+                        lambda platform, world: platform == "cpu")
+    ts, losses, got = run()
+    if spans:
+        assert [b.padded_size for b in ts.plan.buckets] == [
+            F.bucket_length(b.size, 4, "cpu") for b in ts.plan.buckets]
+        assert all(b.padded_size > f.padded_size
+                   for b, f in zip(ts.plan.buckets, flat_ts.plan.buckets))
+        rows = ts.plan.buckets[0].padded_size // 4 // 128
+        text = ts.lower(ts.init(params), batches[0]).as_text()
+        assert f"-> tensor<{rows}x128x" in text
+    else:
+        assert ts.plan == flat_ts.plan
+    assert losses == flat_losses
+    jax.tree.map(np.testing.assert_array_equal, got, flat_params)
+
+
 def test_no_fusion_mode(mesh, world, problem):
     # nearby_layers=1: one bucket per layer (reference no-TF ablation)
     params, batches, ref_params, ref_losses = problem

@@ -314,6 +314,49 @@ def test_elastic_restore_world_resize(mesh, tmp_path):
                                 template=ts4.init(params))
 
 
+def test_elastic_restore_keeps_span_padding(mesh, tmp_path, monkeypatch):
+    """A checkpoint whose buckets are padded to XLA:TPU's spans (a TPU
+    four's lane-dense 'dear' step, PR 41; forced here at world 4 alone)
+    restores elastically onto world 8's flat plan and back: `plan_desc`
+    records the padded lengths, so the rebuilt old plan matches the saved
+    buffers, and parameters, momentum and step come back bit for bit."""
+    import jax.numpy as jnp
+
+    from dear_pytorch_tpu.ops import fusion as F
+
+    monkeypatch.setattr(F, "spans_apply",
+                        lambda platform, world: platform == "cpu"
+                        and world == 4)
+    params = _mlp_params(jax.random.PRNGKey(12))
+    batches = [_data(jax.random.PRNGKey(800 + i)) for i in range(2)]
+    mesh4 = jax.sharding.Mesh(np.asarray(jax.devices()[:4]), ("dp",))
+
+    def build(m):
+        return build_train_step(
+            _loss_fn, params, mesh=m, optimizer=fused_sgd(lr=0.05,
+                                                          momentum=0.9),
+            threshold_mb=0.0008, comm_dtype=jnp.bfloat16, donate=False)
+
+    ts4, ts8 = build(mesh4), build(mesh)
+    assert [b.padded_size for b in ts4.plan.buckets] == [
+        F.bucket_length(b.size, 4, "cpu") for b in ts4.plan.buckets]
+    assert [b.padded_size for b in ts8.plan.buckets] != [
+        b.padded_size for b in ts4.plan.buckets]
+    state = ts4.init(params)
+    for b in batches:
+        state, _ = ts4.step(state, b)
+    ckpt.save_checkpoint(str(tmp_path / "w4"), state, ts4.plan)
+    on8 = ckpt.elastic_restore(str(tmp_path / "w4"), ts8)
+    jax.tree.map(np.testing.assert_array_equal, ts8.gather_params(on8),
+                 ts4.gather_params(state))
+    ckpt.save_checkpoint(str(tmp_path / "w8"), on8, ts8.plan)
+    back = ckpt.elastic_restore(str(tmp_path / "w8"), ts4)
+    jax.tree.map(np.testing.assert_array_equal,
+                 (back.buffers, back.opt_state, back.step),
+                 (state.buffers, state.opt_state, state.step))
+    ts4.step(back, batches[0])
+
+
 def test_generate_example_smoke(mesh, capsys):
     m = _load_example("generate.py")
     m.main(["--steps", "4", "--new-tokens", "3"])

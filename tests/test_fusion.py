@@ -138,3 +138,101 @@ def test_segment_ids_searchsorted_equivalence():
         seg = jnp.where(pos < b.size, seg, len(b.leaf_ids))
         np.testing.assert_array_equal(np.asarray(seg), ref)
         assert b.pad > 0 or b.padded_size == b.size
+
+
+# -- XLA:TPU's reduce-scatter spans (PR 41) ----------------------------------
+
+#: GPT-2 124M's buckets at threshold 25 MB (the dp4 cell): unpadded ->
+#: padded on a TPU four, each a whole number of equal spans
+GPT2_DP4 = [(6_497_280, 6_553_600), (4_727_808, 4_767_744),
+            (4_725_504, 4_767_744), (6_494_208, 6_553_600),
+            (1_969_152, 1_998_848), (38_602_752, 38_879_232)]
+
+
+@pytest.mark.parametrize("platform,world", [
+    ("cpu", 4), ("cpu", 8), (None, 4), ("tpu", 1), ("tpu", 2), ("tpu", 8)])
+def test_bucket_length_off_a_tpu_four_is_the_multiple_of_world(platform,
+                                                               world):
+    from dear_pytorch_tpu.comm.collectives import padded_length
+
+    for n in (0, 1, 7, 1000, 16_385) + tuple(n for n, _ in GPT2_DP4):
+        assert fusion.bucket_length(n, world, platform) == \
+            padded_length(n, world)
+
+
+@pytest.mark.parametrize("n,padded", GPT2_DP4)
+def test_bucket_length_on_a_tpu_four_is_whole_spans(n, padded):
+    assert fusion.bucket_length(n, 4, "tpu") == padded
+
+
+def test_bucket_length_spans_are_the_fewest_that_hold_the_bucket():
+    q, most = fusion._SPAN_QUANTUM, fusion._SPAN_QUANTA
+    for n in np.unique(np.geomspace(1, 2e8, 400).astype(int)):
+        padded = fusion.bucket_length(int(n), 4, "tpu")
+        quanta = -(-int(n) // q)
+        spans = -(-quanta // most)
+        assert padded >= n and padded % q == 0
+        assert padded // q % spans == 0 and padded // q // spans <= most
+        assert padded - n < spans * q          # at most a quantum a span
+        assert (padded // 4) % 128 == 0        # whole [., 128] rows a shard
+
+
+def test_plans_off_a_tpu_four_keep_their_padding(rng):
+    from dear_pytorch_tpu.comm.collectives import padded_length
+
+    params = _params(rng, [8, 16, 128, 3, 700, 9])
+    plan = fusion.plan_by_threshold(params, world=8, threshold_mb=0.002)
+    assert [b.padded_size for b in plan.buckets] == [
+        padded_length(b.size, 8) for b in plan.buckets]
+    for platform in ("cpu", None, "tpu"):     # a TPU eight: no spans read
+        assert fusion.rescale_plan(plan, 8, platform=platform) is plan
+
+
+def test_plan_rebuilt_from_groups_keeps_the_spans(rng):
+    params = _params(rng, [4000, 300, 70_000, 9])
+    plan = fusion.plan_by_threshold(params, world=4, threshold_mb=0.5)
+    tpu = fusion.rescale_plan(plan, 4, platform="tpu")
+    spans = [fusion.bucket_length(b.size, 4, "tpu") for b in plan.buckets]
+    assert [b.padded_size for b in tpu.buckets] == spans
+    assert [b.leaf_ids for b in tpu.buckets] == [b.leaf_ids
+                                                 for b in plan.buckets]
+    assert fusion.rescale_plan(tpu, 4, platform="tpu") is tpu
+    again = fusion.rescale_plan(tpu, 4, epoch=3, platform="tpu")
+    assert again.epoch == 3
+    assert [b.padded_size for b in again.buckets] == spans
+    regrouped = fusion.plan_by_groups(params, 4, [[0, 1], [2, 3]])
+    assert [b.padded_size for b in fusion.rescale_plan(
+        regrouped, 4, platform="tpu").buckets] == [
+        fusion.bucket_length(b.size, 4, "tpu") for b in regrouped.buckets]
+    bufs = fusion.pack_all(params, tpu)
+    assert [x.shape for x in bufs] == [(n,) for n in spans]
+    jax.tree.map(np.testing.assert_array_equal,
+                 fusion.unpack_all(bufs, tpu), params)
+
+
+def test_lane_view_legs_round_trip_like_the_flat_ones(mesh, rng,
+                                                      monkeypatch):
+    """pack -> [n/128, 128] -> reduce-scatter -> all-gather -> unpack over
+    the eight devices gives what the flat legs give: every element's sum
+    over the devices, whatever the view."""
+    from dear_pytorch_tpu.comm import collectives as C
+
+    monkeypatch.setattr(fusion, "spans_apply", lambda platform, world: True)
+    trees = [_params(np.random.default_rng(i), [300, 64, 1000])
+             for i in range(8)]
+    plan = fusion.plan_by_threshold(trees[0], world=8, threshold_mb=None)
+    plan = fusion.rescale_plan(plan, 8, platform="cpu")
+    (b,) = plan.buckets
+    assert b.padded_size % (8 * C.LANES) == 0 and b.pad > 0
+    stacked = jnp.stack([fusion.pack_all(t, plan)[0] for t in trees])
+    flat = C.spmd_call(C.reduce_scatter, stacked, mesh=mesh)
+    view = C.spmd_call(lambda x: C.reduce_scatter(C.lanes(x)).reshape(-1),
+                       stacked, mesh=mesh)
+    np.testing.assert_array_equal(np.asarray(view), np.asarray(flat))
+    full = C.spmd_call(lambda s: C.all_gather(C.lanes(s)).reshape(-1),
+                       view, mesh=mesh)
+    want = jax.tree.map(lambda *xs: sum(xs), *trees)
+    for d in range(8):
+        got = fusion.unpack_all([full[d]], plan)
+        jax.tree.map(lambda a, w: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(w), rtol=1e-6, atol=1e-6), got, want)
