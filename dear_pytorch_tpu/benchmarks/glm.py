@@ -1,16 +1,17 @@
 """Synthetic causal-LM pre-training benchmark for the decoders beyond GPT:
 the sparse latent-attention one (`models/glm_moe.py`: GLM-4.x / DeepSeek-V3
 family), the sparse short-convolution hybrid (`models/lfm2_moe.py`: LFM2-MoE
-family) and the dense Mamba-2 hybrid (`models/granite_hybrid.py`:
-Granite-4.0-H family), the family picked by ``--model``; measured with the
-same harness and output format as `benchmarks/gpt.py`.
+family), the dense Mamba-2 hybrid (`models/granite_hybrid.py`: Granite-4.0-H
+family) and the sparse delta-rule hybrid (`models/ling_hybrid.py`: Ling-3.0
+family), the family picked by ``--model``; measured with the same harness and
+output format as `benchmarks/gpt.py`.
 
 One chip runs its share of a deployment: ``--num-layers`` of the published
 depth (the hybrids: from ``--first-layer`` on), ``--experts-held`` of the
 routed experts from ``--expert-offset`` on (the router keeps its width),
 ``--vocab-size`` ids of the vocabulary. Examples, the benchmark cells' shares
-(BENCHMARK.json, ``glm-4.7-flash-ep8.s4096``, ``lfm2-8b-a1b-ep4.s8192`` and
-``granite-4.0-h-micro-vp4.s4096x1``):
+(BENCHMARK.json, ``glm-4.7-flash-ep8.s4096``, ``lfm2-8b-a1b-ep4.s8192``,
+``granite-4.0-h-micro-vp4.s4096x1`` and ``ling-3.0-flash-ep64.s8192x1``):
 
   python -m dear_pytorch_tpu.benchmarks.glm --model glm47_flash \\
       --num-layers 5 --experts-held 8 --vocab-size 19360 \\
@@ -21,6 +22,9 @@ routed experts from ``--expert-offset`` on (the router keeps its width),
   python -m dear_pytorch_tpu.benchmarks.glm --model granite_4_0_h_micro \\
       --num-layers 10 --vocab-size 25088 --remat \\
       --sequence-len 4096 --batch-size 1 --fp16 --momentum 0.9
+  python -m dear_pytorch_tpu.benchmarks.glm --model ling_3_0_flash \\
+      --first-layer 1 --num-layers 7 --experts-held 8 --vocab-size 19648 \\
+      --remat --sequence-len 8192 --batch-size 1 --fp16 --momentum 0.9
 """
 
 from __future__ import annotations
@@ -42,19 +46,19 @@ from dear_pytorch_tpu.observability import tracer as T
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         description="TPU Synthetic decoder (GLM-MoE, LFM2-MoE, "
-                    "Granite-4.0-H) Benchmark",
+                    "Granite-4.0-H, Ling-3.0) Benchmark",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter,
     )
     p.add_argument("--model", type=str, default="glm47_flash",
                    help="one of " + str(
                        models.glm_names() + models.lfm2_names()
-                       + models.granite_names()))
+                       + models.granite_names() + models.ling_names()))
     p.add_argument("--sequence-len", type=int, default=4096)
     p.add_argument("--num-layers", type=int, default=None,
                    help="blocks run here (the leading dense layer first)")
     p.add_argument("--first-layer", type=int, default=0,
                    help="the hybrids: the published layer the blocks start "
-                        "at (its layer_types, LFM2's dense layers, follow)")
+                        "at (its layer_types and dense layers follow)")
     p.add_argument("--experts-held", type=int, default=None,
                    help="routed experts this chip holds (default: all); the "
                         "router scores all of the model's either way")
@@ -72,8 +76,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def config_from_args(args, dtype):
-    """The model's config (`GlmMoeConfig`, `Lfm2MoeConfig` or
-    `GraniteHybridConfig`) cut to this chip's share."""
+    """The model's config (`GlmMoeConfig`, `Lfm2MoeConfig`,
+    `GraniteHybridConfig` or `LingHybridConfig`) cut to this chip's
+    share."""
     cfg = models.get_model(args.model, dtype=dtype).config
     if isinstance(cfg, models.GraniteHybridConfig):
         if args.experts_held is not None or args.expert_offset:
@@ -84,12 +89,19 @@ def config_from_args(args, dtype):
             layer_types=cfg.layer_types[args.first_layer:][:args.num_layers])
     given = {"experts_held": args.experts_held,
              "vocab_size": args.vocab_size}
+    first = args.first_layer
     if isinstance(cfg, models.Lfm2MoeConfig):
-        first = args.first_layer
         kinds = cfg.layer_types[first:][:args.num_layers]
         given.update(layer_types=kinds, num_hidden_layers=len(kinds),
                      num_dense_layers=max(cfg.num_dense_layers - first, 0))
-    elif args.first_layer:
+    elif isinstance(cfg, models.LingHybridConfig):
+        given.update(layer_types=cfg.layer_types[first:][:args.num_layers],
+                     first_layer=cfg.first_layer + first,
+                     first_k_dense_replace=max(
+                         cfg.first_k_dense_replace - first, 0))
+        if args.no_mtp:
+            given["num_nextn_predict_layers"] = 0
+    elif first:
         raise ValueError("--first-layer is the hybrid families'")
     else:
         given["num_layers"] = args.num_layers
@@ -118,7 +130,8 @@ def main(argv=None) -> runner.BenchResult:
     world = backend.dp_size(mesh)
 
     cfg = config_from_args(args, jnp.bfloat16 if args.fp16 else jnp.float32)
-    lfm2 = isinstance(cfg, models.Lfm2MoeConfig)
+    hybrid = isinstance(cfg, (models.Lfm2MoeConfig,
+                             models.LingHybridConfig))
     dense = isinstance(cfg, models.GraniteHybridConfig)
     model = type(models.get_model(args.model))(cfg)
     global_bs = args.batch_size * world
@@ -150,12 +163,12 @@ def main(argv=None) -> runner.BenchResult:
     state = ts.init(params)
     del params
 
-    depth = (f"layers {', '.join(cfg.layer_types)}" if lfm2 or dense else
+    depth = (f"layers {', '.join(cfg.layer_types)}" if hybrid or dense else
              f"{cfg.num_layers} layer(s), {cfg.num_nextn_predict_layers} "
              "prediction module(s)")
     experts = "no routed experts"
     if not dense:
-        scored = cfg.num_experts if lfm2 else cfg.n_routed_experts
+        scored = cfg.num_experts if hybrid else cfg.n_routed_experts
         held = cfg.experts_held or scored
         experts = (f"experts [{cfg.expert_offset}, "
                    f"{cfg.expert_offset + held}) of {scored}")

@@ -33,6 +33,7 @@ from dear_pytorch_tpu.models.gpt import (  # noqa: F401
 )
 from dear_pytorch_tpu.models import glm_moe as _glm_moe
 from dear_pytorch_tpu.models import lfm2_moe as _lfm2_moe
+from dear_pytorch_tpu.models import ling_hybrid as _ling_hybrid
 from dear_pytorch_tpu.models.glm_moe import (  # noqa: F401
     GLM47_FLASH,
     GLM_MOE_TINY,
@@ -53,6 +54,12 @@ from dear_pytorch_tpu.models.lfm2_moe import (  # noqa: F401
     Lfm2MoeConfig,
     Lfm2MoeLmHeadModel,
     lfm2_moe_lm_loss,
+)
+from dear_pytorch_tpu.models.ling_hybrid import (  # noqa: F401
+    LING_3_0_FLASH,
+    LING_HYBRID_TINY,
+    LingHybridConfig,
+    LingHybridLmHeadModel,
 )
 from dear_pytorch_tpu.models.densenet import (  # noqa: F401
     DenseNet121,
@@ -126,12 +133,28 @@ _GRANITE_REGISTRY: dict[str, Any] = {
     "granite_hybrid_tiny": GRANITE_HYBRID_TINY,   # CPU tests and smoke runs
 }
 
+# Hybrid sparse decoders: Kimi-delta-attention mixers among latent
+# attention layers (models/ling_hybrid.py).
+_LING_REGISTRY: dict[str, Any] = {
+    "ling_3_0_flash": LING_3_0_FLASH,
+    "ling_hybrid_tiny": LING_HYBRID_TINY,   # CPU tests and smoke runs only
+}
+
 #: decoders `benchmarks/glm.py` runs: (registry, model class)
 _DECODERS = (
     (_GLM_REGISTRY, GlmMoeLmHeadModel),
     (_LFM2_REGISTRY, Lfm2MoeLmHeadModel),
     (_GRANITE_REGISTRY, GraniteHybridLmHeadModel),
+    (_LING_REGISTRY, LingHybridLmHeadModel),
 )
+
+#: the sparse decoders' modules, by config type: each has its
+#: ``expert_assignments``
+_SPARSE_FAMILIES = {
+    GlmMoeConfig: _glm_moe,
+    Lfm2MoeConfig: _lfm2_moe,
+    LingHybridConfig: _ling_hybrid,
+}
 
 
 def cnn_names() -> list[str]:
@@ -158,12 +181,19 @@ def granite_names() -> list[str]:
     return sorted(_GRANITE_REGISTRY)
 
 
+def ling_names() -> list[str]:
+    return sorted(_LING_REGISTRY)
+
+
 def expert_assignments(cfg, intermediates):
     """``[expert layers, experts held]`` assignments made to each held
-    expert of either sparse decoder, from the ``intermediates`` collection
-    of one ``model.apply(..., mutable=["intermediates"])``."""
-    family = _lfm2_moe if isinstance(cfg, Lfm2MoeConfig) else _glm_moe
-    return family.expert_assignments(cfg, intermediates)
+    expert of a sparse decoder, from the ``intermediates`` collection of one
+    ``model.apply(..., mutable=["intermediates"])``; the family is looked
+    up by ``cfg``'s type."""
+    if type(cfg) not in _SPARSE_FAMILIES:
+        raise TypeError(f"{type(cfg).__name__} is no sparse decoder's "
+                        "config")
+    return _SPARSE_FAMILIES[type(cfg)].expert_assignments(cfg, intermediates)
 
 
 def get_model(name: str, *, dtype=jnp.float32, **kwargs):
@@ -193,7 +223,7 @@ def get_model(name: str, *, dtype=jnp.float32, **kwargs):
     raise KeyError(
         f"unknown model {name!r}; CNNs: {cnn_names()}, BERT: {bert_names()}, "
         f"GPT: {gpt_names()}, GLM: {glm_names()}, LFM2: {lfm2_names()}, "
-        f"Granite: {granite_names()}"
+        f"Granite: {granite_names()}, Ling: {ling_names()}"
     )
 
 

@@ -174,7 +174,11 @@ class RoutedExperts(nn.Module):
     ``norm_topk_eps``, a constant of the source model's code), then
     ``routed_scaling_factor`` scales them. ``router_bias`` (the
     ``e_score_correction_bias``) is a parameter leaf behind `stop_gradient`:
-    it moves the selection and receives no gradient.
+    it moves the selection and receives no gradient. With ``n_group`` > 1
+    the selection is group-limited (DeepSeek-V3's): the router's experts
+    fall into ``n_group`` equal groups, each group scores the sum of its two
+    best ``score + router_bias``, and the top-k is taken within the
+    ``topk_group`` best groups; ``n_group`` 1 is the flat top-k.
 
     Dropless under any imbalance: the ``T*k`` assignments are sorted by
     held expert (absent ones last, in no group) and the feed-forward runs
@@ -214,6 +218,8 @@ class RoutedExperts(nn.Module):
     scoring: str = "sigmoid"
     norm_topk_prob: bool = True
     norm_topk_eps: float = 1e-20
+    n_group: int = 1
+    topk_group: int = 1
     routed_scaling_factor: float = 1.0
     dtype: Any = jnp.float32
     kernel_init: Callable = nn.initializers.lecun_normal()
@@ -229,12 +235,31 @@ class RoutedExperts(nn.Module):
             scores = jax.nn.softmax(logits, axis=-1)
         else:
             raise ValueError(f"unknown scoring {self.scoring!r}")
-        _, idx = lax.top_k(scores + lax.stop_gradient(router_bias), self.top_k)
+        choice = scores + lax.stop_gradient(router_bias)
+        if self.n_group > 1:
+            choice = self.limit_to_groups(choice)
+        _, idx = lax.top_k(choice, self.top_k)
         weights = jnp.take_along_axis(scores, idx, axis=-1)
         if self.norm_topk_prob:
             weights = weights / (jnp.sum(weights, -1, keepdims=True)
                                  + self.norm_topk_eps)
         return idx, weights * self.routed_scaling_factor
+
+    def limit_to_groups(self, choice):
+        """``choice`` ``[T, router_width]`` with the experts outside each
+        token's ``topk_group`` best groups at ``-inf``; a group's score is
+        the sum of its two best entries."""
+        T, width = choice.shape
+        if width % self.n_group or (self.topk_group * width // self.n_group
+                                    < self.top_k):
+            raise ValueError(
+                f"{self.n_group} groups of {width} experts, {self.topk_group}"
+                f" kept, cannot give {self.top_k} experts a token")
+        grouped = choice.reshape(T, self.n_group, width // self.n_group)
+        best = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)     # [T, groups]
+        _, kept = lax.top_k(best, self.topk_group)
+        keep = jnp.any(kept[..., None] == jnp.arange(self.n_group), axis=1)
+        return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(T, width)
 
     @nn.compact
     def __call__(self, x):
