@@ -67,11 +67,12 @@ from dear_pytorch_tpu.ops.kda import kda_chunked
 #: the KDA scan's output, named for `_BLOCK_POLICY`
 _SCAN_OUT = "kda_out"
 
-#: KDA heads a pass of the chunked scan (`ops.kda.kda_chunked`'s
-#: ``heads_at_once``): the op's backward working set is this share of all
-#: 32. The benchmark cell's step by XLA's memory analysis for a v5e: 16.62
-#: GB of the 16.9 the compiler allows so, refused with all 32 at once (17.2
-#: GB needed, 1.5 over)
+#: KDA heads a pass of XLA's chunked scan (`ops.kda.kda_chunked`'s
+#: ``heads_at_once``, off the TPU kernels' path): the op's backward working
+#: set is this share of all 32. The benchmark cell's step on that form by
+#: XLA's memory analysis for a v5e: 16.62 GB of the 16.9 the compiler allows
+#: so, refused with all 32 at once (17.2 GB needed, 1.5 over). The kernels
+#: work a few heads and one chunk at a time and ignore it.
 _KDA_HEADS_AT_ONCE = 16
 
 

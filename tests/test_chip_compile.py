@@ -28,7 +28,7 @@ from dear_pytorch_tpu.ops.collective_matmul import (
     ring_all_gather,
 )
 from dear_pytorch_tpu.ops.flash_attention import flash_attention
-from dear_pytorch_tpu.ops import grouped_matmul, moe_rows
+from dear_pytorch_tpu.ops import grouped_matmul, kda, moe_rows
 from dear_pytorch_tpu.ops.fused_sgd import fused_sgd
 from dear_pytorch_tpu.ops.fusion import bucket_length
 from dear_pytorch_tpu.parallel import DearState, build_train_step
@@ -67,7 +67,7 @@ def compiled_kernels(monkeypatch):
     (By module name through sys.modules: `dear_pytorch_tpu.ops` re-exports
     a `flash_attention` FUNCTION that shadows the module attribute.)"""
     for name in ("flash_attention", "collective_matmul", "moe_rows",
-                 "grouped_matmul"):
+                 "grouped_matmul", "kda"):
         monkeypatch.setattr(sys.modules[f"dear_pytorch_tpu.ops.{name}"],
                             "_interpret", lambda: False)
 
@@ -427,6 +427,81 @@ def test_granite_attention_block_holds_the_grouped_flash_kernels(
         == kernels - 1
     assert "f32[1,32,4096,4096]" not in text
     assert not re.search(r"\b(cosine|sine)\(", text)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_kda_kernels_compile_for_v5e(compiled_kernels, one_chip, direction,
+                                     dtype):
+    """The chunked delta rule's kernels at one Ling-3.0-flash KDA layer's
+    shape (``[1, 8192, 32, 128]``, chunk 64), in the cell's bf16 and in the
+    f32 of its reference check."""
+    shape = (1, 8192, 32, 128)
+    assert kda.kernel_applies(shape, 64, dtype)
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    args = ([on(shape, dtype)] * 3 + [on(shape, jnp.float32),
+                                      on(shape[:3], jnp.float32)])
+
+    def scan(*a):
+        return kda.kda_chunked(*a, 64)
+
+    fn = scan if direction == "fwd" else jax.grad(
+        lambda *a: jnp.sum(scan(*a).astype(jnp.float32)), argnums=range(5))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    # the gradient alone never reads the forward kernel's output
+    assert [re.search(r"kda_(fwd|bwd)", line).group(0) for line in calls] \
+        == [f"kda_{direction}"]
+    assert "triangular-solve" not in text
+
+
+def test_ling_kda_block_holds_the_scan_kernels_under_its_scope(
+        compiled_kernels, one_chip):
+    """One recomputed Ling-3.0-flash KDA block at the cell's widths (hidden
+    2560, 32 heads of 128, S=8192, bf16), value and gradient: the scan is
+    two kernels whose OWN ``op_name`` lies under ``kda/scan``, the forward
+    one and, under ``transpose(jvp(block))``, the backward one (the block
+    keeps the scan's output, so the recomputed forward holds no kernel),
+    which is what `kda_scan_kernel_calls_per_step` counts and `kda_scan_ms`
+    reads; no XLA triangular solve or ``while`` of the chunked form is
+    left."""
+    import dataclasses
+
+    from dear_pytorch_tpu import models
+    from dear_pytorch_tpu.models import ling_hybrid
+    from perfbench import cell as cells
+
+    cfg = dataclasses.replace(models.LING_3_0_FLASH, layer_types=("kda",),
+                              dtype=jnp.bfloat16)
+    layer = ling_hybrid.nn.remat(ling_hybrid.LingBlock,
+                                 policy=ling_hybrid._BLOCK_POLICY)(
+        cfg, "kda", "dense")
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    x = on((1, 8192, cfg.hidden_size), jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: on(a.shape, a.dtype),
+        jax.eval_shape(lambda key: layer.init(key, jnp.zeros(
+            (1, 64, cfg.hidden_size), jnp.bfloat16), None)["params"],
+            jax.random.PRNGKey(0)))
+
+    def loss(p, x):
+        with jax.named_scope("block"):
+            return jnp.sum(jnp.square(
+                layer.apply({"params": p}, x, None).astype(jnp.float32)))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in calls]
+    assert len(names) == 2
+    assert all(re.search(r"(?:^|[/(])kda/scan(?:[/)]|$)", n) for n in names)
+    assert [("transpose(jvp(block))" in n, n.endswith("pallas_call"))
+            for n in names] == [(False, True), (True, True)]
+    counter = cells.layer_reader("kda_scan_kernel_calls_per_step")
+    assert counter({"built": {"compiled_text": text}}) == 2.0
+    assert "triangular-solve" not in text and " while(" not in text
 
 
 def test_gpt2_head_and_loss_keep_one_bf16_logits_buffer(one_chip):

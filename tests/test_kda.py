@@ -1,7 +1,9 @@
 """`ops.kda.kda_chunked` against the recurrence it computes, written here
 twice: a loop over positions in float64 numpy (values) and a `lax.scan` over
 positions (values and gradients); small sizes, float32, matmul precision
-"highest", decays down to the lower bound."""
+"highest", decays down to the lower bound. Its Pallas kernels (Pallas'
+interpreter off the TPU) against the same loop and against XLA's chunked
+form, at the kernels' 128-lane widths."""
 
 import jax
 import jax.numpy as jnp
@@ -189,3 +191,133 @@ def test_refuses_what_it_cannot_chunk(bad):
     else:
         with pytest.raises(ValueError, match="differ"):
             kda.kda_chunked(q, k[..., :4], v, g, beta, 8)
+
+
+# -- the Pallas kernels (interpret mode here) --------------------------------
+
+WIDE = 128          # the kernels take whole 128-lane tiles
+
+
+def _wide(seq, dtype=jnp.float32, seed=0, heads=2, low=BOUND):
+    """`_inputs` at the kernels' widths: K = V = 128, one sequence."""
+    key = jax.random.split(jax.random.PRNGKey(seed), 5)
+    shape = (1, seq, heads, WIDE)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    q, k = (unit(jax.random.normal(kk, shape)) for kk in key[:2])
+    v = jax.random.normal(key[2], shape)
+    g = jax.random.uniform(key[3], shape, minval=low, maxval=0.0)
+    g = g.at[..., ::4].set(low)
+    beta = jax.random.uniform(key[4], shape[:3])
+    return tuple(x.astype(dtype) for x in (q, k, v)) + (g, beta)
+
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """`kda_chunked` on the kernels (Pallas' interpreter off the TPU)."""
+    monkeypatch.setattr(kda, "kernel_applies", lambda *a, **kw: True)
+
+
+def _scan64(*args):
+    """`kda_chunked` at chunk 64, traced afresh (jit's cache is keyed by the
+    function, not by which path `kernel_applies` chose)."""
+    return jax.jit(lambda *a: kda.kda_chunked(*a, 64))(*args)
+
+
+def _xla(*args):
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kda, "kernel_applies", lambda *a, **kw: False)
+        return _scan64(*args)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_kernels_equal_the_recurrence_and_the_xla_form(kernels, dtype):
+    """f32: within `_close` of the float64 recurrence and of XLA's chunked
+    form. bf16 operands: both forms round W, P, U and the states to bf16 at
+    the same points, but a rounding whose f32 input differs in its last
+    bits (another order of sums) can land one bf16 step (4e-3) the other
+    way, so they agree to 3e-2 of the output's size, the band bf16 keeps to
+    the recurrence (`test_bf16_operands_fail_the_float32_limit`), and the
+    float32 limit fails them."""
+    args = _wide(256, getattr(jnp, dtype))
+    want = _loop(*(a.astype(jnp.float32) for a in args))
+    with jax.default_matmul_precision("highest"):
+        got = _scan64(*args)
+        xla = _xla(*args)
+    assert not np.array_equal(np.asarray(got), np.asarray(xla))
+    assert got.dtype == args[2].dtype and got.shape == (1, 256, 2, WIDE)
+    got, xla = np.asarray(got, np.float32), np.asarray(xla, np.float32)
+    if dtype == "float32":
+        _close(got, want)
+        _close(got, xla)
+        return
+    for ref in (want, xla):
+        np.testing.assert_allclose(got, ref, rtol=0,
+                                   atol=3e-2 * np.abs(ref).max())
+    with pytest.raises(AssertionError):
+        _close(got, want)
+
+
+def test_the_kernels_gradients_equal_the_xla_forms(kernels):
+    """All five input gradients through the kernels' `custom_vjp` against
+    autodiff of XLA's chunked form, f32, within `_close`."""
+    args = _wide(256, seed=1)
+    cot = jax.random.normal(jax.random.PRNGKey(9), (1, 256, 2, WIDE))
+
+    def loss(*a):
+        return jnp.sum(kda.kda_chunked(*a, 64) * cot)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(loss, argnums=range(5)))(*args)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(kda, "kernel_applies", lambda *a, **kw: False)
+            want = jax.jit(jax.grad(loss, argnums=range(5)))(*args)
+    for name, a, b in zip(("q", "k", "v", "log_alpha", "beta"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert np.abs(np.asarray(b)).max() > 0, name
+        _close(a, b)
+
+
+def test_the_kernels_at_the_bound_stay_finite(kernels):
+    """Every channel at the bound for whole chunks of 64 (the decay across
+    a chunk e^-320): values and gradients finite, values on the
+    recurrence."""
+    q, k, v, g, beta = _wide(128, seed=2)
+    g = jnp.full_like(g, BOUND)
+    with jax.default_matmul_precision("highest"):
+        got = _scan64(q, k, v, g, beta)
+        grads = jax.jit(jax.grad(lambda *a: jnp.sum(kda.kda_chunked(*a, 64)),
+                                 argnums=range(5)))(q, k, v, g, beta)
+    assert np.isfinite(np.asarray(got)).all()
+    for x in grads:
+        assert np.isfinite(np.asarray(x)).all()
+    _close(got, _loop(q, k, v, g, beta))
+
+
+def test_the_kernels_refuse_a_gate_below_the_bound(kernels):
+    q, k, v, g, beta = _wide(64, seed=3)
+    ok = kda.kda_chunked(q, k, v, g, beta, 64)
+    bad = kda.kda_chunked(q, k, v, g.at[0, 3, 1, 2].set(BOUND - 0.01), beta,
+                          64)
+    assert np.isfinite(np.asarray(ok)).all()
+    assert np.isnan(np.asarray(bad)).all()
+
+
+@pytest.mark.parametrize("shape,chunk,dtype,want", [
+    ((1, 8192, 32, 128), 64, jnp.bfloat16, True),     # the cell's layer
+    ((1, 8192, 32, 128), 64, jnp.float32, True),      # its f32 check
+    ((1, 8192, 32, 64), 64, jnp.bfloat16, False),     # half a lane tile
+    ((1, 8192, 32, 128), 16, jnp.bfloat16, False),    # another chunk
+    ((1, 8200, 32, 128), 64, jnp.bfloat16, False),    # no whole chunks
+    ((1, 8192, 32, 128), 64, jnp.float16, False),
+    ((1, 262144, 32, 128), 64, jnp.bfloat16, False),  # VMEM
+])
+def test_kernel_applies_by_backend_shape_and_dtype(monkeypatch, shape,
+                                                   chunk, dtype, want):
+    """Off the TPU never; on one (described here) for whole lane tiles,
+    whole chunks of 64 and bf16 or f32."""
+    assert not kda.kernel_applies(shape, chunk, dtype)
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    assert kda.kernel_applies(shape, chunk, dtype) == want

@@ -224,6 +224,39 @@ def _shapes(cfg):
         jax.random.PRNGKey(0))
 
 
+CALL = ('  %{name}.{n} = bf16[1,8192,4096]{{2,1,0}} custom-call(%a, %b), '
+        'custom_call_target="tpu_custom_call", metadata={{op_name="{op}"}}')
+
+
+@pytest.mark.parametrize("lines,want", [
+    ([CALL.format(name="kda_fwd", n=1,
+                  op=F + "h_1/kda/scan/kda_fwd/pallas_call")], 1.0),
+    ([CALL.format(name="kda_bwd", n=2, op=B + "jvp(LingHybridLmHeadModel)/"
+                  "checkpoint/h_1/kda/scan/kda_bwd/pallas_call")], 1.0),
+    # a kernel without its own op_name, and one under another scope
+    (['  %kda_fwd.3 = bf16[1,8192,4096]{2,1,0} custom-call(%a), '
+      'custom_call_target="tpu_custom_call"',
+      CALL.format(name="flash_fwd", n=4,
+                  op=F + "h_4/attention/flash_fwd/pallas_call")], None),
+    # six KDA layers, forward and backward, among the other kernels
+    ([CALL.format(name=f"kda_{side}", n=10 * i + n,
+                  op=(F if side == "fwd" else B) + f"h_{i}/kda/scan/"
+                  f"kda_{side}/pallas_call")
+      for i in (0, 1, 2, 3, 5, 6) for n, side in enumerate(("fwd", "bwd"))]
+     + [CALL.format(name="flash_fwd", n=4,
+                    op=F + "h_4/attention/flash_fwd/pallas_call"),
+        '  %fusion.5 = f32[1,8192,4096]{2,1,0} fusion(%x), kind=kLoop, '
+        'metadata={op_name="' + F + 'h_1/kda/scan/mul"}'], 12.0),
+], ids=["forward", "backward", "none", "a step"])
+def test_the_kernel_counter_on_compiled_lines(lines, want):
+    """`kda_scan_kernel_calls_per_step` counts the ``tpu_custom_call``s
+    whose own ``op_name`` lies under ``kda/scan``, forward or under
+    ``transpose(jvp(...))``; a scopeless one, another scope's kernel and
+    XLA's fusions under the scope are not counted."""
+    read = cells.layer_reader("kda_scan_kernel_calls_per_step")
+    assert read({"built": {"compiled_text": "\n".join(lines)}}) == want
+
+
 def test_the_configuration_file_keeps_every_published_number():
     """Every key of the source's config.json is in the file under its own
     name at the top level and in ``model``, unchanged but for those in
@@ -341,7 +374,7 @@ def test_the_benchmarks_entries_resolve():
                  "mtp_ms", "kernel_flops_util_pct", "forward_ms", "loss_ms",
                  "moe_row_kernel_calls_per_step",
                  "expert_matmul_kernel_calls_per_step",
-                 "latent_projection_ms"):
+                 "latent_projection_ms", "kda_scan_kernel_calls_per_step"):
         assert name in names, name
         assert callable(cells.layer_reader(name))
     for name in ("mamba_mixer_ms", "short_conv_ms", "dropout_ms",
@@ -351,11 +384,13 @@ def test_the_benchmarks_entries_resolve():
         "tokens_per_s_per_chip", "step_ms_p95", "peak_hbm_gb", "setup_s"]
     bench = cells.load_json(ROOT / "BENCHMARK.json")
     new = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in new] == ["kda_mixer_ms", "kda_scan_ms",
-                                        "kda_scan_roofline_pct"]
+    assert [m["name"] for m in new] == [
+        "kda_mixer_ms", "kda_scan_ms", "kda_scan_roofline_pct",
+        "kda_scan_kernel_calls_per_step"]
     assert all(m["layer"] == "models and kernels" and m["moves"]
-               == "tokens_per_s_per_chip" and m["source"] == "device_trace"
-               for m in new)
+               == "tokens_per_s_per_chip" for m in new)
+    assert [m["source"] for m in new] == ["device_trace"] * 3 + [
+        "program_counter"]
     assert [w["name"] for w in bench["workloads"]].index(CELL) == 6
     assert [c["name"] for c in bench["configs"]].index(CONFIG) == 5
     assert all(len(e["why"]) <= 200 for e in bench["workloads"]
